@@ -77,7 +77,7 @@ def _dec_kernel(len_ref,                       # scalar prefetch: (B,) lengths
 
 
 def decode_attention_pallas(q, k_cache, v_cache, lengths, *, block_s: int = 256,
-                            interpret: bool = True):
+                            interpret: bool):
     """q: (B,1,Hq,hd); k/v_cache: (B,S,Hkv,hd); lengths (B,). -> (B,1,Hq,hd)."""
     B, _, Hq, hd = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]

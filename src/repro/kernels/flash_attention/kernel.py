@@ -87,7 +87,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            softcap: float = 0.0, block_q: int = 128,
-                           block_kv: int = 128, interpret: bool = True):
+                           block_kv: int = 128, interpret: bool):
     """q: (B,S,Hq,hd), k/v: (B,S,Hkv,hd) -> (B,S,Hq,hd)."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]

@@ -1,20 +1,22 @@
-"""Pallas TPU paged chunked-prefill kernel: one prompt chunk vs a paged KV
+"""Pallas TPU paged chunked-prefill kernel: prompt chunks vs a paged KV
 pool.
 
-The serving engine's chunked prefill (models/transformer.prefill_chunk_paged)
-ingests a prompt in fixed C-token chunks; each chunk's queries attend
-causally within the chunk AND against every page the slot already wrote —
-a ragged cross-chunk read the jnp oracle serves by gathering the slot's
-whole block row into a contiguous buffer per layer per chunk. This kernel
-removes the gather, mirroring the paged flash-decode kernel one PR back:
+The serving engine's chunked prefill (models/transformer.prefill_ragged_paged
+and prefill_chunk_paged) ingests prompts in fixed C-token chunks; each
+chunk's queries attend causally within the chunk AND against every page its
+slot already wrote — a ragged cross-chunk read the jnp oracle serves by
+gathering the slot's whole block row into a contiguous buffer per layer per
+chunk. This kernel removes the gather, mirroring the paged flash-decode
+kernel (and sharing its page-tile and running-softmax helpers):
 
-  * `(block_row, [offset, chunk_len])` are scalar-prefetched and the block
-    row IS the K/V `index_map`: grid step (h, p) streams physical page
-    `block_row[p]` HBM->VMEM straight from the pool.
-  * steps past the live range (`ceil((offset+chunk_len)/page)` pages)
-    re-map to the last live page — Pallas elides the DMA for a revisited
+  * `(block_rows, [offset, chunk_len] per row)` are scalar-prefetched and
+    the block rows ARE the K/V `index_map`: grid step (r, h, p) streams
+    physical page `block_rows[r, p]` HBM->VMEM straight from the pool.
+  * steps past a row's live range (`ceil((offset+chunk_len)/page)` pages)
+    re-map to its last live page — Pallas elides the DMA for a revisited
     block — and `pl.when` prunes their compute along with unmapped (-1)
-    pages, so the read volume is O(offset + chunk_len), not O(P * page).
+    pages and whole padding rows, so the read volume is
+    O(sum_r (offset + chunk_len)), not O(R * P * page).
   * in-page positions past `offset+chunk_len` hold stale pool bytes and are
     zeroed before the MXU; the causal mask `kpos <= offset + (q mod C)`
     handles the intra-chunk triangle (the chunk's own K/V is written before
@@ -22,10 +24,12 @@ removes the gather, mirroring the paged flash-decode kernel one PR back:
   * the Q tile is the whole (q_per_kv * C, hd) chunk: every query head of
     one KV head rides each streamed page tile, with a running-softmax
     scratch accumulated across pages (flash style).
+  * a quantized (int8/fp8) pool is dequantized in VMEM per page tile with
+    its streamed per-(page, kv-head) scale, as in the decode kernel.
 
-Grid: (Hkv, P) with P = block-row width (callers pre-trim to the live
-width). Query rows past `chunk_len` are computed against whatever the mask
-admits and must be discarded by the caller.
+The single-slot entry points are the ragged kernel with one row. Query rows
+past `chunk_len` are computed against whatever the mask admits and must be
+discarded by the caller.
 """
 from __future__ import annotations
 
@@ -36,135 +40,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.paged_decode_attention.kernel import (
+    init_softmax, lane_view, page_tile, scale_view, softmax_result,
+    softmax_update)
 
 
-def _paged_pref_kernel(row_ref,                # scalar prefetch: (P,) pages
-                       info_ref,               # scalar prefetch: (2,) off,len
-                       q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr,
-                       *, np_: int, ps: int, C: int, rep: int, scale: float):
-    pi = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    total = info_ref[0] + info_ref[1]          # offset + chunk_len
-    page = row_ref[pi]
-    s_start = pi * ps
-
-    # live mapped page: pages past the covering range and unmapped (-1)
-    # entries contribute nothing and are skipped (their block was not
-    # re-fetched either — see the clamped index_map in
-    # paged_prefill_attention_pallas)
-    @pl.when((s_start < total) & (page >= 0))
-    def _body():
-        kpos = s_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        kvalid = kpos < total                   # (ps, 1)
-        q = q_ref[0].reshape(rep * C, -1).astype(jnp.float32)
-        # zero stale rows BEFORE the matmul: positions past offset+chunk_len
-        # hold whatever the pool last held and must not reach the MXU
-        k = jnp.where(kvalid, k_ref[0].astype(jnp.float32)[:, 0], 0.0)
-        v = jnp.where(kvalid, v_ref[0].astype(jnp.float32)[:, 0], 0.0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        # causal: query row r is chunk position r mod C at absolute
-        # position offset + (r mod C)
-        qpos = info_ref[0] + jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (rep * C, 1), 0), C)
-        m = kvalid[:, 0][None, :] & (kpos[:, 0][None, :] <= qpos)
-        s = jnp.where(m, s, NEG_INF)
-
-        m_prev = m_scr[...][:, 0]
-        l_prev = l_scr[...][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(m, p, 0.0)               # rows with no valid key yet
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_prev * alpha + jnp.sum(p, axis=1))[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
-
-    @pl.when(pi == np_ - 1)
-    def _finish():
-        l = l_scr[...][:, 0]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        hd = acc_scr.shape[-1]
-        o_ref[0] = (acc_scr[...] / denom[:, None]).reshape(
-            rep, C, hd).astype(o_ref.dtype)
-
-
-def paged_prefill_attention_pallas(q, k_pages, v_pages, block_row, offset,
-                                   chunk_len, *, interpret: bool = True):
-    """q: (1, C, Hq, hd) one slot's chunk queries; k/v_pages: (n_pages,
-    page, Hkv, hd) with the chunk already written; block_row: (P,) int32
-    page ids (-1 = unmapped); offset/chunk_len: () int32. ->
-    (1, C, Hq, hd); rows past chunk_len are unspecified."""
-    _, C, Hq, hd = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
-    P = block_row.shape[0]
-    rep = Hq // Hkv
-    row = block_row.astype(jnp.int32)
-    info = jnp.stack([jnp.asarray(offset, jnp.int32).reshape(()),
-                      jnp.asarray(chunk_len, jnp.int32).reshape(())])
-
-    # (Hkv, rep, C, hd): group q heads by their kv head
-    qg = jnp.moveaxis(q[0], 1, 0).reshape(Hkv, rep, C, hd)
-
-    def kv_map(h, p, row_ref, info_ref):
-        # steps past the covering range re-stream the last live page:
-        # Pallas skips the DMA for a block index equal to the previous
-        # step's, so pruned pages cost neither bandwidth nor compute
-        n_live = jax.lax.div(info_ref[0] + info_ref[1] + ps - 1, ps)
-        pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        pg = row_ref[pi]
-        return (jnp.maximum(pg, 0), 0, h, 0)
-
-    kernel = functools.partial(_paged_pref_kernel, np_=P, ps=ps, C=C,
-                               rep=rep, scale=1.0 / float(hd) ** 0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Hkv, P),
-        in_specs=[
-            pl.BlockSpec((1, rep, C, hd), lambda h, p, *_: (h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, rep, C, hd),
-                               lambda h, p, *_: (h, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep * C, 1), jnp.float32),
-            pltpu.VMEM((rep * C, 1), jnp.float32),
-            pltpu.VMEM((rep * C, hd), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, rep, C, hd), q.dtype),
-        interpret=interpret,
-    )(row, info, qg, k_pages, v_pages)
-    # (Hkv, rep, C, hd) -> (1, C, Hq, hd) with head index h = kv * rep + r
-    return jnp.moveaxis(out.reshape(Hq, C, hd), 0, 1)[None]
-
-
-def _paged_pref_ragged_kernel(rows_ref,        # scalar prefetch: (R, P) pages
-                              info_ref,        # scalar prefetch: (R, 2)
-                              q_ref, k_ref, v_ref, o_ref,
-                              m_scr, l_scr, acc_scr,
-                              *, np_: int, ps: int, C: int, rep: int,
-                              scale: float):
+def _paged_pref_kernel(rows_ref,               # scalar prefetch: (R, P) pages
+                       info_ref,               # scalar prefetch: (R, 2)
+                       q_ref, k_ref, v_ref, *refs,
+                       np_: int, ps: int, C: int, rep: int, scale: float,
+                       quant: bool):
+    if quant:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        (o_ref, m_scr, l_scr, acc_scr), ks_ref, vs_ref = refs, None, None
     r = pl.program_id(0)
+    h = pl.program_id(1)
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
     total = info_ref[r, 0] + info_ref[r, 1]    # offset + chunk_len
     page = rows_ref[r, pi]
@@ -173,59 +69,32 @@ def _paged_pref_ragged_kernel(rows_ref,        # scalar prefetch: (R, P) pages
     # live mapped page of THIS row: pages past the row's covering range,
     # unmapped (-1) entries, and whole padding rows (len == 0 -> total ==
     # 0) contribute nothing and are skipped (their block was not re-fetched
-    # either — see the clamped index_map in
-    # paged_prefill_attention_ragged_pallas)
+    # either — see the clamped index_map in _paged_prefill)
     @pl.when((s_start < total) & (page >= 0))
     def _body():
-        kpos = s_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        kvalid = kpos < total                   # (ps, 1)
+        valid = s_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) \
+            < total                                             # (ps, 1)
+        kpos = s_start + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         q = q_ref[0, 0].reshape(rep * C, -1).astype(jnp.float32)
-        # zero stale rows BEFORE the matmul: positions past offset+chunk_len
-        # hold whatever the pool last held and must not reach the MXU
-        k = jnp.where(kvalid, k_ref[0].astype(jnp.float32)[:, 0], 0.0)
-        v = jnp.where(kvalid, v_ref[0].astype(jnp.float32)[:, 0], 0.0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        k = page_tile(k_ref, valid, ks_ref, h)
+        v = page_tile(v_ref, valid, vs_ref, h)
         # causal: query row j is chunk position j mod C at absolute
         # position offsets[r] + (j mod C)
         qpos = info_ref[r, 0] + jax.lax.rem(
             jax.lax.broadcasted_iota(jnp.int32, (rep * C, 1), 0), C)
-        m = kvalid[:, 0][None, :] & (kpos[:, 0][None, :] <= qpos)
-        s = jnp.where(m, s, NEG_INF)
-
-        m_prev = m_scr[...][:, 0]
-        l_prev = l_scr[...][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(m, p, 0.0)               # rows with no valid key yet
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_prev * alpha + jnp.sum(p, axis=1))[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
+        mask = (kpos < total) & (kpos <= qpos)                  # (rep*C, ps)
+        softmax_update(q, k, v, mask, m_scr, l_scr, acc_scr, scale)
 
     @pl.when(pi == np_ - 1)
     def _finish():
-        l = l_scr[...][:, 0]
-        denom = jnp.where(l == 0.0, 1.0, l)
         hd = acc_scr.shape[-1]
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).reshape(
+        o_ref[0, 0] = softmax_result(l_scr, acc_scr).reshape(
             rep, C, hd).astype(o_ref.dtype)
 
 
-def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
-                                          offsets, lens, *,
-                                          interpret: bool = True):
-    """Multi-slot ragged chunk attention: the batched-ingest extension of
-    `paged_prefill_attention_pallas`.
-
-    q: (R, C, Hq, hd) — row r is one ingesting slot's chunk queries (chunk
-    K/V already written); k/v_pages: (n_pages, page, Hkv, hd); block_rows:
-    (R, P) int32 per-row page ids (-1 = unmapped); offsets/lens: (R,) int32.
-    Grid (R, Hkv, P): the innermost axis walks row r's pages with the same
-    per-row scalar-prefetched clamp/prune as the single-slot kernel, so the
-    streamed volume is O(sum_r (offsets[r] + lens[r])). -> (R, C, Hq, hd);
-    row r positions past lens[r] (and all of padding rows, lens[r] == 0)
-    are unspecified."""
+def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
+                   interpret: bool):
+    """Shared ragged wrapper; `scales` is None or (k_scales, v_scales)."""
     R, C, Hq, hd = q.shape
     ps, Hkv = k_pages.shape[1], k_pages.shape[2]
     P = block_rows.shape[1]
@@ -237,26 +106,37 @@ def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
     # (R, Hkv, rep, C, hd): group each row's q heads by their kv head
     qg = jnp.moveaxis(q, 2, 1).reshape(R, Hkv, rep, C, hd)
 
-    def kv_map(r, h, p, rows_ref, info_ref):
+    def page_of(r, p, rows_ref, info_ref):
         # steps past row r's covering range re-stream its last live page:
         # Pallas skips the DMA for a block index equal to the previous
         # step's, so pruned pages cost neither bandwidth nor compute
         n_live = jax.lax.div(info_ref[r, 0] + info_ref[r, 1] + ps - 1, ps)
         pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        pg = rows_ref[r, pi]
-        return (jnp.maximum(pg, 0), 0, h, 0)
+        return jnp.maximum(rows_ref[r, pi], 0)
 
-    kernel = functools.partial(_paged_pref_ragged_kernel, np_=P, ps=ps, C=C,
-                               rep=rep, scale=1.0 / float(hd) ** 0.5)
+    def kv_map(r, h, p, rows_ref, info_ref):
+        return (page_of(r, p, rows_ref, info_ref), 0, h)
+
+    def scale_map(r, h, p, rows_ref, info_ref):
+        return (page_of(r, p, rows_ref, info_ref), 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, 1, rep, C, hd),
+                     lambda r, h, p, *_: (r, h, 0, 0, 0)),
+        pl.BlockSpec((1, ps, hd), kv_map),
+        pl.BlockSpec((1, ps, hd), kv_map),
+    ]
+    operands = [qg, lane_view(k_pages), lane_view(v_pages)]
+    if scales is not None:
+        in_specs += [pl.BlockSpec((1, 1, Hkv), scale_map)] * 2
+        operands += [scale_view(s) for s in scales]
+    kernel = functools.partial(_paged_pref_kernel, np_=P, ps=ps, C=C,
+                               rep=rep, scale=1.0 / float(hd) ** 0.5,
+                               quant=scales is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(R, Hkv, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, C, hd), lambda r, h, p, *_: (r, h, 0, 0,
-                                                                  0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, rep, C, hd),
                                lambda r, h, p, *_: (r, h, 0, 0, 0)),
         scratch_shapes=[
@@ -270,231 +150,58 @@ def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, rep, C, hd), q.dtype),
         interpret=interpret,
-    )(rows, info, qg, k_pages, v_pages)
+    )(rows, info, *operands)
     # (R, Hkv, rep, C, hd) -> (R, C, Hq, hd) with head index h = kv*rep + r
     return jnp.moveaxis(out.reshape(R, Hq, C, hd), 1, 2)
 
 
-def _paged_pref_kernel_quant(row_ref,          # scalar prefetch: (P,) pages
-                             info_ref,         # scalar prefetch: (2,) off,len
-                             q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                             m_scr, l_scr, acc_scr,
-                             *, np_: int, ps: int, C: int, rep: int,
-                             scale: float):
-    """Quantized-pool variant of `_paged_pref_kernel`: each page tile is
-    dequantized in VMEM right after the DMA with its streamed
-    per-(page, kv-head) scale scalar."""
-    pi = pl.program_id(1)
+def _one_row(block_row, offset, chunk_len):
+    """Single-slot arguments as a one-row ragged batch."""
+    return (block_row[None],
+            jnp.asarray(offset, jnp.int32).reshape(1),
+            jnp.asarray(chunk_len, jnp.int32).reshape(1))
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    total = info_ref[0] + info_ref[1]          # offset + chunk_len
-    page = row_ref[pi]
-    s_start = pi * ps
+def paged_prefill_attention_pallas(q, k_pages, v_pages, block_row, offset,
+                                   chunk_len, *, interpret: bool):
+    """q: (1, C, Hq, hd) one slot's chunk queries; k/v_pages: (n_pages,
+    page, Hkv, hd) with the chunk already written; block_row: (P,) int32
+    page ids (-1 = unmapped); offset/chunk_len: () int32. ->
+    (1, C, Hq, hd); rows past chunk_len are unspecified."""
+    return _paged_prefill(q, k_pages, v_pages, None,
+                          *_one_row(block_row, offset, chunk_len), interpret)
 
-    @pl.when((s_start < total) & (page >= 0))
-    def _body():
-        kpos = s_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        kvalid = kpos < total                   # (ps, 1)
-        q = q_ref[0].reshape(rep * C, -1).astype(jnp.float32)
-        k = jnp.where(kvalid,
-                      k_ref[0].astype(jnp.float32)[:, 0] * ks_ref[0, 0], 0.0)
-        v = jnp.where(kvalid,
-                      v_ref[0].astype(jnp.float32)[:, 0] * vs_ref[0, 0], 0.0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        qpos = info_ref[0] + jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (rep * C, 1), 0), C)
-        m = kvalid[:, 0][None, :] & (kpos[:, 0][None, :] <= qpos)
-        s = jnp.where(m, s, NEG_INF)
 
-        m_prev = m_scr[...][:, 0]
-        l_prev = l_scr[...][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(m, p, 0.0)               # rows with no valid key yet
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_prev * alpha + jnp.sum(p, axis=1))[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
+def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
+                                          offsets, lens, *,
+                                          interpret: bool):
+    """Multi-slot ragged chunk attention: the batched-ingest extension of
+    `paged_prefill_attention_pallas`.
 
-    @pl.when(pi == np_ - 1)
-    def _finish():
-        l = l_scr[...][:, 0]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        hd = acc_scr.shape[-1]
-        o_ref[0] = (acc_scr[...] / denom[:, None]).reshape(
-            rep, C, hd).astype(o_ref.dtype)
+    q: (R, C, Hq, hd) — row r is one ingesting slot's chunk queries (chunk
+    K/V already written); k/v_pages: (n_pages, page, Hkv, hd); block_rows:
+    (R, P) int32 per-row page ids (-1 = unmapped); offsets/lens: (R,) int32.
+    Grid (R, Hkv, P): the innermost axis walks row r's pages with a per-row
+    scalar-prefetched clamp/prune, so the streamed volume is
+    O(sum_r (offsets[r] + lens[r])). -> (R, C, Hq, hd); row r positions past
+    lens[r] (and all of padding rows, lens[r] == 0) are unspecified."""
+    return _paged_prefill(q, k_pages, v_pages, None, block_rows, offsets,
+                          lens, interpret)
 
 
 def paged_prefill_attention_quant_pallas(q, k_pages, v_pages, k_scales,
                                          v_scales, block_row, offset,
-                                         chunk_len, *, interpret: bool = True):
+                                         chunk_len, *, interpret: bool):
     """`paged_prefill_attention_pallas` over a quantized pool (k/v_scales:
-    (n_pages, Hkv) f32, streamed as (1, 1) blocks through the same clamped
-    block-row index map as their page)."""
-    _, C, Hq, hd = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
-    P = block_row.shape[0]
-    rep = Hq // Hkv
-    row = block_row.astype(jnp.int32)
-    info = jnp.stack([jnp.asarray(offset, jnp.int32).reshape(()),
-                      jnp.asarray(chunk_len, jnp.int32).reshape(())])
-
-    qg = jnp.moveaxis(q[0], 1, 0).reshape(Hkv, rep, C, hd)
-
-    def kv_map(h, p, row_ref, info_ref):
-        n_live = jax.lax.div(info_ref[0] + info_ref[1] + ps - 1, ps)
-        pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        pg = row_ref[pi]
-        return (jnp.maximum(pg, 0), 0, h, 0)
-
-    def scale_map(h, p, row_ref, info_ref):
-        n_live = jax.lax.div(info_ref[0] + info_ref[1] + ps - 1, ps)
-        pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        pg = row_ref[pi]
-        return (jnp.maximum(pg, 0), h)
-
-    kernel = functools.partial(_paged_pref_kernel_quant, np_=P, ps=ps, C=C,
-                               rep=rep, scale=1.0 / float(hd) ** 0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Hkv, P),
-        in_specs=[
-            pl.BlockSpec((1, rep, C, hd), lambda h, p, *_: (h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, rep, C, hd),
-                               lambda h, p, *_: (h, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep * C, 1), jnp.float32),
-            pltpu.VMEM((rep * C, 1), jnp.float32),
-            pltpu.VMEM((rep * C, hd), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, rep, C, hd), q.dtype),
-        interpret=interpret,
-    )(row, info, qg, k_pages, v_pages, k_scales, v_scales)
-    return jnp.moveaxis(out.reshape(Hq, C, hd), 0, 1)[None]
-
-
-def _paged_pref_ragged_kernel_quant(rows_ref,  # scalar prefetch: (R, P) pages
-                                    info_ref,  # scalar prefetch: (R, 2)
-                                    q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                                    o_ref, m_scr, l_scr, acc_scr,
-                                    *, np_: int, ps: int, C: int, rep: int,
-                                    scale: float):
-    """Quantized-pool variant of `_paged_pref_ragged_kernel`."""
-    r = pl.program_id(0)
-    pi = pl.program_id(2)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    total = info_ref[r, 0] + info_ref[r, 1]    # offset + chunk_len
-    page = rows_ref[r, pi]
-    s_start = pi * ps
-
-    @pl.when((s_start < total) & (page >= 0))
-    def _body():
-        kpos = s_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        kvalid = kpos < total                   # (ps, 1)
-        q = q_ref[0, 0].reshape(rep * C, -1).astype(jnp.float32)
-        k = jnp.where(kvalid,
-                      k_ref[0].astype(jnp.float32)[:, 0] * ks_ref[0, 0], 0.0)
-        v = jnp.where(kvalid,
-                      v_ref[0].astype(jnp.float32)[:, 0] * vs_ref[0, 0], 0.0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        qpos = info_ref[r, 0] + jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (rep * C, 1), 0), C)
-        m = kvalid[:, 0][None, :] & (kpos[:, 0][None, :] <= qpos)
-        s = jnp.where(m, s, NEG_INF)
-
-        m_prev = m_scr[...][:, 0]
-        l_prev = l_scr[...][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(m, p, 0.0)               # rows with no valid key yet
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_prev * alpha + jnp.sum(p, axis=1))[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
-
-    @pl.when(pi == np_ - 1)
-    def _finish():
-        l = l_scr[...][:, 0]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        hd = acc_scr.shape[-1]
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).reshape(
-            rep, C, hd).astype(o_ref.dtype)
+    (n_pages, Hkv) f32, streamed as (1, 1, Hkv) blocks through the same
+    clamped block-row index map as their page)."""
+    return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
+                          *_one_row(block_row, offset, chunk_len), interpret)
 
 
 def paged_prefill_attention_ragged_quant_pallas(q, k_pages, v_pages, k_scales,
                                                 v_scales, block_rows, offsets,
-                                                lens, *,
-                                                interpret: bool = True):
+                                                lens, *, interpret: bool):
     """`paged_prefill_attention_ragged_pallas` over a quantized pool."""
-    R, C, Hq, hd = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
-    P = block_rows.shape[1]
-    rep = Hq // Hkv
-    rows = block_rows.astype(jnp.int32)
-    info = jnp.stack([jnp.asarray(offsets, jnp.int32),
-                      jnp.asarray(lens, jnp.int32)], axis=1)       # (R, 2)
-
-    qg = jnp.moveaxis(q, 2, 1).reshape(R, Hkv, rep, C, hd)
-
-    def kv_map(r, h, p, rows_ref, info_ref):
-        n_live = jax.lax.div(info_ref[r, 0] + info_ref[r, 1] + ps - 1, ps)
-        pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        pg = rows_ref[r, pi]
-        return (jnp.maximum(pg, 0), 0, h, 0)
-
-    def scale_map(r, h, p, rows_ref, info_ref):
-        n_live = jax.lax.div(info_ref[r, 0] + info_ref[r, 1] + ps - 1, ps)
-        pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        pg = rows_ref[r, pi]
-        return (jnp.maximum(pg, 0), h)
-
-    kernel = functools.partial(_paged_pref_ragged_kernel_quant, np_=P, ps=ps,
-                               C=C, rep=rep, scale=1.0 / float(hd) ** 0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(R, Hkv, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, C, hd), lambda r, h, p, *_: (r, h, 0, 0,
-                                                                  0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, C, hd),
-                               lambda r, h, p, *_: (r, h, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep * C, 1), jnp.float32),
-            pltpu.VMEM((rep * C, 1), jnp.float32),
-            pltpu.VMEM((rep * C, hd), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Hkv, rep, C, hd), q.dtype),
-        interpret=interpret,
-    )(rows, info, qg, k_pages, v_pages, k_scales, v_scales)
-    return jnp.moveaxis(out.reshape(R, Hq, C, hd), 1, 2)
+    return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
+                          block_rows, offsets, lens, interpret)

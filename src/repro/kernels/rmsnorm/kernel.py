@@ -22,8 +22,8 @@ def _rms_kernel(x_ref, s_ref, o_ref, *, eps: float):
                   * s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def rmsnorm_pallas(x, scale, eps: float = 1e-6, block_rows: int = 256,
-                   interpret: bool = True):
+def rmsnorm_pallas(x, scale, eps: float = 1e-6, block_rows: int = 256, *,
+                   interpret: bool):
     orig_shape = x.shape
     D = x.shape[-1]
     xf = x.reshape(-1, D)

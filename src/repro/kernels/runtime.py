@@ -1,9 +1,13 @@
 """Shared kernel-runtime policy helpers.
 
-Every Pallas wrapper in repro.kernels takes `interpret: Optional[bool]`;
-`None` resolves through `default_interpret()` so the same call sites compile
-to real Mosaic kernels on TPU and fall back to interpret mode everywhere
-else (CPU tests / CI) without per-caller plumbing.
+Every public Pallas wrapper in repro.kernels (`ops.py`) takes
+`interpret: Optional[bool]`; `None` resolves through `default_interpret()`,
+so the served call sites compile real Mosaic kernels on a TPU. Interpret
+mode exists for the CPU test suite only: it runs the same kernel bodies in
+Python to check them against their `ref.py` oracles, and says nothing about
+whether the TPU compiler accepts them (tests/test_tpu_compile.py does). The
+`*_pallas` functions in each `kernel.py` take `interpret` as a required
+keyword, so no direct caller lands in interpret mode by default.
 """
 from __future__ import annotations
 

@@ -73,7 +73,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,   # inputs
         state_out_ref[0] = h_new.astype(state_out_ref.dtype)
 
 
-def ssd_pallas(x, dt, A, B, C, chunk: int = 128, interpret: bool = True):
+def ssd_pallas(x, dt, A, B, C, chunk: int = 128, *, interpret: bool):
     """x: (Bb,S,H,P), dt: (Bb,S,H), A: (H,), B/C: (Bb,S,N).
 
     Returns (y (Bb,S,H,P) f32, final_state (Bb,H,P,N) f32).

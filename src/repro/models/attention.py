@@ -2,7 +2,9 @@
 
 Pure-jnp reference implementations; `cfg.use_pallas=True` routes the hot paths
 through the Pallas kernels in repro.kernels (flash_attention for prefill,
-decode_attention for cached decode).
+decode_attention for cached decode, the paged kernels for the paged cache).
+Whether the cached-attention kernels can serve a config is decided once, by
+`pallas_unsupported`; the cached paths below never fall back per call.
 """
 from __future__ import annotations
 
@@ -16,6 +18,33 @@ from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope, dense_init, rmsnorm
 
 NEG_INF = -1e30
+
+
+def pallas_unsupported(cfg: ModelConfig, paged: bool) -> str:
+    """Why the cached-attention Pallas kernels (dense decode, or with
+    `paged` the paged decode and chunk-ingest kernels) cannot serve `cfg`
+    on this backend; '' when they can. The serving engine asks once, at
+    construction, and reads through the jnp oracle when the answer is not
+    empty."""
+    if cfg.attn_logit_softcap:
+        return "attn_logit_softcap is set and the kernels apply no softcap"
+    if cfg.sliding_window:
+        return "sliding_window is set and the kernels attend the whole cache"
+    from repro.analysis.rules import LANE_MULTIPLE
+    from repro.kernels.runtime import default_interpret
+    hd = cfg.resolved_head_dim
+    if paged and not default_interpret() and hd % LANE_MULTIPLE:
+        return (f"head_dim {hd} is not a multiple of {LANE_MULTIPLE}, which "
+                "the TPU block rule needs for a (page, head_dim) tile")
+    return ""
+
+
+def _require_kernels(cfg: ModelConfig, paged: bool = True) -> None:
+    """Trace-time guard on the cached paths: use_pallas on a config the
+    kernels cannot serve is an error, never a silent fallback."""
+    why = pallas_unsupported(cfg, paged)
+    if why:
+        raise ValueError(f"{cfg.name}: use_pallas=True but {why}")
 
 
 # ---------------------------------------------------------------------------
@@ -250,7 +279,8 @@ def attention_decode(cfg: ModelConfig, params: dict, x: jax.Array,
     layer_k, layer_v = cache_lib.update_layer_kv(layer_k, layer_v, lengths,
                                                  k, v, window=window)
     Sc = layer_k.shape[1]
-    if cfg.use_pallas and T == 1 and not window:
+    if cfg.use_pallas and T == 1:
+        _require_kernels(cfg, paged=False)
         from repro.kernels.decode_attention import ops as da_ops
         out = da_ops.decode_attention(q, layer_k, layer_v, lengths + T)
     else:
@@ -320,7 +350,8 @@ def attention_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
         k_pages, v_pages, k_scales, v_scales = pc.write_token_quant(
             k_pages, v_pages, k_scales, v_scales, block_table, lengths,
             k, v, cfg.kv_dtype, active=active)
-        if cfg.use_pallas and T == 1 and not cfg.attn_logit_softcap:
+        if cfg.use_pallas:
+            _require_kernels(cfg)
             from repro.kernels.paged_decode_attention import ops as pda_ops
             out = pda_ops.paged_decode_attention_quant(
                 q, k_pages, v_pages, k_scales, v_scales, table, lengths + T)
@@ -338,7 +369,8 @@ def attention_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
         return out, k_pages, v_pages, k_scales, v_scales
     k_pages, v_pages = pc.write_token(k_pages, v_pages, block_table, lengths,
                                       k, v, active=active)
-    if cfg.use_pallas and T == 1 and not cfg.attn_logit_softcap:
+    if cfg.use_pallas:
+        _require_kernels(cfg)
         from repro.kernels.paged_decode_attention import ops as pda_ops
         # the new token was just written at position `lengths`
         out = pda_ops.paged_decode_attention(q, k_pages, v_pages, table,
@@ -396,7 +428,8 @@ def attention_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
         k_pages, v_pages, k_scales, v_scales = pc.write_prompt_quant(
             k_pages, v_pages, k_scales, v_scales, block_row, k, v,
             chunk_len, cfg.kv_dtype, offset=offset)
-        if cfg.use_pallas and not cfg.attn_logit_softcap:
+        if cfg.use_pallas:
+            _require_kernels(cfg)
             from repro.kernels.paged_prefill_attention import ops as ppa_ops
             out = ppa_ops.paged_prefill_attention_quant(
                 q, k_pages, v_pages, k_scales, v_scales, row, offset,
@@ -415,7 +448,8 @@ def attention_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
         return out, k_pages, v_pages, k_scales, v_scales
     k_pages, v_pages = pc.write_prompt(k_pages, v_pages, block_row, k, v,
                                        chunk_len, offset=offset)
-    if cfg.use_pallas and not cfg.attn_logit_softcap:
+    if cfg.use_pallas:
+        _require_kernels(cfg)
         from repro.kernels.paged_prefill_attention import ops as ppa_ops
         out = ppa_ops.paged_prefill_attention(q, k_pages, v_pages, row,
                                               offset, chunk_len)
@@ -473,7 +507,8 @@ def attention_prefill_ragged_paged(cfg: ModelConfig, params: dict,
         k_pages, v_pages, k_scales, v_scales = pc.write_prompt_ragged_quant(
             k_pages, v_pages, k_scales, v_scales, block_rows, k, v, lens,
             offsets, cfg.kv_dtype)
-        if cfg.use_pallas and not cfg.attn_logit_softcap:
+        if cfg.use_pallas:
+            _require_kernels(cfg)
             from repro.kernels.paged_prefill_attention import ops as ppa_ops
             out = ppa_ops.paged_prefill_attention_ragged_quant(
                 q, k_pages, v_pages, k_scales, v_scales, rows, offsets, lens)
@@ -491,7 +526,8 @@ def attention_prefill_ragged_paged(cfg: ModelConfig, params: dict,
         return out, k_pages, v_pages, k_scales, v_scales
     k_pages, v_pages = pc.write_prompt_ragged(k_pages, v_pages, block_rows,
                                               k, v, lens, offsets)
-    if cfg.use_pallas and not cfg.attn_logit_softcap:
+    if cfg.use_pallas:
+        _require_kernels(cfg)
         from repro.kernels.paged_prefill_attention import ops as ppa_ops
         out = ppa_ops.paged_prefill_attention_ragged(q, k_pages, v_pages,
                                                      rows, offsets, lens)

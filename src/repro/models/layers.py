@@ -128,7 +128,8 @@ def init_embedding(cfg: ModelConfig, key) -> dict:
 
 
 def embed(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array:
-    return params["tok"].astype(_dt(cfg))[tokens]
+    # gather, then cast: casting first converts the whole (vocab, d) table
+    return params["tok"][tokens].astype(_dt(cfg))
 
 
 def unembed(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:

@@ -32,12 +32,14 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
+import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import attention as attn_lib
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.models.paged_cache import PageAllocator
@@ -290,6 +292,19 @@ class InferenceEngine:
                  n_pages: Optional[int] = None, prefix_sharing: bool = True,
                  ragged_ingest: bool = True, host_swap: bool = True):
         assert kv_backend in ("dense", "paged"), kv_backend
+        # The attention read path is chosen here, once, for every jitted
+        # step this engine runs: the Pallas kernels when the config asks
+        # for them and they can serve it on this backend, else the jnp
+        # gather oracle, with the reason kept and warned.
+        self.read_path_note = (
+            attn_lib.pallas_unsupported(cfg, paged=kv_backend == "paged")
+            if cfg.use_pallas else "")
+        if self.read_path_note:
+            warnings.warn(f"{name}: use_pallas=True cannot be served "
+                          f"({self.read_path_note}); attention reads "
+                          "through the jnp oracle")
+            cfg = cfg.with_(use_pallas=False)
+        self.read_path = "pallas" if cfg.use_pallas else "oracle"
         self.cfg = cfg
         self.params = params
         self.max_batch = max_batch
@@ -1316,9 +1331,11 @@ class InferenceEngine:
                             jnp.zeros((rb,), jnp.int32),
                             jnp.zeros((rb,), jnp.int32))
                         count += 1
-            elif self.prefill_chunk:
-                # serial fallback scheduler: warm the single-slot chunk
-                # variants instead (zero-length chunk: every write drops)
+            if self.prefill_chunk and (self.prefix_sharing
+                                       or not self.ragged_ingest):
+                # single-slot chunk variants: the serial fallback scheduler
+                # and prefill_prefix (the PICE fan-out's shared prefix) run
+                # them (zero-length chunk: every write drops)
                 for live in lives:
                     _, self.cache = self._prefill_chunk(
                         live, self.params,
@@ -1326,7 +1343,7 @@ class InferenceEngine:
                         self.cache, jnp.asarray(0, jnp.int32),
                         jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
                     count += 1
-            elif prompt_lens:
+            if prompt_lens and not self.prefill_chunk:
                 for S in sorted({min(_bucket(n), self.max_len)
                                  for n in prompt_lens}):
                     self._sync_table()

@@ -191,6 +191,67 @@ def test_attention_decode_paged_trim_bit_identical():
     np.testing.assert_array_equal(np.asarray(full), np.asarray(trim))
 
 
+def test_attention_paged_refuses_a_config_the_kernels_cannot_serve():
+    """use_pallas on a softcapped config is an error at trace time, never
+    a silent per-call fallback to the oracle."""
+    B, page, P, n_pages = 2, 8, 4, 8
+    cfg, params, ks = _paged_attn_setup(True)
+    cfg = cfg.with_(attn_logit_softcap=30.0)
+    x = jax.random.normal(ks[1], (B, 1, cfg.d_model), jnp.float32)
+    kp, vp = _pools(ks[2], n_pages, page, cfg.n_kv_heads,
+                    cfg.resolved_head_dim, jnp.float32)
+    table = _chained_table(np.array([5, 9]), page, P)
+    with pytest.raises(ValueError, match="softcap"):
+        attn_lib.attention_decode_paged(cfg, params, x, kp, vp, table,
+                                        jnp.asarray([5, 9], jnp.int32))
+
+
+@pytest.mark.parametrize("override,path", [
+    (dict(), "pallas"),
+    (dict(attn_logit_softcap=30.0), "oracle"),
+    (dict(use_pallas=False), "oracle"),
+])
+def test_engine_picks_its_read_path_at_construction(override, path):
+    """The engine decides Pallas vs oracle once and says why it did not
+    take the kernels when the config asked for them."""
+    from repro.serving.engine import InferenceEngine
+    cfg, _, _ = _paged_attn_setup(True)
+    cfg = cfg.with_(**override)
+    if path == "oracle" and cfg.use_pallas:
+        with pytest.warns(UserWarning, match="softcap"):
+            eng = InferenceEngine(cfg, None, max_batch=2, max_len=64,
+                                  kv_backend="paged", page_size=8)
+        assert "softcap" in eng.read_path_note
+    else:
+        eng = InferenceEngine(cfg, None, max_batch=2, max_len=64,
+                              kv_backend="paged", page_size=8)
+        assert eng.read_path_note == ""
+    assert eng.read_path == path
+    assert eng.cfg.use_pallas == (path == "pallas")
+
+
+@pytest.mark.parametrize("kv_backend,path", [("paged", "oracle"),
+                                             ("dense", "pallas")])
+def test_head_dim_rule_binds_only_the_paged_read_path(monkeypatch,
+                                                       kv_backend, path):
+    """On a TPU (compiled Pallas) a head_dim off the 128-lane tile rules out
+    the paged kernels' (page, head_dim) blocks, but not the dense decode
+    kernel, so a dense engine keeps its kernels."""
+    from repro.kernels import runtime
+    from repro.serving.engine import InferenceEngine
+    monkeypatch.setattr(runtime, "default_interpret", lambda: False)
+    cfg, _, _ = _paged_attn_setup(True)          # head_dim 32
+    if path == "oracle":
+        with pytest.warns(UserWarning, match="head_dim 32"):
+            eng = InferenceEngine(cfg, None, max_batch=2, max_len=64,
+                                  kv_backend=kv_backend, page_size=8)
+    else:
+        eng = InferenceEngine(cfg, None, max_batch=2, max_len=64,
+                              kv_backend=kv_backend, page_size=8)
+        assert eng.read_path_note == ""
+    assert eng.read_path == path
+
+
 def test_validate_paged_alignment():
     cfg = ModelConfig()
     cfg.validate_paged(16, 256)

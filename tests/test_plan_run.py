@@ -1,7 +1,8 @@
 """Plan/run engine step: batched ragged ingest vs the serial fallback vs
-monolithic prefill (three-way bit-identity), the one-table-push-per-step
-contract, admission-stamp pruning under churn, surfaced prompt truncation,
-and the bounded score buffer."""
+monolithic prefill (three-way agreement: identical tokens, logprobs within
+float32 rounding), the one-table-push-per-step contract, admission-stamp
+pruning under churn, surfaced prompt truncation, and the bounded score
+buffer."""
 import jax
 import numpy as np
 import pytest
@@ -33,11 +34,21 @@ def _engine(params, chunk=0, **kw):
     return InferenceEngine(cfg, params, **kw)
 
 
+# The three schedulers batch different rows into each call (one chunk per
+# step, R rows per step, or one monolithic prefill), and XLA picks kernels
+# and reduction orders by shape, so logprobs agree to float32 rounding, not
+# bit for bit: on XLA:CPU under JAX 0.9.0 they differ by at most 1.9e-6.
+# 1e-5 is five times that and far below any change of the chosen token.
+# Tokens stay exactly equal.
+LOGPROB_ATOL = 1e-5
+
+
 def _assert_same(a, b):
     for i, ((ta, la), (tb, lb)) in enumerate(zip(a, b)):
         assert ta == tb, f"request {i}: tokens diverge"
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
-                                      err_msg=f"request {i}: logprobs diverge")
+        np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
+                                   rtol=0, atol=LOGPROB_ATOL,
+                                   err_msg=f"request {i}: logprobs diverge")
 
 
 def _assert_same_replay(a, b):
@@ -49,7 +60,7 @@ def _assert_same_replay(a, b):
 
 
 # ---------------------------------------------------------------------------
-# three-way bit-identity: batched ragged == serial one-chunk == monolithic
+# three-way agreement: batched ragged == serial one-chunk == monolithic
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("chunk", [16, 48])
@@ -81,8 +92,9 @@ def test_three_way_sampled_serialized(params):
 
 def test_three_way_fork_suffixes(params):
     """Fork fan-out: suffix replay rides the (batched) chunk path; serial
-    and batched must agree bitwise, and both match monolithic up to the
-    documented (1, V)-vs-(B, V) unembed ulp on the post-replay logprob."""
+    and batched must agree (identical tokens, LOGPROB_ATOL), and both match
+    monolithic up to the documented (1, V)-vs-(B, V) unembed ulp on the
+    post-replay logprob."""
     prefix = [(i % 100) + 1 for i in range(70)]
     suffixes = [[5, 6, 7], [9], [11] * 20]
     mono = _engine(params, chunk=0, max_batch=4)

@@ -72,3 +72,31 @@ def test_sampler_greedy_vs_temperature(engine):
     assert g1 == g2, "greedy must be deterministic"
     (h1, _), = hot.generate([[65, 66]], max_new=10)
     assert len(h1) >= 1
+
+
+@pytest.mark.parametrize("param_dtype", ["float32", "bfloat16"])
+def test_build_engines_serves_params_only(monkeypatch, param_dtype):
+    """Serving (train_steps=0) builds params in the config's param_dtype
+    and never the optimizer state (which at qwen3-8b width would not fit
+    one chip beside the fleet)."""
+    from repro.configs.pice_cloud_edge import FleetMember, Pairing
+    from repro.launch import serve
+
+    def no_optimizer(*args, **kwargs):
+        raise AssertionError("optimizer state built for serving")
+    monkeypatch.setattr(serve, "init_train_state", no_optimizer)
+    monkeypatch.setattr(serve, "MAX_BATCH", 2)
+    monkeypatch.setattr(serve, "MAX_LEN", 64)
+    cfg = TINY_EDGE_A.with_(param_dtype=param_dtype)
+    pair = Pairing(cloud="c", members={"c": FleetMember(cfg, 0.9),
+                                       "e": FleetMember(cfg, 0.7, seed=1)})
+    engines, caps = serve.build_engines(pair, train_steps=0, seed=3)
+    assert set(engines) == {"c", "e"} and caps == {"c": 0.9, "e": 0.7}
+    leaves = jax.tree.leaves(engines["c"].params)
+    assert {leaf.dtype for leaf in leaves} == {jnp.dtype(param_dtype)}
+    # members draw their weights from the launch seed plus their own offset
+    expect = jax.jit(transformer.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(4))
+    np.testing.assert_array_equal(
+        np.asarray(engines["e"].params["embed"]["tok"], np.float32),
+        np.asarray(expect["embed"]["tok"], np.float32))
