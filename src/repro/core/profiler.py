@@ -128,13 +128,18 @@ class RuntimeMonitor:
     admission_rejects: int = 0      # progressive path refused on forecast
     #                                 KV occupancy (scheduler admission gate)
     degraded: Dict[str, int] = dataclasses.field(default_factory=dict)
-    # arrival-relative request telemetry (serving front-end + pipeline):
-    # TTFT and end-to-end latency measured FROM ARRIVAL — queue wait
-    # included — not from admission. Bounded windows (BoundedRecord) so a
-    # long-running fleet keeps the most recent ~4096 samples.
+    # arrival-relative request telemetry: TTFT and end-to-end latency
+    # measured FROM ARRIVAL — queue wait included — not from admission.
+    # `ttft_window` / `latency_window` hold the engine front-ends'
+    # requests (a PICE request's sketch and expansion sub-requests among
+    # them); `request_latency_window` holds the pipeline's user requests,
+    # each once. Bounded windows (BoundedRecord) so a long-running fleet
+    # keeps the most recent ~4096 samples.
     ttft_window: BoundedRecord = dataclasses.field(
         default_factory=BoundedRecord)
     latency_window: BoundedRecord = dataclasses.field(
+        default_factory=BoundedRecord)
+    request_latency_window: BoundedRecord = dataclasses.field(
         default_factory=BoundedRecord)
 
     def on_enqueue(self, expected_tokens: float):
@@ -177,14 +182,23 @@ class RuntimeMonitor:
         self.ttft_window.append(float(ttft_s))
 
     def record_latency(self, latency_s: float):
-        """A request finished `latency_s` seconds after arrival."""
+        """A front-end request finished `latency_s` seconds after
+        arrival."""
         self.latency_window.append(float(latency_s))
+
+    def record_request_latency(self, latency_s: float):
+        """A user request's stitched response was ready `latency_s`
+        seconds after its arrival."""
+        self.request_latency_window.append(float(latency_s))
 
     def ttft_percentile(self, q: float) -> float:
         return self.ttft_window.percentile(q)
 
     def latency_percentile(self, q: float) -> float:
         return self.latency_window.percentile(q)
+
+    def request_latency_percentile(self, q: float) -> float:
+        return self.request_latency_window.percentile(q)
 
     @property
     def edge_failure_rate(self) -> float:

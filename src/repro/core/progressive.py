@@ -19,6 +19,13 @@ engines. Ensemble members expand concurrently (`handle_async` gathers
 them on one event loop), and many in-flight `handle_async` calls share one
 engine fleet — the serving front-end's load path. `handle` is the
 synchronous single-request facade over it.
+
+With the tracer on (core/trace.py), each request records the intervals
+`pipeline.request` (arrival to response), `pipeline.sketch` (arrival to the
+sketch's return) and `pipeline.expand` (the sketch's return to the last
+member's return, with one `pipeline.member` child per ensemble member),
+and the `pipeline.ensemble` span; every record made on its behalf carries
+its id.
 """
 from __future__ import annotations
 
@@ -28,7 +35,7 @@ import time
 from typing import Dict, List, Optional
 
 from repro.core import ensemble as ens
-from repro.core import exec_optimizer, sketch as sketch_lib
+from repro.core import exec_optimizer, sketch as sketch_lib, trace
 from repro.core.dispatch import MultiListQueue
 from repro.core.profiler import LatencyModel, RuntimeMonitor
 from repro.core.scheduler import DynamicScheduler, EdgeModelInfo, ScheduleDecision
@@ -114,9 +121,10 @@ class PICEPipeline:
         if resp.degraded:
             self.monitor.record_degraded(resp.degraded)
         resp.queue_wait_s = queue_wait_s
-        # arrival-relative end-to-end latency window (queue wait included
-        # when the request carried an arrival stamp)
-        self.monitor.record_latency(resp.latency_s)
+        # arrival-relative end-to-end latency of the user request (queue
+        # wait included when the request carried an arrival stamp); the
+        # front-ends record its sub-requests in a window of their own
+        self.monitor.record_request_latency(resp.latency_s)
         return resp
 
     # ------------------------------------------------------------------
@@ -157,6 +165,24 @@ class PICEPipeline:
         return asyncio.run(self.handle_async(req))
 
     async def handle_async(self, req: Request) -> Response:
+        if not trace.enabled():
+            return await self._handle(req)
+        start = trace.from_perf(req.arrival_time_s) \
+            if req.arrival_time_s is not None else trace.now()
+        token = trace.REQUEST.set(req.req_id)
+        try:
+            resp = await self._handle(req, start)
+            trace.interval("pipeline.request", start, trace.now(),
+                           mode=resp.mode, degraded=resp.degraded,
+                           net_delay_s=resp.network_s)
+            return resp
+        finally:
+            trace.REQUEST.reset(token)
+
+    async def _handle(self, req: Request,
+                      start_ns: Optional[int] = None) -> Response:
+        """`handle_async`'s body; `start_ns` is the request's arrival on
+        the tracer's clock when the tracer is on."""
         now = time.perf_counter()
         # latency (and the SLA deadline) anchor at ARRIVAL when the request
         # carries a stamp — time queued upstream counts against the budget
@@ -197,6 +223,11 @@ class PICEPipeline:
             max_new=min(decision.sketch_tokens + 10,
                         self.cfg.max_sketch_tokens),
             deadline_s=deadline, role="sketch")
+        t_sketch, expand_id = 0, None
+        if start_ns is not None:
+            t_sketch = trace.now()
+            trace.interval("pipeline.sketch", start_ns, t_sketch)
+            expand_id = trace.new_id()
         sketch_text = sketch_text.strip()
         sentences = sketch_lib.segment_sketch(sketch_text)
         if not sentences:
@@ -291,6 +322,7 @@ class PICEPipeline:
             prio = 1 if name == primary else 0
             role = "expansion_primary" if name == primary \
                 else "expansion_extra"
+            t0 = trace.now() if expand_id is not None else 0
             try:
                 outs = await eng.generate_fanout_async(
                     prefix_toks, suffix_toks, max_new=max_new,
@@ -303,6 +335,10 @@ class PICEPipeline:
                 fault("edge_" + type(exc).__name__)
                 return name, None
             self.monitor.record_edge_result(True)
+            if expand_id is not None:
+                trace.interval("pipeline.member", t0, trace.now(),
+                               parent=expand_id, engine=name, role=role,
+                               groups=len(suffix_toks))
             return name, outs
 
         launched = []
@@ -320,6 +356,10 @@ class PICEPipeline:
         member_outs = await asyncio.gather(*launched) if launched else []
         group_results = {n: outs for n, outs in member_outs
                          if outs is not None}
+        if expand_id is not None:
+            trace.interval("pipeline.expand", t_sketch, trace.now(),
+                           span_id=expand_id,
+                           groups=len(suffix_toks) * len(group_results))
         if not group_results:
             # every member faulted or the deadline arrived before any could
             # launch: the edge path produced nothing
@@ -329,28 +369,29 @@ class PICEPipeline:
                 queue_wait_s=queue_wait)
         degraded = "ensemble_partial" if len(group_results) < len(names) \
             else ""
-        for gi in range(len(plan.groups)):
-            cands = []
-            for name, outs in group_results.items():
-                out, lps = outs[gi]
-                if not out:
-                    continue      # deadline-cancelled before its first token
-                cands.append(ens.Candidate(
-                    text=tok.decode(out).strip(),
-                    mean_log2_prob=ens.mean_log2_from_nats(lps),
-                    n_tokens=len(out), model=name))
-            if not cands:
-                # no member produced this group: the sketch sentences
-                # themselves are the (terse but correct-topic) fallback
-                chosen.append(" ".join(plan.groups[gi]))
-                degraded = "sketch_groups"
-                continue
-            best, scores = ens.select_best(cands, sketch_text,
-                                           self.cfg.alpha1, self.cfg.alpha2)
-            chosen.append(best.text)
-            total_conf += max(scores)
-            edge_tokens += best.n_tokens
-        text = " ".join(chosen).strip()
+        with trace.span("pipeline.ensemble", groups=len(plan.groups)):
+            for gi in range(len(plan.groups)):
+                cands = []
+                for name, outs in group_results.items():
+                    out, lps = outs[gi]
+                    if not out:
+                        continue  # deadline-cancelled before its first token
+                    cands.append(ens.Candidate(
+                        text=tok.decode(out).strip(),
+                        mean_log2_prob=ens.mean_log2_from_nats(lps),
+                        n_tokens=len(out), model=name))
+                if not cands:
+                    # no member produced this group: the sketch sentences
+                    # themselves are the (terse but correct-topic) fallback
+                    chosen.append(" ".join(plan.groups[gi]))
+                    degraded = "sketch_groups"
+                    continue
+                best, scores = ens.select_best(
+                    cands, sketch_text, self.cfg.alpha1, self.cfg.alpha2)
+                chosen.append(best.text)
+                total_conf += max(scores)
+                edge_tokens += best.n_tokens
+            text = " ".join(chosen).strip()
         return self._finish(Response(
             req_id=req.req_id, text=text, mode="progressive",
             cloud_tokens=len(sk_toks), edge_tokens=edge_tokens,

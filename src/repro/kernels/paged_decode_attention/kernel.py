@@ -154,8 +154,11 @@ def _paged_dec_kernel(tbl_ref,                 # scalar prefetch: (B, P) pages
 
 
 def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths,
-                  interpret: bool):
-    """Shared wrapper; `scales` is None or the (k_scales, v_scales) pair."""
+                  interpret: bool, name: str):
+    """Shared wrapper; `scales` is None or the (k_scales, v_scales) pair.
+    `name` names the kernel in compiled programs and profiler traces (the
+    public wrapper's name, so every variant starts with
+    `paged_decode_attention`)."""
     B, _, Hq, hd = q.shape
     ps, Hkv = k_pages.shape[1], k_pages.shape[2]
     P = block_table.shape[1]
@@ -209,6 +212,7 @@ def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(table, lens, *operands)
     return out.reshape(B, 1, Hq, hd)
 
@@ -219,7 +223,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
     block_table: (B, P) int32 page ids (-1 = unmapped); lengths: (B,) valid
     token counts. -> (B,1,Hq,hd); zero-length rows return zeros."""
     return _paged_decode(q, k_pages, v_pages, None, block_table, lengths,
-                         interpret)
+                         interpret, "paged_decode_attention")
 
 
 def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
@@ -231,4 +235,5 @@ def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
     Hkv) f32 per-(page, kv-head) dequant scales, streamed as (1, 1, Hkv)
     blocks through the same clamped block-table index map as their page."""
     return _paged_decode(q, k_pages, v_pages, (k_scales, v_scales),
-                         block_table, lengths, interpret)
+                         block_table, lengths, interpret,
+                         "paged_decode_attention_quant")

@@ -93,8 +93,10 @@ def _paged_pref_kernel(rows_ref,               # scalar prefetch: (R, P) pages
 
 
 def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
-                   interpret: bool):
-    """Shared ragged wrapper; `scales` is None or (k_scales, v_scales)."""
+                   interpret: bool, name: str):
+    """Shared ragged wrapper; `scales` is None or (k_scales, v_scales).
+    `name` names the kernel in compiled programs and profiler traces (the
+    public wrapper's name: `paged_prefill_attention[_ragged][_quant]`)."""
     R, C, Hq, hd = q.shape
     ps, Hkv = k_pages.shape[1], k_pages.shape[2]
     P = block_rows.shape[1]
@@ -150,6 +152,7 @@ def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, rep, C, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(rows, info, *operands)
     # (R, Hkv, rep, C, hd) -> (R, C, Hq, hd) with head index h = kv*rep + r
     return jnp.moveaxis(out.reshape(R, Hq, C, hd), 1, 2)
@@ -169,7 +172,8 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, block_row, offset,
     page ids (-1 = unmapped); offset/chunk_len: () int32. ->
     (1, C, Hq, hd); rows past chunk_len are unspecified."""
     return _paged_prefill(q, k_pages, v_pages, None,
-                          *_one_row(block_row, offset, chunk_len), interpret)
+                          *_one_row(block_row, offset, chunk_len), interpret,
+                          "paged_prefill_attention")
 
 
 def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
@@ -186,7 +190,7 @@ def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
     O(sum_r (offsets[r] + lens[r])). -> (R, C, Hq, hd); row r positions past
     lens[r] (and all of padding rows, lens[r] == 0) are unspecified."""
     return _paged_prefill(q, k_pages, v_pages, None, block_rows, offsets,
-                          lens, interpret)
+                          lens, interpret, "paged_prefill_attention_ragged")
 
 
 def paged_prefill_attention_quant_pallas(q, k_pages, v_pages, k_scales,
@@ -196,7 +200,8 @@ def paged_prefill_attention_quant_pallas(q, k_pages, v_pages, k_scales,
     (n_pages, Hkv) f32, streamed as (1, 1, Hkv) blocks through the same
     clamped block-row index map as their page)."""
     return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
-                          *_one_row(block_row, offset, chunk_len), interpret)
+                          *_one_row(block_row, offset, chunk_len), interpret,
+                          "paged_prefill_attention_quant")
 
 
 def paged_prefill_attention_ragged_quant_pallas(q, k_pages, v_pages, k_scales,
@@ -204,4 +209,5 @@ def paged_prefill_attention_ragged_quant_pallas(q, k_pages, v_pages, k_scales,
                                                 lens, *, interpret: bool):
     """`paged_prefill_attention_ragged_pallas` over a quantized pool."""
     return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
-                          block_rows, offsets, lens, interpret)
+                          block_rows, offsets, lens, interpret,
+                          "paged_prefill_attention_ragged_quant")

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.models import attention as attn_lib
 from repro.models import transformer
 from repro.models.config import ModelConfig
@@ -324,7 +325,6 @@ class InferenceEngine:
         self.slots = [Slot() for _ in range(max_batch)]
         self.key = jax.random.PRNGKey(0)
         self.tokens_generated = 0
-        self.busy_s = 0.0
         self._arrivals = 0
         self.evictions = 0
         self.peak_pages = 0
@@ -681,8 +681,7 @@ class InferenceEngine:
         trades a host->device transfer of the swapped bytes for the whole
         prefill recompute (see docs/serving.md for the crossover)."""
         slot = self.free_slots()[0]
-        t0 = time.perf_counter()
-        self._t_admit.setdefault(r.req_id, t0)
+        self._t_admit.setdefault(r.req_id, time.perf_counter())
         self._prune_admit_stamps()
         uploads = self.alloc.promote(r.req_id, slot)    # MemoryError if dry
         chain = self.alloc.owned[slot]
@@ -722,7 +721,6 @@ class InferenceEngine:
         s.arrival = self._arrivals
         self._arrivals += 1
         self._track_peak()
-        self.busy_s += time.perf_counter() - t0
         return slot
 
     def _live_pages(self, active: List[int]) -> int:
@@ -763,14 +761,16 @@ class InferenceEngine:
         """One (1, prefill_chunk)-shaped ingest call: pad, pick the covering
         live width, write+attend the chunk at `offset`. Returns the chunk's
         last-valid-token logits (1, V)."""
-        padded = np.zeros((1, self.prefill_chunk), np.int32)
-        padded[0, :len(chunk)] = chunk
-        live = self._chunk_live(offset + len(chunk))
-        self._sync_table()
-        logits, self.cache = self._prefill_chunk(
-            live, self.params, jnp.asarray(padded), self.cache,
-            jnp.asarray(slot, jnp.int32), jnp.asarray(offset, jnp.int32),
-            jnp.asarray(len(chunk), jnp.int32))
+        with trace.span("engine.feed_chunk", engine=self.name,
+                        offset=offset, length=len(chunk)):
+            padded = np.zeros((1, self.prefill_chunk), np.int32)
+            padded[0, :len(chunk)] = chunk
+            live = self._chunk_live(offset + len(chunk))
+            self._sync_table()
+            logits, self.cache = self._prefill_chunk(
+                live, self.params, jnp.asarray(padded), self.cache,
+                jnp.asarray(slot, jnp.int32), jnp.asarray(offset, jnp.int32),
+                jnp.asarray(len(chunk), jnp.int32))
         return logits
 
     def _ingest_chunk(self, slot: int):
@@ -784,13 +784,20 @@ class InferenceEngine:
         logits = self._feed_chunk(slot, chunk, s.ctx_len)
         s.ctx_len += len(chunk)
         if not s.prefill_toks:
+            self._first_token(slot, logits)
+        return logits
+
+    def _first_token(self, slot: int, logits):
+        """Sample, read back and commit a slot's first token from (1, V)
+        prefill logits: the one draw admission and the serial ingest take."""
+        with trace.span("engine.first_token", engine=self.name):
             self.key, sub = jax.random.split(self.key)
             tok = sample(logits, sub, self.sampler)
             lp = token_logprob(logits, tok)
-            # repro-analysis: disable=RA103 reason=admission-time first-token draw; one batched readback, off the decode loop
-            tok_h, lp_h = jax.device_get((tok, lp))
+            with trace.span("engine.readback", engine=self.name):
+                # repro-analysis: disable=RA103 reason=admission-time first-token draw; one batched readback, off the decode loop
+                tok_h, lp_h = jax.device_get((tok, lp))
             self._commit(slot, int(tok_h[0]), float(lp_h[0]))
-        return logits
 
     def _prefill_into_chunks(self, slot: int, toks: List[int]):
         """Synchronous chunked ingest of a whole prompt (prefill_prefix and
@@ -857,9 +864,10 @@ class InferenceEngine:
         # as independent submissions would, keeping the per-row PRNG draws —
         # and therefore sampled outputs — bit-identical to the unshared path
         slot = free[-1]
-        t0 = time.perf_counter()
-        toks, padded, _ = self._pad_prompt(list(prefix), self.max_len)
-        logits = self._prefill_into(slot, toks, padded)
+        with trace.span("engine.prefill_prefix", engine=self.name,
+                        tokens=len(prefix)):
+            toks, padded, _ = self._pad_prompt(list(prefix), self.max_len)
+            logits = self._prefill_into(slot, toks, padded)
         s = self.slots[slot]
         s.req_id, s.active, s.parked = -1, False, True
         s.prompt = list(prefix)
@@ -867,7 +875,6 @@ class InferenceEngine:
         s.prefill_toks = []
         s.ctx_len = len(toks)
         self._prefix_logits[slot] = logits
-        self.busy_s += time.perf_counter() - t0
         return slot
 
     def release_prefix(self, slot: int) -> None:
@@ -915,8 +922,7 @@ class InferenceEngine:
         if not free:
             raise RuntimeError("no free slot")
         slot = free[0]
-        t0 = time.perf_counter()
-        self._t_admit.setdefault(req_id, t0)
+        self._t_admit.setdefault(req_id, time.perf_counter())
         self._prune_admit_stamps()
 
         dropped = 0
@@ -992,15 +998,9 @@ class InferenceEngine:
         self._track_peak()
         if not s.pending and not s.prefill_toks:
             # sample the first token from (possibly shared) prefill logits
-            self.key, sub = jax.random.split(self.key)
-            tok = sample(logits, sub, self.sampler)
-            lp = token_logprob(logits, tok)
-            # repro-analysis: disable=RA103 reason=admission-time first-token draw; one batched readback, off the decode loop
-            tok_h, lp_h = jax.device_get((tok, lp))
-            self._commit(slot, int(tok_h[0]), float(lp_h[0]))
+            self._first_token(slot, logits)
         # else: the first sample comes after the last suffix/prompt token
         # is ingested
-        self.busy_s += time.perf_counter() - t0
         return slot
 
     def _prune_admit_stamps(self):
@@ -1092,14 +1092,15 @@ class InferenceEngine:
             return False
         commits, toks_d, lps_d = self._pending_decode
         self._pending_decode = None
-        t0 = time.perf_counter()
-        toks, lps = jax.device_get((toks_d, lps_d))
-        for i in commits:
-            # in-engine nothing deactivates a slot between dispatch and
-            # harvest; the guard covers direct _evict_victim calls (tests)
-            if self.slots[i].active:
-                self._commit(i, int(toks[i]), float(lps[i]))
-        self.busy_s += time.perf_counter() - t0
+        with trace.span("engine.harvest", engine=self.name):
+            with trace.span("engine.readback", engine=self.name):
+                toks, lps = jax.device_get((toks_d, lps_d))
+            for i in commits:
+                # in-engine nothing deactivates a slot between dispatch and
+                # harvest; the guard covers direct _evict_victim calls
+                # (tests)
+                if self.slots[i].active:
+                    self._commit(i, int(toks[i]), float(lps[i]))
         return True
 
     def _plan_decode(self, active_ids: List[int]) -> StepPlan:
@@ -1169,47 +1170,54 @@ class InferenceEngine:
                                 self.slots[j].arrival))
         C = self.prefill_chunk
         rows: List[Tuple[int, int, List[int]]] = []
-        for i in ing:
-            s = self.slots[i]
-            chunk = s.prefill_toks[:C]
-            s.prefill_toks = s.prefill_toks[C:]
-            rows.append((i, s.ctx_len, chunk))
-            s.ctx_len += len(chunk)
-        R = 1
-        while R < len(rows):
-            R *= 2                      # bucket rows (lo=1) to bound variants
-        toks = np.zeros((R, C), np.int32)
-        # padding rows carry the out-of-range slot `max_batch`: their cache
-        # scatters drop and their gathers clip to a live row and are
-        # discarded
-        slots = np.full((R,), self.max_batch, np.int32)
-        offs = np.zeros((R,), np.int32)
-        lens = np.zeros((R,), np.int32)
-        for r, (i, off, chunk) in enumerate(rows):
-            toks[r, :len(chunk)] = chunk
-            slots[r], offs[r], lens[r] = i, off, len(chunk)
-        live = self._chunk_live(max(off + len(chunk)
-                                    for _, off, chunk in rows))
-        self.kv_bytes_read += self._page_kv_bytes * sum(
-            -(-(off + len(chunk)) // self.page_size)
-            for _, off, chunk in rows)
-        logits, self.cache = self._prefill_ragged(
-            live, self.params, jnp.asarray(toks), self.cache,
-            jnp.asarray(slots), jnp.asarray(offs), jnp.asarray(lens))
-        draws: List[Tuple[int, object, object]] = []
-        for r, (i, _, _) in enumerate(rows):
-            s = self.slots[i]
-            if s.active and not s.prefill_toks:
-                # final chunk landed: first token — the same (1, V) sample a
-                # monolithic add_request takes (row slices of the batched
-                # logits are bitwise the single-slot logits)
+        with trace.span("engine.ingest", engine=self.name) as sp:
+            for i in ing:
+                s = self.slots[i]
+                chunk = s.prefill_toks[:C]
+                s.prefill_toks = s.prefill_toks[C:]
+                rows.append((i, s.ctx_len, chunk))
+                s.ctx_len += len(chunk)
+            if sp:
+                sp.attrs["rows"] = [(off, len(chunk))
+                                    for _, off, chunk in rows]
+            R = 1
+            while R < len(rows):
+                R *= 2                  # bucket rows (lo=1) to bound variants
+            toks = np.zeros((R, C), np.int32)
+            # padding rows carry the out-of-range slot `max_batch`: their
+            # cache scatters drop and their gathers clip to a live row and
+            # are discarded
+            slots = np.full((R,), self.max_batch, np.int32)
+            offs = np.zeros((R,), np.int32)
+            lens = np.zeros((R,), np.int32)
+            for r, (i, off, chunk) in enumerate(rows):
+                toks[r, :len(chunk)] = chunk
+                slots[r], offs[r], lens[r] = i, off, len(chunk)
+            live = self._chunk_live(max(off + len(chunk)
+                                        for _, off, chunk in rows))
+            self.kv_bytes_read += self._page_kv_bytes * sum(
+                -(-(off + len(chunk)) // self.page_size)
+                for _, off, chunk in rows)
+            logits, self.cache = self._prefill_ragged(
+                live, self.params, jnp.asarray(toks), self.cache,
+                jnp.asarray(slots), jnp.asarray(offs), jnp.asarray(lens))
+        # final chunk landed: first token — the same (1, V) sample a
+        # monolithic add_request takes (row slices of the batched logits
+        # are bitwise the single-slot logits)
+        done = [(r, i) for r, (i, _, _) in enumerate(rows)
+                if self.slots[i].active and not self.slots[i].prefill_toks]
+        if not done:
+            return True
+        with trace.span("engine.first_token", engine=self.name):
+            draws: List[Tuple[int, object, object]] = []
+            for r, i in done:
                 self.key, sub = jax.random.split(self.key)
                 tok = sample(logits[r:r + 1], sub, self.sampler)
                 lp = token_logprob(logits[r:r + 1], tok)
                 draws.append((i, tok, lp))
-        if draws:
-            # repro-analysis: disable=RA103 reason=one batched readback for every first token finishing this step (was 2 scalar syncs per row)
-            flat = jax.device_get([(t, l) for _, t, l in draws])
+            with trace.span("engine.readback", engine=self.name):
+                # repro-analysis: disable=RA103 reason=one batched readback for every first token finishing this step (was 2 scalar syncs per row)
+                flat = jax.device_get([(t, l) for _, t, l in draws])
             for (i, _, _), (tok_h, lp_h) in zip(draws, flat):
                 self._commit(i, int(tok_h[0]), float(lp_h[0]))
         return True
@@ -1239,51 +1247,64 @@ class InferenceEngine:
         teacher-forced: the step feeds `pending[0]` instead of the last
         sampled token and the sampled output is discarded until the suffix
         is exhausted — the logits after the final suffix token seed the
-        first real sample."""
-        if self.step_hook is not None:
-            # fault injection point: may stall (straggler), cancel a slot
-            # (mid-decode crash), squeeze the page pool, or raise
-            # EngineCrash — all before this step's harvest/plan/dispatch
-            self.step_hook(self)
-        worked = self._harvest()
-        if not any(s.active for s in self.slots):
-            return worked
-        t0 = time.perf_counter()
-        batched = self.prefill_chunk and self.ragged_ingest \
-            and self.kv_backend == "paged"
-        if not batched and self.prefill_chunk:
-            # legacy scheduler: one chunk for the most urgent ingesting
-            # slot, which joins the decode batch this same step
-            pref = [i for i, s in enumerate(self.slots)
-                    if s.active and s.prefill_toks]
-            if pref:
-                # highest priority first (a latency-critical latecomer's
-                # chunks jump the queue of a long opportunistic ingest),
-                # oldest admission within a class
-                self._ingest_chunk(min(
-                    pref, key=lambda j: (-self.slots[j].priority,
-                                         self.slots[j].arrival)))
+        first real sample.
+
+        The step's span (`engine.step`, with the active slots at its start)
+        holds one child per phase: harvest (with its readback), the legacy
+        serial ingest, plan (page growth and the decode plan), table push,
+        ingest, first token (with its readback) and decode dispatch."""
+        with trace.span("engine.step", engine=self.name,
+                        max_batch=self.max_batch) as sp:
+            if self.step_hook is not None:
+                # fault injection point: may stall (straggler), cancel a
+                # slot (mid-decode crash), squeeze the page pool, or raise
+                # EngineCrash — all before this step's harvest/plan/dispatch
+                self.step_hook(self)
+            if sp:
+                sp.attrs["active"] = sum(1 for s in self.slots if s.active)
+            worked = self._harvest()
+            if not any(s.active for s in self.slots):
+                return worked
+            batched = self.prefill_chunk and self.ragged_ingest \
+                and self.kv_backend == "paged"
+            if not batched and self.prefill_chunk:
+                # legacy scheduler: one chunk for the most urgent ingesting
+                # slot, which joins the decode batch this same step
+                pref = [i for i, s in enumerate(self.slots)
+                        if s.active and s.prefill_toks]
+                if pref:
+                    # highest priority first (a latency-critical latecomer's
+                    # chunks jump the queue of a long opportunistic ingest),
+                    # oldest admission within a class
+                    self._ingest_chunk(min(
+                        pref, key=lambda j: (-self.slots[j].priority,
+                                             self.slots[j].arrival)))
+                    worked = True
+            with trace.span("engine.plan", engine=self.name):
+                active = [i for i, s in enumerate(self.slots)
+                          if s.active and not s.prefill_toks]
+                if self.kv_backend == "paged" and active:
+                    self._grow_pages()      # may evict, incl. mid-ingest slots
+                    active = [i for i, s in enumerate(self.slots)
+                              if s.active and not s.prefill_toks]
+                plan = self._plan_decode(active) if active else None
+            if self.kv_backend == "paged":
+                # ONE table push per step, before the first dispatch that reads
+                # it. Finish commits below may free rows again; those stale
+                # entries ride until the next step's push — decode writes are
+                # active-masked, so they cannot touch a COW sibling's pages.
+                with trace.span("engine.table_push", engine=self.name):
+                    self._sync_table()
+            if batched:
+                worked = self._run_ingest() or worked
+            if plan is not None:
+                with trace.span("engine.decode", engine=self.name) as sp:
+                    if sp:
+                        sp.attrs["ctx"] = [self.slots[i].ctx_len
+                                           for i in plan.active_ids]
+                    self._dispatch_decode(plan)
                 worked = True
-        active = [i for i, s in enumerate(self.slots)
-                  if s.active and not s.prefill_toks]
-        if self.kv_backend == "paged" and active:
-            self._grow_pages()          # may evict, incl. mid-ingest slots
-            active = [i for i, s in enumerate(self.slots)
-                      if s.active and not s.prefill_toks]
-        plan = self._plan_decode(active) if active else None
-        if self.kv_backend == "paged":
-            # ONE table push per step, before the first dispatch that reads
-            # it. Finish commits below may free rows again; those stale
-            # entries ride until the next step's push — decode writes are
-            # active-masked, so they cannot touch a COW sibling's pages.
-            self._sync_table()
-        if batched:
-            worked = self._run_ingest() or worked
-        if plan is not None:
-            self._dispatch_decode(plan)
-            worked = True
-        self.busy_s += time.perf_counter() - t0
-        return worked
+            return worked
 
     def warmup(self, *, max_context: Optional[int] = None,
                prompt_lens: Tuple[int, ...] = (),
@@ -1500,7 +1521,8 @@ class InferenceEngine:
                     raise MemoryError(
                         f"request {r.req_id} cannot fit in the page pool")
                 return None                      # wait for pages to free
-            return self._admit_swapped(r)
+            with trace.span("engine.swap_promote", engine=self.name):
+                return self._admit_swapped(r)
         if r.share_from >= 0 and not self.slots[r.share_from].parked:
             r.share_from, r.suffix = -1, []       # prefix gone: monolithic
         if r.share_from >= 0:

@@ -36,7 +36,15 @@ Per-request deadlines ride the PR-9 cancel machinery: an overdue request is
 cancelled through `engine.cancel` (pending-decode commits pruned, survivor
 streams bit-identical) and its handle finishes with reason "deadline" and
 whatever tokens it produced. TTFT/TPOT/latency are recorded per request
-FROM ARRIVAL — queue wait included.
+FROM ARRIVAL — queue wait included; each handle keeps its admission stamp,
+so the wait for a slot is known apart from the time running.
+
+With the tracer on (core/trace.py), each sub-request leaves two intervals,
+`frontend.queued` (arrival to the slot `try_admit` gave it; a fan-out
+fork arrives at its `generate_fanout_async` call, so the wait to park the
+prefix counts) and `frontend.running` (admission to finish), under the user
+request that submitted it; each driver iteration's admission and publish
+passes are spans.
 """
 from __future__ import annotations
 
@@ -46,6 +54,7 @@ import itertools
 import time
 from typing import AsyncIterator, Dict, List, Optional, Tuple
 
+from repro.core import trace
 from repro.core.dispatch import MultiListQueue
 from repro.serving.engine import EngineRequest, InferenceEngine
 from repro.serving.requests import TIER_PRIORITY
@@ -114,8 +123,12 @@ class RequestHandle:
         self.error: Optional[BaseException] = None
         self.first_token_s: Optional[float] = None
         self.finish_s: Optional[float] = None
+        # perf_counter when `try_admit` first gave the request a slot
+        self.admitted_s: Optional[float] = None
         self._frontend = frontend
         self._queued = None               # the _Queued entry while waiting
+        # tracer on at submission: (queued-from ns, user request id)
+        self._trace: Optional[Tuple[int, Optional[int]]] = None
         self._deltas: asyncio.Queue = asyncio.Queue()
         self._done = asyncio.Event()
 
@@ -129,6 +142,13 @@ class RequestHandle:
         if self.first_token_s is None:
             return None
         return self.first_token_s - self.req.arrival_time_s
+
+    @property
+    def queue_wait_s(self) -> Optional[float]:
+        """Arrival to the first admission into an engine slot."""
+        if self.admitted_s is None:
+            return None
+        return self.admitted_s - self.req.arrival_time_s
 
     @property
     def latency_s(self) -> Optional[float]:
@@ -275,10 +295,15 @@ class EngineFrontend:
         return self.submit(req, sheddable=sheddable).stream()
 
     def _enqueue(self, req: CompletionRequest, work: EngineRequest,
-                 sheddable: bool) -> RequestHandle:
+                 sheddable: bool,
+                 queued_ns: Optional[int] = None) -> RequestHandle:
         if req.arrival_time_s is None:   # fanout forks enqueue directly
             req.arrival_time_s = time.perf_counter()
         h = RequestHandle(req, self)
+        if trace.enabled():
+            h._trace = (trace.from_perf(req.arrival_time_s)
+                        if queued_ns is None else queued_ns,
+                        trace.REQUEST.get())
         q = _Queued(h, work)
         h._queued = q
         if sheddable:
@@ -369,16 +394,21 @@ class EngineFrontend:
         device state. Exits when the front-end drains (a later submit
         re-spawns it)."""
         engine = self.engine
+        # the task inherited its spawner's context: the steps it drives
+        # serve every request, none of them the spawner's alone
+        trace.REQUEST.set(None)
         try:
             while True:
                 self._sweep_deadlines(time.perf_counter())
                 try:
-                    self._admit()
+                    with trace.span("frontend.admit", engine=engine.name):
+                        self._admit()
                     if any(s.active for s in engine.slots):
                         engine.step()
                 except Exception as exc:   # EngineCrash, or any step fault
                     self.on_crash(exc)
-                self._publish_and_settle()
+                with trace.span("frontend.publish", engine=engine.name):
+                    self._publish_and_settle()
                 for r in engine.drain_resumes():
                     if r.req_id in self._live:
                         self._live[r.req_id].state = "evicted"
@@ -474,6 +504,18 @@ class EngineFrontend:
             self._slot_of[rid] = slot
             engine._inflight.add(rid)
             q.handle.state = "running"
+            q.handle.admitted_s = time.perf_counter()
+            if q.handle._trace is not None:
+                self._trace_wait(q.handle, "frontend.queued",
+                                 q.handle._trace[0])
+
+    def _trace_wait(self, h: RequestHandle, name: str, start_ns: int,
+                    **attrs) -> None:
+        """One of a sub-request's intervals, ending now, under the user
+        request that submitted it."""
+        trace.interval(name, start_ns, trace.now(), engine=self.engine.name,
+                       role=h.req.role, sub=h.req.req_id,
+                       request=h._trace[1], **attrs)
 
     def _remove_queued(self, q: "_Queued") -> None:
         if q in self._lane:
@@ -534,6 +576,9 @@ class EngineFrontend:
         h.finish_reason = reason
         h.error = error
         h.finish_s = time.perf_counter()
+        if h._trace is not None and h.admitted_s is not None:
+            self._trace_wait(h, "frontend.running",
+                             trace.from_perf(h.admitted_s), reason=reason)
         if reason in ("stop", "length"):
             self.completed += 1
         elif reason == "shed":
@@ -592,6 +637,7 @@ class EngineFrontend:
         await all members. Falls back to independent submissions exactly
         where the engine does."""
         engine = self.engine
+        t_call = trace.now() if trace.enabled() else None
         if (engine.kv_backend != "paged" or engine.max_batch < 2
                 or not engine.prefix_sharing):
             return await self.generate_async(
@@ -619,7 +665,8 @@ class EngineFrontend:
                     req_id=req.req_id, prompt=list(req.prompt),
                     max_new=max_new, carry_tokens=[], carry_lps=[],
                     share_from=p_slot, suffix=list(sfx), priority=priority)
-                handles.append(self._enqueue(req, work, sheddable=False))
+                handles.append(self._enqueue(req, work, sheddable=False,
+                                             queued_ns=t_call))
             return await self._gather(handles)
         finally:
             engine.release_prefix(p_slot)

@@ -260,3 +260,53 @@ def test_validate_paged_alignment():
     with pytest.raises(AssertionError):
         cfg.with_(use_pallas=True).validate_paged(12, 240)  # sublane align
     cfg.with_(use_pallas=True).validate_paged(16, 256)
+
+
+# ---------------------------------------------------------------------------
+# kernel names: a profiler trace and the benchmark's readers find each
+# kernel by the name its public wrapper gives its pallas_call
+# ---------------------------------------------------------------------------
+
+def _pallas_names(fn, *args):
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(str(eqn.params["name"]))
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None:
+                    walk(getattr(inner, "jaxpr", inner))
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def _kernel_args(quant: bool, ragged: bool, decode: bool):
+    S = jax.ShapeDtypeStruct
+    dt = jnp.int8 if quant else jnp.float32
+    pages = S((6, 8, 1, 128), dt)
+    scales = [S((6, 1), jnp.float32)] * 2 if quant else []
+    i32 = jnp.int32
+    if decode:
+        return [S((2, 1, 2, 128), jnp.float32), pages, pages, *scales,
+                S((2, 3), i32), S((2,), i32)]
+    if ragged:
+        return [S((2, 8, 2, 128), jnp.float32), pages, pages, *scales,
+                S((2, 3), i32), S((2,), i32), S((2,), i32)]
+    return [S((1, 8, 2, 128), jnp.float32), pages, pages, *scales,
+            S((3,), i32), S((), i32), S((), i32)]
+
+
+@pytest.mark.parametrize("wrapper", [
+    "paged_decode_attention", "paged_decode_attention_quant",
+    "paged_prefill_attention", "paged_prefill_attention_quant",
+    "paged_prefill_attention_ragged",
+    "paged_prefill_attention_ragged_quant"])
+def test_paged_kernels_carry_their_wrappers_name(wrapper):
+    from repro.kernels.paged_prefill_attention import ops as ppa_ops
+    decode = wrapper.startswith("paged_decode")
+    fn = getattr(pda_ops if decode else ppa_ops, wrapper)
+    args = _kernel_args("quant" in wrapper, "ragged" in wrapper, decode)
+    names = _pallas_names(lambda *a: fn(*a, interpret=True), *args)
+    assert names == [wrapper]
