@@ -41,8 +41,11 @@ def paged_decode_case(smoke: bool = False):
     n_pages = B * P
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.float32)
-    pages_k = jax.random.normal(ks[1], (n_pages, page, Hkv, hd), jnp.float32)
-    pages_v = jax.random.normal(ks[2], (n_pages, page, Hkv, hd), jnp.float32)
+    # one layer of the stacked head-major pool the engine carries
+    pages_k = jax.random.normal(ks[1], (1, n_pages, Hkv, page, hd),
+                                jnp.float32)
+    pages_v = jax.random.normal(ks[2], (1, n_pages, Hkv, page, hd),
+                                jnp.float32)
     # length-skewed batch: one straggler at half the table width, the rest
     # short — so all three read strategies differ: the trim drops the
     # columns NO slot uses, the kernel additionally skips per-row dead width

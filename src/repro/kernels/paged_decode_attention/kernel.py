@@ -1,7 +1,7 @@
 """Pallas TPU paged flash-decode kernel: one new token vs a paged KV pool.
 
 The serving engine's paged backend (models/paged_cache.py) stores KV in a
-per-layer page pool `(n_pages, page_size, n_kv, hd)` addressed through a
+stacked page pool `(L, n_pages, n_kv, page_size, hd)` addressed through a
 block table `(B, P)`. The jnp oracle first *gathers* every slot's full
 table width into a contiguous `(B, P * page_size, n_kv, hd)` buffer — per
 layer, per token, sized by the table width rather than actual lengths —
@@ -9,10 +9,13 @@ and only then attends. At long context that double-pays the PICE decode
 hot spot (KV reads are >50% of decode latency); this kernel removes the
 gather entirely:
 
-  * `(block_table, lengths)` are scalar-prefetched, and the block table IS
-    the K/V `index_map`: grid step (b, h, p) streams physical page
-    `block_table[b, p]` HBM->VMEM directly from the pool. No contiguous
-    copy ever exists.
+  * `(layer, block_table, lengths)` are scalar-prefetched, and the block
+    table IS the K/V `index_map`: grid step (b, h, p) streams the
+    `(page_size, hd)` tile of layer `layer`, physical page
+    `block_table[b, p]`, KV head h HBM->VMEM directly from the stacked
+    pool. No contiguous copy, and no per-layer slice of the pool, ever
+    exists: the model's layer loop hands every layer the whole stacked
+    pool and its own (traced) layer index.
   * steps past a slot's live pages re-map to its last live page — Pallas
     elides the DMA for a revisited block — and `pl.when` skips their
     compute, so per-step read volume is O(sum ceil(len/page)) pages, not
@@ -31,9 +34,10 @@ gather entirely:
 Grid: (B, Hkv, P) with P = block-table width (callers should pre-trim it
 to the live width). Rows with length 0 return zeros.
 
-The pool is read through `lane_view`, so one grid step's K/V tile is
-`(page_size, hd)`: the TPU compiler accepts it when hd % 128 == 0 and
-page_size % 8 == 0 (the engine checks both before it picks this path).
+The pool is stored head-major, so one grid step's K/V tile is the
+contiguous `(page_size, hd)` block `(1, 1, 1, page_size, hd)`: the TPU
+compiler accepts it when hd % 128 == 0 and page_size % 8 == 0 (the engine
+checks both before it picks this path).
 """
 from __future__ import annotations
 
@@ -47,36 +51,33 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -1e30
 
 
-def lane_view(pages):
-    """(n_pages, page, Hkv, hd) -> (n_pages, page, Hkv * hd), a free
-    reshape. KV head h becomes lane block h of width hd, so a
-    (1, page, hd) block indexed (page_id, 0, h) is one head's page tile
-    whose last two dims meet the TPU (8, 128) block rule; a (.., 1, hd)
-    block over the Hkv axis does not."""
-    n, ps, hkv, hd = pages.shape
-    return pages.reshape(n, ps, hkv * hd)
-
-
 def scale_view(scales):
-    """(n_pages, Hkv) dequant scales -> (n_pages, 1, Hkv): a (1, 1, Hkv)
-    block then spans whole trailing dims, which the TPU block rule accepts
-    (a (1, 1) block of the 2-D tensor does not, in VMEM or SMEM)."""
-    return scales.reshape(scales.shape[0], 1, scales.shape[1])
+    """(L, n_pages, Hkv) dequant scales -> (L, n_pages, 1, Hkv): a
+    (1, 1, 1, Hkv) block then spans whole trailing dims, which the TPU
+    block rule accepts (a (1, 1) block of the page and head axes does not,
+    in VMEM or SMEM)."""
+    return scales.reshape(*scales.shape[:2], 1, scales.shape[2])
+
+
+def layer_operand(layer):
+    """The layer index as the (1,) int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def head_scale(s_ref, h):
-    """KV head h's scale from a (1, 1, Hkv) block, as a (1, 1) array
+    """KV head h's scale from a (1, 1, 1, Hkv) block, as a (1, 1) array
     (a masked lane reduction: no dynamic lane indexing)."""
-    row = s_ref[0]                                            # (1, Hkv)
+    row = s_ref[0, 0]                                         # (1, Hkv)
     hit = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) == h
     return jnp.sum(jnp.where(hit, row, 0.0), axis=1, keepdims=True)
 
 
 def page_tile(ref, valid, s_ref=None, h=None):
-    """One page's (ps, hd) K or V tile in f32, dequantized when `s_ref` is
-    given, with rows past the valid range zeroed BEFORE the MXU: they hold
-    stale pool bytes that must not reach it as NaN/inf."""
-    x = ref[0].astype(jnp.float32)
+    """One page's (ps, hd) K or V tile, from a (1, 1, 1, ps, hd) block, in
+    f32, dequantized when `s_ref` is given, with rows past the valid range
+    zeroed BEFORE the MXU: they hold stale pool bytes that must not reach
+    it as NaN/inf."""
+    x = ref[0, 0, 0].astype(jnp.float32)
     if s_ref is not None:
         x = x * head_scale(s_ref, h)
     return jnp.where(valid, x, 0.0)
@@ -114,7 +115,8 @@ def softmax_result(l_scr, acc_scr):
     return acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
 
 
-def _paged_dec_kernel(tbl_ref,                 # scalar prefetch: (B, P) pages
+def _paged_dec_kernel(layer_ref,               # scalar prefetch: (1,) layer
+                      tbl_ref,                 # scalar prefetch: (B, P) pages
                       len_ref,                 # scalar prefetch: (B,) lengths
                       q_ref, k_ref, v_ref, *refs,
                       np_: int, ps: int, scale: float, quant: bool):
@@ -153,14 +155,14 @@ def _paged_dec_kernel(tbl_ref,                 # scalar prefetch: (B, P) pages
         o_ref[0, 0] = softmax_result(l_scr, acc_scr).astype(o_ref.dtype)
 
 
-def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths,
+def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths, layer,
                   interpret: bool, name: str):
     """Shared wrapper; `scales` is None or the (k_scales, v_scales) pair.
     `name` names the kernel in compiled programs and profiler traces (the
     public wrapper's name, so every variant starts with
     `paged_decode_attention`)."""
     B, _, Hq, hd = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
+    Hkv, ps = k_pages.shape[2], k_pages.shape[3]
     P = block_table.shape[1]
     rep = Hq // Hkv
     table = block_table.astype(jnp.int32)
@@ -177,26 +179,26 @@ def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths,
         pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
         return jnp.maximum(tbl_ref[b, pi], 0)
 
-    def kv_map(b, h, p, tbl_ref, len_ref):
-        return (page_of(b, p, tbl_ref, len_ref), 0, h)
+    def kv_map(b, h, p, layer_ref, tbl_ref, len_ref):
+        return (layer_ref[0], page_of(b, p, tbl_ref, len_ref), h, 0, 0)
 
-    def scale_map(b, h, p, tbl_ref, len_ref):
-        return (page_of(b, p, tbl_ref, len_ref), 0, 0)
+    def scale_map(b, h, p, layer_ref, tbl_ref, len_ref):
+        return (layer_ref[0], page_of(b, p, tbl_ref, len_ref), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, rep, hd), lambda b, h, p, *_: (b, h, 0, 0)),
-        pl.BlockSpec((1, ps, hd), kv_map),
-        pl.BlockSpec((1, ps, hd), kv_map),
+        pl.BlockSpec((1, 1, 1, ps, hd), kv_map),
+        pl.BlockSpec((1, 1, 1, ps, hd), kv_map),
     ]
-    operands = [qg, lane_view(k_pages), lane_view(v_pages)]
+    operands = [qg, k_pages, v_pages]
     if scales is not None:
-        in_specs += [pl.BlockSpec((1, 1, Hkv), scale_map)] * 2
+        in_specs += [pl.BlockSpec((1, 1, 1, Hkv), scale_map)] * 2
         operands += [scale_view(s) for s in scales]
     kernel = functools.partial(_paged_dec_kernel, np_=P, ps=ps,
                                scale=1.0 / float(hd) ** 0.5,
                                quant=scales is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, P),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, rep, hd),
@@ -213,27 +215,29 @@ def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), q.dtype),
         interpret=interpret,
         name=name,
-    )(table, lens, *operands)
+    )(layer_operand(layer), table, lens, *operands)
     return out.reshape(B, 1, Hq, hd)
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
-                                  *, interpret: bool):
-    """q: (B,1,Hq,hd); k/v_pages: (n_pages, page, Hkv, hd);
+                                  layer=0, *, interpret: bool):
+    """q: (B,1,Hq,hd); k/v_pages: (L, n_pages, Hkv, page, hd);
     block_table: (B, P) int32 page ids (-1 = unmapped); lengths: (B,) valid
-    token counts. -> (B,1,Hq,hd); zero-length rows return zeros."""
+    token counts; layer: () int32, the pool layer read. -> (B,1,Hq,hd);
+    zero-length rows return zeros."""
     return _paged_decode(q, k_pages, v_pages, None, block_table, lengths,
-                         interpret, "paged_decode_attention")
+                         layer, interpret, "paged_decode_attention")
 
 
 def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
                                         v_scales, block_table, lengths,
-                                        *, interpret: bool):
+                                        layer=0, *, interpret: bool):
     """`paged_decode_attention_pallas` over a quantized pool.
 
-    k/v_pages: (n_pages, page, Hkv, hd) int8 / fp8; k/v_scales: (n_pages,
-    Hkv) f32 per-(page, kv-head) dequant scales, streamed as (1, 1, Hkv)
-    blocks through the same clamped block-table index map as their page."""
+    k/v_pages: (L, n_pages, Hkv, page, hd) int8 / fp8; k/v_scales: (L,
+    n_pages, Hkv) f32 per-(page, kv-head) dequant scales, streamed as
+    (1, 1, 1, Hkv) blocks through the same clamped block-table index map as
+    their page."""
     return _paged_decode(q, k_pages, v_pages, (k_scales, v_scales),
-                         block_table, lengths, interpret,
+                         block_table, lengths, layer, interpret,
                          "paged_decode_attention_quant")

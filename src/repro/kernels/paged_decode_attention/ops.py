@@ -12,29 +12,30 @@ from repro.kernels.runtime import resolve_interpret
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
-                           interpret: Optional[bool] = None):
+                           layer=0, interpret: Optional[bool] = None):
     """Single-token GQA attention over a paged KV pool, streamed through
     the block table (no gather).
 
-    q: (B,1,Hq,hd); k/v_pages: (n_pages, page_size, Hkv, hd);
-    block_table: (B, P) int32 page ids (-1 = unmapped); lengths: (B,)
-    valid token counts. Pre-trim `block_table` to the live width
-    (ceil(max(lengths)/page_size) columns) so the grid does not walk
-    columns no slot uses.
+    q: (B,1,Hq,hd); k/v_pages: (L, n_pages, Hkv, page_size, hd), the
+    stacked head-major pool; block_table: (B, P) int32 page ids (-1 =
+    unmapped); lengths: (B,) valid token counts; layer: () int32, the
+    pool layer read (traced: one compile serves every layer). Pre-trim
+    `block_table` to the live width (ceil(max(lengths)/page_size) columns)
+    so the grid does not walk columns no slot uses.
     """
     return _kernel.paged_decode_attention_pallas(
-        q, k_pages, v_pages, block_table, lengths,
+        q, k_pages, v_pages, block_table, lengths, layer,
         interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
-                                 block_table, lengths,
+                                 block_table, lengths, layer=0,
                                  interpret: Optional[bool] = None):
     """`paged_decode_attention` over an int8/fp8 pool: pages are streamed at
     the storage width and dequantized in-VMEM with their per-(page, kv-head)
-    scales (k/v_scales: (n_pages, Hkv) f32). Numerics follow the quantized
-    tolerance contract in docs/serving.md, not the bit-exact one."""
+    scales (k/v_scales: (L, n_pages, Hkv) f32). Numerics follow the
+    quantized tolerance contract in docs/serving.md, not the bit-exact one."""
     return _kernel.paged_decode_attention_quant_pallas(
-        q, k_pages, v_pages, k_scales, v_scales, block_table, lengths,
+        q, k_pages, v_pages, k_scales, v_scales, block_table, lengths, layer,
         interpret=resolve_interpret(interpret))

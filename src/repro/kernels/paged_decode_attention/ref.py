@@ -16,16 +16,12 @@ from repro.models import paged_cache as pc
 NEG_INF = -1e30
 
 
-def paged_decode_attention_ref(q, k_pages, v_pages, block_table, lengths):
-    """q: (B,1,Hq,hd); k/v_pages: (n_pages, page, Hkv, hd); block_table:
-    (B, P) int32 (-1 = unmapped); lengths: (B,) valid token counts.
-    Returns (B,1,Hq,hd); zero-length rows return zeros (matching the
-    kernel), not the uniform-softmax garbage of an all-masked SDPA."""
+def _attend(q, gk, gv, lengths):
+    """Masked single-token SDPA over gathered contiguous K/V (B, S, Hkv,
+    hd); zero-length rows return zeros (matching the kernel), not the
+    uniform-softmax garbage of an all-masked SDPA."""
     B, _, Hq, hd = q.shape
-    Hkv = k_pages.shape[2]
-    rep = Hq // Hkv
-    gk = pc.gather_sequence(k_pages, block_table)     # (B, P*page, Hkv, hd)
-    gv = pc.gather_sequence(v_pages, block_table)
+    rep = Hq // gk.shape[2]
     S = gk.shape[1]
     k = jnp.repeat(gk, rep, axis=2) if rep > 1 else gk
     v = jnp.repeat(gv, rep, axis=2) if rep > 1 else gv
@@ -40,26 +36,21 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_table, lengths):
                      jnp.zeros_like(out))
 
 
+def paged_decode_attention_ref(q, k_pages, v_pages, block_table, lengths,
+                               layer=0):
+    """q: (B,1,Hq,hd); k/v_pages: (L, n_pages, Hkv, page, hd), read at layer
+    `layer`; block_table: (B, P) int32 (-1 = unmapped); lengths: (B,) valid
+    token counts. Returns (B,1,Hq,hd); zero-length rows return zeros."""
+    return _attend(q, pc.gather_sequence(k_pages, block_table, layer),
+                   pc.gather_sequence(v_pages, block_table, layer), lengths)
+
+
 def paged_decode_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
-                                     block_table, lengths):
+                                     block_table, lengths, layer=0):
     """Quantized-pool oracle: dequantize-gather into the contiguous f32
     layout, then attend exactly as the float oracle. Kernel-vs-this is a
     reduction-order comparison (tight tolerance); this-vs-the-float-pool
     oracle is the quantization tolerance contract (docs/serving.md)."""
-    gk = pc.gather_sequence_dequant(k_pages, k_scales, block_table)
-    gv = pc.gather_sequence_dequant(v_pages, v_scales, block_table)
-    B, _, Hq, hd = q.shape
-    Hkv = k_pages.shape[2]
-    rep = Hq // Hkv
-    S = gk.shape[1]
-    k = jnp.repeat(gk, rep, axis=2) if rep > 1 else gk
-    v = jnp.repeat(gv, rep, axis=2) if rep > 1 else gv
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    logits = jnp.einsum("bqnh,bknh->bnqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale       # (B,Hq,1,S)
-    mask = (jnp.arange(S)[None, :] < lengths[:, None])[:, None, None]
-    logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
-    return jnp.where((lengths > 0)[:, None, None, None], out,
-                     jnp.zeros_like(out)).astype(q.dtype)
+    gk = pc.gather_sequence_dequant(k_pages, k_scales, block_table, layer)
+    gv = pc.gather_sequence_dequant(v_pages, v_scales, block_table, layer)
+    return _attend(q, gk, gv, lengths).astype(q.dtype)

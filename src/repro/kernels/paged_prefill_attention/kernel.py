@@ -9,9 +9,11 @@ gathering the slot's whole block row into a contiguous buffer per layer per
 chunk. This kernel removes the gather, mirroring the paged flash-decode
 kernel (and sharing its page-tile and running-softmax helpers):
 
-  * `(block_rows, [offset, chunk_len] per row)` are scalar-prefetched and
-    the block rows ARE the K/V `index_map`: grid step (r, h, p) streams
-    physical page `block_rows[r, p]` HBM->VMEM straight from the pool.
+  * `(layer, block_rows, [offset, chunk_len] per row)` are
+    scalar-prefetched and the block rows ARE the K/V `index_map`: grid step
+    (r, h, p) streams the `(page_size, hd)` tile of layer `layer`, physical
+    page `block_rows[r, p]`, KV head h HBM->VMEM straight from the stacked
+    head-major pool (no per-layer slice of it exists).
   * steps past a row's live range (`ceil((offset+chunk_len)/page)` pages)
     re-map to its last live page — Pallas elides the DMA for a revisited
     block — and `pl.when` prunes their compute along with unmapped (-1)
@@ -41,11 +43,12 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.paged_decode_attention.kernel import (
-    init_softmax, lane_view, page_tile, scale_view, softmax_result,
+    init_softmax, layer_operand, page_tile, scale_view, softmax_result,
     softmax_update)
 
 
-def _paged_pref_kernel(rows_ref,               # scalar prefetch: (R, P) pages
+def _paged_pref_kernel(layer_ref,              # scalar prefetch: (1,) layer
+                       rows_ref,               # scalar prefetch: (R, P) pages
                        info_ref,               # scalar prefetch: (R, 2)
                        q_ref, k_ref, v_ref, *refs,
                        np_: int, ps: int, C: int, rep: int, scale: float,
@@ -93,12 +96,12 @@ def _paged_pref_kernel(rows_ref,               # scalar prefetch: (R, P) pages
 
 
 def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
-                   interpret: bool, name: str):
+                   layer, interpret: bool, name: str):
     """Shared ragged wrapper; `scales` is None or (k_scales, v_scales).
     `name` names the kernel in compiled programs and profiler traces (the
     public wrapper's name: `paged_prefill_attention[_ragged][_quant]`)."""
     R, C, Hq, hd = q.shape
-    ps, Hkv = k_pages.shape[1], k_pages.shape[2]
+    Hkv, ps = k_pages.shape[2], k_pages.shape[3]
     P = block_rows.shape[1]
     rep = Hq // Hkv
     rows = block_rows.astype(jnp.int32)
@@ -116,27 +119,27 @@ def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
         pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
         return jnp.maximum(rows_ref[r, pi], 0)
 
-    def kv_map(r, h, p, rows_ref, info_ref):
-        return (page_of(r, p, rows_ref, info_ref), 0, h)
+    def kv_map(r, h, p, layer_ref, rows_ref, info_ref):
+        return (layer_ref[0], page_of(r, p, rows_ref, info_ref), h, 0, 0)
 
-    def scale_map(r, h, p, rows_ref, info_ref):
-        return (page_of(r, p, rows_ref, info_ref), 0, 0)
+    def scale_map(r, h, p, layer_ref, rows_ref, info_ref):
+        return (layer_ref[0], page_of(r, p, rows_ref, info_ref), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, rep, C, hd),
                      lambda r, h, p, *_: (r, h, 0, 0, 0)),
-        pl.BlockSpec((1, ps, hd), kv_map),
-        pl.BlockSpec((1, ps, hd), kv_map),
+        pl.BlockSpec((1, 1, 1, ps, hd), kv_map),
+        pl.BlockSpec((1, 1, 1, ps, hd), kv_map),
     ]
-    operands = [qg, lane_view(k_pages), lane_view(v_pages)]
+    operands = [qg, k_pages, v_pages]
     if scales is not None:
-        in_specs += [pl.BlockSpec((1, 1, Hkv), scale_map)] * 2
+        in_specs += [pl.BlockSpec((1, 1, 1, Hkv), scale_map)] * 2
         operands += [scale_view(s) for s in scales]
     kernel = functools.partial(_paged_pref_kernel, np_=P, ps=ps, C=C,
                                rep=rep, scale=1.0 / float(hd) ** 0.5,
                                quant=scales is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(R, Hkv, P),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, rep, C, hd),
@@ -153,7 +156,7 @@ def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
         out_shape=jax.ShapeDtypeStruct((R, Hkv, rep, C, hd), q.dtype),
         interpret=interpret,
         name=name,
-    )(rows, info, *operands)
+    )(layer_operand(layer), rows, info, *operands)
     # (R, Hkv, rep, C, hd) -> (R, C, Hq, hd) with head index h = kv*rep + r
     return jnp.moveaxis(out.reshape(R, Hq, C, hd), 1, 2)
 
@@ -166,48 +169,52 @@ def _one_row(block_row, offset, chunk_len):
 
 
 def paged_prefill_attention_pallas(q, k_pages, v_pages, block_row, offset,
-                                   chunk_len, *, interpret: bool):
-    """q: (1, C, Hq, hd) one slot's chunk queries; k/v_pages: (n_pages,
-    page, Hkv, hd) with the chunk already written; block_row: (P,) int32
-    page ids (-1 = unmapped); offset/chunk_len: () int32. ->
-    (1, C, Hq, hd); rows past chunk_len are unspecified."""
+                                   chunk_len, layer=0, *, interpret: bool):
+    """q: (1, C, Hq, hd) one slot's chunk queries; k/v_pages: (L, n_pages,
+    Hkv, page, hd) with the chunk already written to layer `layer`;
+    block_row: (P,) int32 page ids (-1 = unmapped); offset/chunk_len: ()
+    int32. -> (1, C, Hq, hd); rows past chunk_len are unspecified."""
     return _paged_prefill(q, k_pages, v_pages, None,
-                          *_one_row(block_row, offset, chunk_len), interpret,
-                          "paged_prefill_attention")
+                          *_one_row(block_row, offset, chunk_len), layer,
+                          interpret, "paged_prefill_attention")
 
 
 def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
-                                          offsets, lens, *,
+                                          offsets, lens, layer=0, *,
                                           interpret: bool):
     """Multi-slot ragged chunk attention: the batched-ingest extension of
     `paged_prefill_attention_pallas`.
 
     q: (R, C, Hq, hd) — row r is one ingesting slot's chunk queries (chunk
-    K/V already written); k/v_pages: (n_pages, page, Hkv, hd); block_rows:
+    K/V already written); k/v_pages: (L, n_pages, Hkv, page, hd), read at
+    layer `layer`; block_rows:
     (R, P) int32 per-row page ids (-1 = unmapped); offsets/lens: (R,) int32.
     Grid (R, Hkv, P): the innermost axis walks row r's pages with a per-row
     scalar-prefetched clamp/prune, so the streamed volume is
     O(sum_r (offsets[r] + lens[r])). -> (R, C, Hq, hd); row r positions past
     lens[r] (and all of padding rows, lens[r] == 0) are unspecified."""
     return _paged_prefill(q, k_pages, v_pages, None, block_rows, offsets,
-                          lens, interpret, "paged_prefill_attention_ragged")
+                          lens, layer, interpret,
+                          "paged_prefill_attention_ragged")
 
 
 def paged_prefill_attention_quant_pallas(q, k_pages, v_pages, k_scales,
                                          v_scales, block_row, offset,
-                                         chunk_len, *, interpret: bool):
+                                         chunk_len, layer=0, *,
+                                         interpret: bool):
     """`paged_prefill_attention_pallas` over a quantized pool (k/v_scales:
-    (n_pages, Hkv) f32, streamed as (1, 1, Hkv) blocks through the same
-    clamped block-row index map as their page)."""
+    (L, n_pages, Hkv) f32, streamed as (1, 1, 1, Hkv) blocks through the
+    same clamped block-row index map as their page)."""
     return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
-                          *_one_row(block_row, offset, chunk_len), interpret,
-                          "paged_prefill_attention_quant")
+                          *_one_row(block_row, offset, chunk_len), layer,
+                          interpret, "paged_prefill_attention_quant")
 
 
 def paged_prefill_attention_ragged_quant_pallas(q, k_pages, v_pages, k_scales,
                                                 v_scales, block_rows, offsets,
-                                                lens, *, interpret: bool):
+                                                lens, layer=0, *,
+                                                interpret: bool):
     """`paged_prefill_attention_ragged_pallas` over a quantized pool."""
     return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
-                          block_rows, offsets, lens, interpret,
+                          block_rows, offsets, lens, layer, interpret,
                           "paged_prefill_attention_ragged_quant")

@@ -19,50 +19,12 @@ from repro.models import paged_cache as pc
 NEG_INF = -1e30
 
 
-def paged_prefill_attention_ref(q, k_pages, v_pages, block_row, offset,
-                                chunk_len):
-    """q: (1, C, Hq, hd) chunk queries (RoPE already applied, chunk K/V
-    already written to the pages); k/v_pages: (n_pages, page, Hkv, hd);
-    block_row: (P,) int32 page ids (-1 = unmapped); offset: () tokens
-    already cached before this chunk; chunk_len: () valid tokens in the
-    chunk. Returns (1, C, Hq, hd); rows past chunk_len are unspecified
-    (the caller discards them)."""
-    B, C, Hq, hd = q.shape
-    Hkv = k_pages.shape[2]
-    rep = Hq // Hkv
-    gk = pc.gather_sequence(k_pages, block_row[None])    # (1, P*page, Hkv, hd)
-    gv = pc.gather_sequence(v_pages, block_row[None])
-    S = gk.shape[1]
-    k = jnp.repeat(gk, rep, axis=2) if rep > 1 else gk
-    v = jnp.repeat(gv, rep, axis=2) if rep > 1 else gv
-    qpos = offset + jnp.arange(C)
-    kpos = jnp.arange(S)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    logits = jnp.einsum("bqnh,bknh->bnqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale    # (1,Hq,C,S)
-    total = offset + chunk_len
-    mask = ((kpos[None, :] <= qpos[:, None])
-            & (kpos[None, :] < total))[None, None]
-    logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
-
-
-def paged_prefill_attention_ragged_ref(q, k_pages, v_pages, block_rows,
-                                       offsets, lens):
-    """Ragged multi-slot oracle: R independent chunk reads in one batch.
-
-    q: (R, C, Hq, hd) — row r is slot r's chunk queries (RoPE applied, chunk
-    K/V already written); block_rows: (R, P) per-row block-table rows;
-    offsets/lens: (R,). Returns (R, C, Hq, hd); row r positions past lens[r]
-    are unspecified (callers discard them), as is every position of padding
-    rows (lens[r] == 0).
-    """
+def _attend_rows(q, gk, gv, offsets, lens):
+    """Causal chunk SDPA of R rows over gathered contiguous K/V (R, S, Hkv,
+    hd): row r's queries sit at offsets[r].. and see keys below
+    offsets[r] + lens[r]."""
     R, C, Hq, hd = q.shape
-    Hkv = k_pages.shape[2]
-    rep = Hq // Hkv
-    gk = pc.gather_sequence(k_pages, block_rows)         # (R, P*page, Hkv, hd)
-    gv = pc.gather_sequence(v_pages, block_rows)
+    rep = Hq // gk.shape[2]
     S = gk.shape[1]
     k = jnp.repeat(gk, rep, axis=2) if rep > 1 else gk
     v = jnp.repeat(gv, rep, axis=2) if rep > 1 else gv
@@ -79,59 +41,51 @@ def paged_prefill_attention_ragged_ref(q, k_pages, v_pages, block_rows,
     return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
 
 
-def paged_prefill_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
-                                      block_row, offset, chunk_len):
-    """Quantized-pool oracle for the single-slot chunk kernel: dequantize-
-    gather into the contiguous f32 layout, then attend exactly as the float
-    oracle (docs/serving.md tolerance contract)."""
-    gk = pc.gather_sequence_dequant(k_pages, k_scales, block_row[None])
-    gv = pc.gather_sequence_dequant(v_pages, v_scales, block_row[None])
-    return _attend_chunk(q, gk, gv, offset, chunk_len).astype(q.dtype)
+def _one_row(block_row, offset, chunk_len):
+    return (block_row[None], jnp.asarray(offset, jnp.int32).reshape(1),
+            jnp.asarray(chunk_len, jnp.int32).reshape(1))
+
+
+def paged_prefill_attention_ragged_ref(q, k_pages, v_pages, block_rows,
+                                       offsets, lens, layer=0):
+    """Ragged multi-slot oracle: R independent chunk reads in one batch.
+
+    q: (R, C, Hq, hd) — row r is slot r's chunk queries (RoPE applied, chunk
+    K/V already written); k/v_pages: (L, n_pages, Hkv, page, hd), read at
+    layer `layer`; block_rows: (R, P) per-row block-table rows;
+    offsets/lens: (R,). Returns (R, C, Hq, hd); row r positions past
+    lens[r] are unspecified (callers discard them), as is every position of
+    padding rows (lens[r] == 0).
+    """
+    return _attend_rows(q, pc.gather_sequence(k_pages, block_rows, layer),
+                        pc.gather_sequence(v_pages, block_rows, layer),
+                        offsets, lens)
+
+
+def paged_prefill_attention_ref(q, k_pages, v_pages, block_row, offset,
+                                chunk_len, layer=0):
+    """The ragged oracle for one slot. q: (1, C, Hq, hd) chunk queries;
+    block_row: (P,) int32 page ids (-1 = unmapped); offset: () tokens
+    already cached before this chunk; chunk_len: () valid tokens in the
+    chunk. Returns (1, C, Hq, hd); rows past chunk_len are unspecified."""
+    return paged_prefill_attention_ragged_ref(
+        q, k_pages, v_pages, *_one_row(block_row, offset, chunk_len), layer)
 
 
 def paged_prefill_attention_ragged_quant_ref(q, k_pages, v_pages, k_scales,
                                              v_scales, block_rows, offsets,
-                                             lens):
-    """Quantized-pool oracle for the ragged multi-slot chunk kernel."""
-    gk = pc.gather_sequence_dequant(k_pages, k_scales, block_rows)
-    gv = pc.gather_sequence_dequant(v_pages, v_scales, block_rows)
-    R, C, Hq, hd = q.shape
-    Hkv = k_pages.shape[2]
-    rep = Hq // Hkv
-    S = gk.shape[1]
-    k = jnp.repeat(gk, rep, axis=2) if rep > 1 else gk
-    v = jnp.repeat(gv, rep, axis=2) if rep > 1 else gv
-    qpos = offsets[:, None] + jnp.arange(C)[None, :]              # (R, C)
-    kpos = jnp.arange(S)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    logits = jnp.einsum("bqnh,bknh->bnqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale            # (R,Hq,C,S)
-    total = (offsets + lens)[:, None, None]                       # (R, 1, 1)
-    mask = ((kpos[None, None, :] <= qpos[:, :, None])
-            & (kpos[None, None, :] < total))[:, None]             # (R,1,C,S)
-    logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
-    return out.astype(q.dtype)
+                                             lens, layer=0):
+    """Quantized-pool oracle for the ragged multi-slot chunk kernel:
+    dequantize-gather into the contiguous f32 layout, then attend exactly
+    as the float oracle (docs/serving.md tolerance contract)."""
+    gk = pc.gather_sequence_dequant(k_pages, k_scales, block_rows, layer)
+    gv = pc.gather_sequence_dequant(v_pages, v_scales, block_rows, layer)
+    return _attend_rows(q, gk, gv, offsets, lens).astype(q.dtype)
 
 
-def _attend_chunk(q, gk, gv, offset, chunk_len):
-    """Causal chunk SDPA over gathered contiguous K/V (shared tail of the
-    single-slot oracles)."""
-    B, C, Hq, hd = q.shape
-    Hkv = gk.shape[2]
-    rep = Hq // Hkv
-    S = gk.shape[1]
-    k = jnp.repeat(gk, rep, axis=2) if rep > 1 else gk
-    v = jnp.repeat(gv, rep, axis=2) if rep > 1 else gv
-    qpos = offset + jnp.arange(C)
-    kpos = jnp.arange(S)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    logits = jnp.einsum("bqnh,bknh->bnqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale    # (1,Hq,C,S)
-    total = offset + chunk_len
-    mask = ((kpos[None, :] <= qpos[:, None])
-            & (kpos[None, :] < total))[None, None]
-    logits = jnp.where(mask, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
+def paged_prefill_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
+                                      block_row, offset, chunk_len, layer=0):
+    """Quantized-pool oracle for the single-slot chunk kernel."""
+    return paged_prefill_attention_ragged_quant_ref(
+        q, k_pages, v_pages, k_scales, v_scales,
+        *_one_row(block_row, offset, chunk_len), layer)

@@ -303,22 +303,67 @@ def attention_decode(cfg: ModelConfig, params: dict, x: jax.Array,
     return out, layer_k, layer_v
 
 
+def _paged_read(cfg: ModelConfig, q, pools: dict, layer, rows, positions,
+                kernels, kernel_args):
+    """Attention read shared by the paged decode / chunk / ragged paths:
+    q (R, T, Hq, hd) at `positions` (R, T) against layer `layer` of
+    `pools` through block-table `rows` (R, P), the new tokens already
+    written. `cfg.use_pallas` streams the mapped pages through the
+    (plain, quantized) kernel pair `kernels`, called with `kernel_args`
+    after the pools; the oracle gathers `rows` into the contiguous layout
+    and runs the masked grouped SDPA the dense path runs, so dense and
+    paged backends stay bit-identical on it."""
+    from repro.models import paged_cache as pc
+    kp, vp = pools["k_pages"], pools["v_pages"]
+    quant = "k_scale" in pools
+    if cfg.use_pallas:
+        _require_kernels(cfg)
+        plain, quantized = kernels
+        if quant:
+            return quantized(q, kp, vp, pools["k_scale"], pools["v_scale"],
+                             *kernel_args, layer=layer)
+        return plain(q, kp, vp, *kernel_args, layer=layer)
+    if quant:
+        gk = pc.gather_sequence_dequant(kp, pools["k_scale"], rows, layer)
+        gv = pc.gather_sequence_dequant(vp, pools["v_scale"], rows, layer)
+        q = q.astype(jnp.float32)
+    else:
+        gk = pc.gather_sequence(kp, rows, layer)        # (R, P*page, kv, hd)
+        gv = pc.gather_sequence(vp, rows, layer)
+    ki = jnp.arange(gk.shape[1])[None, None, :]
+    mask = (ki <= positions[:, :, None])[:, None]
+    return _grouped_sdpa(q, gk, gv, mask, cfg.q_per_kv,
+                         cfg.attn_logit_softcap)
+
+
+def _paged_qkv(cfg: ModelConfig, params: dict, x: jax.Array, positions):
+    q, k, v = _project_qkv(cfg, params, x)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _paged_out(params: dict, out: jax.Array, dt) -> jax.Array:
+    return jnp.einsum("bsnh,nhd->bsd", out.astype(dt),
+                      params["wo"].astype(dt))
+
+
 def attention_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
-                           k_pages: jax.Array, v_pages: jax.Array,
-                           block_table: jax.Array, lengths: jax.Array,
+                           pools: dict, layer, block_table: jax.Array,
+                           lengths: jax.Array,
                            live_pages: Optional[int] = None,
-                           active: Optional[jax.Array] = None,
-                           k_scales: Optional[jax.Array] = None,
-                           v_scales: Optional[jax.Array] = None):
+                           active: Optional[jax.Array] = None):
     """Decode step against a paged KV pool (vLLM-style block table).
 
-    x: (B, 1, D); k_pages/v_pages: (n_pages, page, n_kv, hd) this layer's
-    pools; block_table: (B, P) page ids (-1 = unmapped); lengths: (B,) tokens
-    already cached per slot. Returns (out, new_k_pages, new_v_pages,
-    new_k_scales, new_v_scales) — the scales are None unless
-    cfg.kv_quantized, in which case k/v_scales: (n_pages, n_kv) f32 are the
-    pool's per-(page, kv-head) dequant scales and the whole path follows the
-    quantized tolerance contract (docs/serving.md) instead of bit-exactness.
+    x: (B, 1, D); pools: one attention segment's stacked pools
+    {"k_pages", "v_pages"} (L, n_pages, n_kv, page, hd), plus "k_scale" /
+    "v_scale" (L, n_pages, n_kv) f32 when cfg.kv_quantized; layer: () the
+    layer read and written (traced in the layer loop); block_table: (B, P)
+    page ids (-1 = unmapped); lengths: (B,) tokens already cached per slot.
+    Returns (out, pools) with the new token written in place; a quantized
+    pool follows the quantized tolerance contract (docs/serving.md) instead
+    of bit-exactness.
 
     live_pages (static) trims the READ width to the first `live_pages`
     block-table columns — callers pass ceil((max(lengths)+1)/page_size),
@@ -328,82 +373,43 @@ def attention_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
 
     The read path is keyed on cfg.use_pallas: the paged flash-decode kernel
     streams only mapped pages through the block table (per-step KV volume
-    O(sum lengths)); the fallback/oracle gathers the (trimmed) table into
-    the contiguous layout and runs the same masked grouped SDPA as the
-    dense path, so dense and paged backends stay bit-identical on it.
+    O(sum lengths)); the fallback/oracle gathers the (trimmed) table (see
+    `_paged_read`).
 
     `active` (B,) bool, when given, drops inactive rows' K/V writes — the
     plan/run engine defers freed slots' block-table clears, so a stale row
     may still map pages a COW sibling owns (see pc.write_token).
     """
     from repro.models import paged_cache as pc
+    from repro.kernels.paged_decode_attention import ops as pda_ops
     B, T, _ = x.shape
-    q, k, v = _project_qkv(cfg, params, x)
     positions = lengths[:, None] + jnp.arange(T)[None, :]
-    if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    dt = x.dtype
+    q, k, v = _paged_qkv(cfg, params, x, positions)
+    pools = pc.write_pools(pools, layer, "token", block_table, lengths, k, v,
+                           active=active)
     table = block_table if live_pages is None \
         else block_table[:, :live_pages]
-    if cfg.kv_quantized:
-        k_pages, v_pages, k_scales, v_scales = pc.write_token_quant(
-            k_pages, v_pages, k_scales, v_scales, block_table, lengths,
-            k, v, cfg.kv_dtype, active=active)
-        if cfg.use_pallas:
-            _require_kernels(cfg)
-            from repro.kernels.paged_decode_attention import ops as pda_ops
-            out = pda_ops.paged_decode_attention_quant(
-                q, k_pages, v_pages, k_scales, v_scales, table, lengths + T)
-        else:
-            gk = pc.gather_sequence_dequant(k_pages, k_scales, table)
-            gv = pc.gather_sequence_dequant(v_pages, v_scales, table)
-            Sc = gk.shape[1]
-            ki = jnp.arange(Sc)[None, None, :]
-            qpos = positions[:, :, None]
-            mask = (ki <= qpos)[:, None]
-            out = _grouped_sdpa(q.astype(jnp.float32), gk, gv, mask,
-                                cfg.q_per_kv, cfg.attn_logit_softcap)
-        out = out.astype(dt)
-        out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
-        return out, k_pages, v_pages, k_scales, v_scales
-    k_pages, v_pages = pc.write_token(k_pages, v_pages, block_table, lengths,
-                                      k, v, active=active)
-    if cfg.use_pallas:
-        _require_kernels(cfg)
-        from repro.kernels.paged_decode_attention import ops as pda_ops
-        # the new token was just written at position `lengths`
-        out = pda_ops.paged_decode_attention(q, k_pages, v_pages, table,
-                                             lengths + T)
-    else:
-        gk = pc.gather_sequence(k_pages, table)
-        gv = pc.gather_sequence(v_pages, table)
-        Sc = gk.shape[1]
-        ki = jnp.arange(Sc)[None, None, :]
-        qpos = positions[:, :, None]
-        mask = (ki <= qpos)[:, None]
-        out = _grouped_sdpa(q, gk, gv, mask, cfg.q_per_kv,
-                            cfg.attn_logit_softcap)
-    out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
-    return out, k_pages, v_pages, None, None
+    # the new token was just written at position `lengths`
+    out = _paged_read(cfg, q, pools, layer, table, positions,
+                      (pda_ops.paged_decode_attention,
+                       pda_ops.paged_decode_attention_quant),
+                      (table, lengths + T))
+    return _paged_out(params, out, x.dtype), pools
 
 
 def attention_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
-                                  k_pages: jax.Array, v_pages: jax.Array,
-                                  block_row: jax.Array, offset, chunk_len,
-                                  live_pages: Optional[int] = None,
-                                  k_scales: Optional[jax.Array] = None,
-                                  v_scales: Optional[jax.Array] = None):
+                                  pools: dict, layer, block_row: jax.Array,
+                                  offset, chunk_len,
+                                  live_pages: Optional[int] = None):
     """One prompt chunk against a paged KV pool (chunked prefill).
 
     x: (1, C, D) — C new tokens of ONE slot, right-padded to `chunk_len`
-    valid; block_row: (P,) the slot's block-table row; offset: () tokens
-    already written for this slot (the chunk's first logical position).
-    Writes the chunk's K/V at offset..offset+chunk_len-1, then attends each
-    chunk query causally within the chunk AND against everything the slot
-    already holds (ragged cross-chunk read). Returns (out, k_pages, v_pages,
-    k_scales, v_scales) — scales are None unless cfg.kv_quantized (see
-    attention_decode_paged).
+    valid; pools/layer: as in attention_decode_paged; block_row: (P,) the
+    slot's block-table row; offset: () tokens already written for this slot
+    (the chunk's first logical position). Writes the chunk's K/V at
+    offset..offset+chunk_len-1, then attends each chunk query causally
+    within the chunk AND against everything the slot already holds (ragged
+    cross-chunk read). Returns (out, pools).
 
     The oracle/fallback reads through the same gather + `_grouped_sdpa`
     formulation as the paged decode step — deliberately: the grouped einsum
@@ -415,77 +421,37 @@ def attention_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
     (kernels/paged_prefill_attention), which streams only the slot's mapped
     pages HBM->VMEM through the scalar-prefetched block row.
     """
-    B, C, _ = x.shape
-    q, k, v = _project_qkv(cfg, params, x)
-    positions = jnp.asarray(offset, jnp.int32) + jnp.arange(C)[None]
-    if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
     from repro.models import paged_cache as pc
-    dt = x.dtype
+    from repro.kernels.paged_prefill_attention import ops as ppa_ops
+    B, C, _ = x.shape
+    positions = jnp.asarray(offset, jnp.int32) + jnp.arange(C)[None]
+    q, k, v = _paged_qkv(cfg, params, x, positions)
+    pools = pc.write_pools(pools, layer, "prompt", block_row, k, v,
+                           chunk_len, offset=offset)
     row = block_row if live_pages is None else block_row[:live_pages]
-    if cfg.kv_quantized:
-        k_pages, v_pages, k_scales, v_scales = pc.write_prompt_quant(
-            k_pages, v_pages, k_scales, v_scales, block_row, k, v,
-            chunk_len, cfg.kv_dtype, offset=offset)
-        if cfg.use_pallas:
-            _require_kernels(cfg)
-            from repro.kernels.paged_prefill_attention import ops as ppa_ops
-            out = ppa_ops.paged_prefill_attention_quant(
-                q, k_pages, v_pages, k_scales, v_scales, row, offset,
-                chunk_len)
-        else:
-            gk = pc.gather_sequence_dequant(k_pages, k_scales, row[None])
-            gv = pc.gather_sequence_dequant(v_pages, v_scales, row[None])
-            Sc = gk.shape[1]
-            ki = jnp.arange(Sc)[None, None, :]
-            qpos = positions[:, :, None]
-            mask = (ki <= qpos)[:, None]
-            out = _grouped_sdpa(q.astype(jnp.float32), gk, gv, mask,
-                                cfg.q_per_kv, cfg.attn_logit_softcap)
-        out = out.astype(dt)
-        out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
-        return out, k_pages, v_pages, k_scales, v_scales
-    k_pages, v_pages = pc.write_prompt(k_pages, v_pages, block_row, k, v,
-                                       chunk_len, offset=offset)
-    if cfg.use_pallas:
-        _require_kernels(cfg)
-        from repro.kernels.paged_prefill_attention import ops as ppa_ops
-        out = ppa_ops.paged_prefill_attention(q, k_pages, v_pages, row,
-                                              offset, chunk_len)
-    else:
-        gk = pc.gather_sequence(k_pages, row[None])
-        gv = pc.gather_sequence(v_pages, row[None])
-        Sc = gk.shape[1]
-        ki = jnp.arange(Sc)[None, None, :]
-        qpos = positions[:, :, None]
-        mask = (ki <= qpos)[:, None]
-        out = _grouped_sdpa(q, gk, gv, mask, cfg.q_per_kv,
-                            cfg.attn_logit_softcap)
-    out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
-    return out, k_pages, v_pages, None, None
+    out = _paged_read(cfg, q, pools, layer, row[None], positions,
+                      (ppa_ops.paged_prefill_attention,
+                       ppa_ops.paged_prefill_attention_quant),
+                      (row, offset, chunk_len))
+    return _paged_out(params, out, x.dtype), pools
 
 
 def attention_prefill_ragged_paged(cfg: ModelConfig, params: dict,
-                                   x: jax.Array, k_pages: jax.Array,
-                                   v_pages: jax.Array, block_rows: jax.Array,
-                                   offsets: jax.Array, lens: jax.Array,
-                                   live_pages: Optional[int] = None,
-                                   k_scales: Optional[jax.Array] = None,
-                                   v_scales: Optional[jax.Array] = None):
+                                   x: jax.Array, pools: dict, layer,
+                                   block_rows: jax.Array, offsets: jax.Array,
+                                   lens: jax.Array,
+                                   live_pages: Optional[int] = None):
     """R prompt chunks — one per ingesting slot — against a paged KV pool in
     a single call (batched ragged ingest).
 
     x: (R, C, D) — row r is slot r's next chunk, right-padded to `lens[r]`
-    valid tokens; block_rows: (R, P) the slots' block-table rows (pre-trimmed
-    to the shared live width); offsets: (R,) tokens already written per slot.
-    Writes every row's chunk K/V (`pc.write_prompt_ragged` — distinct slots
-    own distinct pages, so the scatter is collision-free), then attends each
-    row's queries causally within its chunk AND against everything that slot
-    already holds. Returns (out, k_pages, v_pages, k_scales, v_scales) —
-    scales are None unless cfg.kv_quantized (see attention_decode_paged);
-    row r positions past lens[r] are unspecified, as are padding rows
-    (lens == 0).
+    valid tokens; pools/layer: as in attention_decode_paged; block_rows:
+    (R, P) the slots' block-table rows; offsets: (R,) tokens already
+    written per slot. Writes every row's chunk K/V (`pc.write_prompt_ragged`
+    — distinct slots own distinct pages, so the scatter is collision-free),
+    then attends each row's queries causally within its chunk AND against
+    everything that slot already holds. Returns (out, pools); row r
+    positions past lens[r] are unspecified, as are padding rows (lens == 0).
 
     Numerics contract: both read paths are row-independent — the oracle is
     the same gather + `_grouped_sdpa` formulation as the single-slot chunk
@@ -494,54 +460,19 @@ def attention_prefill_ragged_paged(cfg: ModelConfig, params: dict,
     kernel does — so batched ingest is bitwise the one-chunk-per-step
     scheduler, which is in turn bitwise monolithic prefill.
     """
-    R, C, _ = x.shape
-    q, k, v = _project_qkv(cfg, params, x)
-    positions = offsets[:, None] + jnp.arange(C)[None, :]          # (R, C)
-    if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
     from repro.models import paged_cache as pc
-    dt = x.dtype
+    from repro.kernels.paged_prefill_attention import ops as ppa_ops
+    R, C, _ = x.shape
+    positions = offsets[:, None] + jnp.arange(C)[None, :]          # (R, C)
+    q, k, v = _paged_qkv(cfg, params, x, positions)
+    pools = pc.write_pools(pools, layer, "prompt_ragged", block_rows, k, v,
+                           lens, offsets)
     rows = block_rows if live_pages is None else block_rows[:, :live_pages]
-    if cfg.kv_quantized:
-        k_pages, v_pages, k_scales, v_scales = pc.write_prompt_ragged_quant(
-            k_pages, v_pages, k_scales, v_scales, block_rows, k, v, lens,
-            offsets, cfg.kv_dtype)
-        if cfg.use_pallas:
-            _require_kernels(cfg)
-            from repro.kernels.paged_prefill_attention import ops as ppa_ops
-            out = ppa_ops.paged_prefill_attention_ragged_quant(
-                q, k_pages, v_pages, k_scales, v_scales, rows, offsets, lens)
-        else:
-            gk = pc.gather_sequence_dequant(k_pages, k_scales, rows)
-            gv = pc.gather_sequence_dequant(v_pages, v_scales, rows)
-            Sc = gk.shape[1]
-            ki = jnp.arange(Sc)[None, None, :]
-            qpos = positions[:, :, None]
-            mask = (ki <= qpos)[:, None]
-            out = _grouped_sdpa(q.astype(jnp.float32), gk, gv, mask,
-                                cfg.q_per_kv, cfg.attn_logit_softcap)
-        out = out.astype(dt)
-        out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
-        return out, k_pages, v_pages, k_scales, v_scales
-    k_pages, v_pages = pc.write_prompt_ragged(k_pages, v_pages, block_rows,
-                                              k, v, lens, offsets)
-    if cfg.use_pallas:
-        _require_kernels(cfg)
-        from repro.kernels.paged_prefill_attention import ops as ppa_ops
-        out = ppa_ops.paged_prefill_attention_ragged(q, k_pages, v_pages,
-                                                     rows, offsets, lens)
-    else:
-        gk = pc.gather_sequence(k_pages, rows)         # (R, P*page, kv, hd)
-        gv = pc.gather_sequence(v_pages, rows)
-        Sc = gk.shape[1]
-        ki = jnp.arange(Sc)[None, None, :]
-        qpos = positions[:, :, None]
-        mask = (ki <= qpos)[:, None]
-        out = _grouped_sdpa(q, gk, gv, mask, cfg.q_per_kv,
-                            cfg.attn_logit_softcap)
-    out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
-    return out, k_pages, v_pages, None, None
+    out = _paged_read(cfg, q, pools, layer, rows, positions,
+                      (ppa_ops.paged_prefill_attention_ragged,
+                       ppa_ops.paged_prefill_attention_ragged_quant),
+                      (rows, offsets, lens))
+    return _paged_out(params, out, x.dtype), pools
 
 
 def _grouped_sdpa(q, k, v, mask, q_per_kv: int, softcap: float = 0.0):

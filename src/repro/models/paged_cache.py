@@ -10,10 +10,16 @@ fallback. Callers should trim the table they read through to the live
 width (ceil(max(lengths)/page_size) columns) so even the gather stops
 paying for `max_pages_per_seq`.
 
-Layout:
-  pages:       (L, n_pages, page_size, n_kv, hd)
+Layout (head-major, the layout the Pallas kernels read, so no relayout
+sits between the pool and a kernel):
+  pages:       (L, n_pages, n_kv, page_size, hd)
+  scales:      (L, n_pages, n_kv)            (int8 / fp8 pools only)
   block_table: (B, max_pages_per_seq) int32  (-1 = unmapped)
   lengths:     (B,)
+
+Every reader and writer takes a segment's whole stacked pool and a `layer`
+index into its leading axis, and touches only the pages it addresses: the
+model's layer loop carries the pools and never slices a layer out.
 """
 from __future__ import annotations
 
@@ -28,7 +34,7 @@ import jax.numpy as jnp
 # Quantized KV pages (cfg.kv_dtype in {"int8", "fp8"}).
 #
 # Storage is the quantized pool plus a per-(page, kv-head) f32 scale tensor
-# (n_pages, n_kv). Writes requantize whole pages: dequantize the touched
+# (L, n_pages, n_kv). Writes requantize whole pages: dequantize the touched
 # page, overlay the new tokens in f32, recompute abs-max over the valid
 # positions, rescale, and scatter page + scale together. Earlier tokens in
 # a page are therefore re-rounded at most page_size times — a bounded error
@@ -55,60 +61,66 @@ def quant_scale(amax: jax.Array, kv_dtype: str) -> jax.Array:
 
 
 def _quantize(x: jax.Array, scale: jax.Array, kv_dtype: str) -> jax.Array:
-    """x: f32 (..., page, kv, hd); scale: (..., kv) -> storage dtype."""
-    y = x / scale[..., None, :, None]
+    """x: f32 (..., kv, page, hd); scale: (..., kv) -> storage dtype."""
+    y = x / scale[..., None, None]
     if kv_dtype == "int8":
         return jnp.clip(jnp.round(y), -127.0, 127.0).astype(jnp.int8)
     return y.astype(jnp.float8_e4m3fn)
 
 
-def init_paged_kv(n_layers: int, n_pages: int, page_size: int, n_kv: int,
-                  head_dim: int, batch: int, max_pages_per_seq: int,
-                  dtype=jnp.bfloat16) -> dict:
-    return {
-        "k_pages": jnp.zeros((n_layers, n_pages, page_size, n_kv, head_dim),
-                             dtype),
-        "v_pages": jnp.zeros((n_layers, n_pages, page_size, n_kv, head_dim),
-                             dtype),
-        "block_table": jnp.full((batch, max_pages_per_seq), -1, jnp.int32),
-        "lengths": jnp.zeros((batch,), jnp.int32),
-    }
+def _to_sequence(g: jax.Array) -> jax.Array:
+    """Gathered pages (B, P, kv, page, hd) -> contiguous (B, P*page, kv, hd)."""
+    B, P, kv, page, hd = g.shape
+    return jnp.swapaxes(g, 2, 3).reshape(B, P * page, kv, hd)
 
 
-def gather_sequence(pages: jax.Array, block_table: jax.Array) -> jax.Array:
-    """pages: (n_pages, page, n_kv, hd); block_table: (B, P) ->
-    contiguous (B, P*page, n_kv, hd). Unmapped (-1) pages read page 0 and
-    must be masked by `lengths` downstream."""
+def gather_sequence(pages: jax.Array, block_table: jax.Array,
+                    layer=0) -> jax.Array:
+    """pages: (L, n_pages, kv, page, hd); block_table: (B, P) -> layer
+    `layer`'s contiguous (B, P*page, n_kv, hd). Unmapped (-1) pages read
+    page 0 and must be masked by `lengths` downstream."""
     idx = jnp.maximum(block_table, 0)
-    g = pages[idx]                                   # (B, P, page, kv, hd)
-    B, P, page, kv, hd = g.shape
-    return g.reshape(B, P * page, kv, hd)
+    return _to_sequence(pages[layer, idx])
 
 
 def gather_sequence_dequant(pages: jax.Array, scales: jax.Array,
-                            block_table: jax.Array) -> jax.Array:
+                            block_table: jax.Array, layer=0) -> jax.Array:
     """`gather_sequence` for a quantized pool: dequantize per-(page, head)
     on read, returning contiguous f32 (B, P*page, n_kv, hd). scales:
-    (n_pages, n_kv) f32."""
+    (L, n_pages, n_kv) f32."""
     idx = jnp.maximum(block_table, 0)
-    g = pages[idx].astype(jnp.float32)               # (B, P, page, kv, hd)
-    g = g * scales[idx][:, :, None, :, None]
-    B, P, page, kv, hd = g.shape
-    return g.reshape(B, P * page, kv, hd)
+    g = pages[layer, idx].astype(jnp.float32)        # (B, P, kv, page, hd)
+    g = g * scales[layer, idx][..., None, None]
+    return _to_sequence(g)
+
+
+def _scatter_rows(pages: jax.Array, layer, page_ids: jax.Array,
+                  off: jax.Array, new: jax.Array) -> jax.Array:
+    """Write one hd-wide row per (token, kv head) in place.
+
+    page_ids/off: (...) per-token page id and in-page offset (page id
+    n_pages drops the token); new: (..., kv, hd). Each scattered window is
+    a single contiguous hd row of the head-major pool, which keeps the
+    pool in the kernels' layout when the write sits inside a layer loop (a
+    (kv, hd) window made XLA pick another layout for the loop's carry and
+    relay the whole pool every layer)."""
+    heads = jnp.arange(pages.shape[2])
+    return pages.at[layer, page_ids[..., None], heads,
+                    off[..., None]].set(new.astype(pages.dtype), mode="drop")
 
 
 def write_token(pages_k: jax.Array, pages_v: jax.Array, block_table: jax.Array,
                 lengths: jax.Array, new_k: jax.Array, new_v: jax.Array,
-                active: Optional[jax.Array] = None
+                active: Optional[jax.Array] = None, layer=0
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Write one token per slot at its current length.
+    """Write one token per slot at its current length into layer `layer`.
 
-    pages_*: (n_pages, page, kv, hd); new_*: (B, 1, kv, hd). `active` (B,)
-    bool, when given, drops inactive rows' writes entirely — the engine's
-    plan/run loop pushes freed rows' block-table clears lazily (at most one
-    table transfer per step), so a freed slot's stale row may still map
-    pages a COW sibling owns; masking here keeps those pages untouched."""
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    pages_*: (L, n_pages, kv, page, hd); new_*: (B, 1, kv, hd). `active`
+    (B,) bool, when given, drops inactive rows' writes entirely — the
+    engine's plan/run loop pushes freed rows' block-table clears lazily (at
+    most one table transfer per step), so a freed slot's stale row may still
+    map pages a COW sibling owns; masking here keeps those pages untouched."""
+    n_pages, page_size = pages_k.shape[1], pages_k.shape[3]
     pos = lengths
     page_of = jnp.take_along_axis(block_table, (pos // page_size)[:, None],
                                   axis=1, mode="clip")[:, 0]    # (B,)
@@ -120,42 +132,37 @@ def write_token(pages_k: jax.Array, pages_v: jax.Array, block_table: jax.Array,
         safe_page = jnp.where(active, safe_page, n_pages)
     # cast-to-pool is a no-op at the default kv_dtype; kv_dtype="bfloat16"
     # stores a narrower non-quantized pool than the compute dtype
-    pages_k = pages_k.at[safe_page, off].set(
-        new_k[:, 0].astype(pages_k.dtype), mode="drop")
-    pages_v = pages_v.at[safe_page, off].set(
-        new_v[:, 0].astype(pages_v.dtype), mode="drop")
-    return pages_k, pages_v
+    return (_scatter_rows(pages_k, layer, safe_page, off, new_k[:, 0]),
+            _scatter_rows(pages_v, layer, safe_page, off, new_v[:, 0]))
 
 
 def write_prompt(pages_k: jax.Array, pages_v: jax.Array, block_row: jax.Array,
                  new_k: jax.Array, new_v: jax.Array, prompt_len: jax.Array,
-                 offset=0) -> Tuple[jax.Array, jax.Array]:
+                 offset=0, layer=0) -> Tuple[jax.Array, jax.Array]:
     """Scatter a prefilled prompt (or prompt chunk) K/V into one sequence's
-    pages.
+    pages of layer `layer`.
 
-    pages_*: (n_pages, page, kv, hd); block_row: (P,) this sequence's block-
-    table row; new_*: (1, S, kv, hd) right-padded; prompt_len: () valid count
-    in new_*; offset: () logical position of new_*[0, 0] — chunked prefill
-    writes chunk i at offset i * chunk, spanning page boundaries freely.
+    pages_*: (L, n_pages, kv, page, hd); block_row: (P,) this sequence's
+    block-table row; new_*: (1, S, kv, hd) right-padded; prompt_len: ()
+    valid count in new_*; offset: () logical position of new_*[0, 0] —
+    chunked prefill writes chunk i at offset i * chunk, spanning page
+    boundaries freely.
     """
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    n_pages, page_size = pages_k.shape[1], pages_k.shape[3]
     S = new_k.shape[1]
     pos = jnp.asarray(offset, jnp.int32) + jnp.arange(S)
     page_of = jnp.take(block_row, pos // page_size, mode="clip")
     valid = (jnp.arange(S) < prompt_len) & (page_of >= 0)
     safe_page = jnp.where(valid, page_of, n_pages)       # OOB rows dropped
     off = pos % page_size
-    pages_k = pages_k.at[safe_page, off].set(
-        new_k[0].astype(pages_k.dtype), mode="drop")
-    pages_v = pages_v.at[safe_page, off].set(
-        new_v[0].astype(pages_v.dtype), mode="drop")
-    return pages_k, pages_v
+    return (_scatter_rows(pages_k, layer, safe_page, off, new_k[0]),
+            _scatter_rows(pages_v, layer, safe_page, off, new_v[0]))
 
 
 def write_prompt_ragged(pages_k: jax.Array, pages_v: jax.Array,
                         block_rows: jax.Array, new_k: jax.Array,
-                        new_v: jax.Array, lens: jax.Array, offsets: jax.Array
-                        ) -> Tuple[jax.Array, jax.Array]:
+                        new_v: jax.Array, lens: jax.Array, offsets: jax.Array,
+                        layer=0) -> Tuple[jax.Array, jax.Array]:
     """Scatter R slots' prompt chunks into their pages in one shot.
 
     The batched-ingest analogue of `write_prompt`: row r holds slot r's next
@@ -165,10 +172,10 @@ def write_prompt_ragged(pages_k: jax.Array, pages_v: jax.Array,
     scatter is order-independent — row r's writes are bitwise what a
     `write_prompt` call for that row alone would produce.
 
-    pages_*: (n_pages, page, kv, hd); block_rows: (R, P); new_*: (R, C, kv,
-    hd); lens/offsets: (R,). Padding rows (lens == 0) write nothing.
+    pages_*: (L, n_pages, kv, page, hd); block_rows: (R, P); new_*: (R, C,
+    kv, hd); lens/offsets: (R,). Padding rows (lens == 0) write nothing.
     """
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    n_pages, page_size = pages_k.shape[1], pages_k.shape[3]
     R, C = new_k.shape[0], new_k.shape[1]
     pos = offsets[:, None] + jnp.arange(C)[None, :]            # (R, C)
     page_of = jnp.take_along_axis(block_rows, pos // page_size, axis=1,
@@ -176,18 +183,15 @@ def write_prompt_ragged(pages_k: jax.Array, pages_v: jax.Array,
     valid = (jnp.arange(C)[None, :] < lens[:, None]) & (page_of >= 0)
     safe_page = jnp.where(valid, page_of, n_pages)             # OOB dropped
     off = pos % page_size
-    pages_k = pages_k.at[safe_page, off].set(
-        new_k.astype(pages_k.dtype), mode="drop")
-    pages_v = pages_v.at[safe_page, off].set(
-        new_v.astype(pages_v.dtype), mode="drop")
-    return pages_k, pages_v
+    return (_scatter_rows(pages_k, layer, safe_page, off, new_k),
+            _scatter_rows(pages_v, layer, safe_page, off, new_v))
 
 
 def write_token_quant(pages_k: jax.Array, pages_v: jax.Array,
                       scales_k: jax.Array, scales_v: jax.Array,
                       block_table: jax.Array, lengths: jax.Array,
                       new_k: jax.Array, new_v: jax.Array, kv_dtype: str,
-                      active: Optional[jax.Array] = None
+                      active: Optional[jax.Array] = None, layer=0
                       ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """`write_token` for a quantized pool: requantize each slot's tail page.
 
@@ -195,7 +199,7 @@ def write_token_quant(pages_k: jax.Array, pages_v: jax.Array,
     eagerly), so rewriting the whole page never clobbers a sibling. Garbage
     positions past the new token are zeroed out of both the abs-max and the
     stored page."""
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    n_pages, page_size = pages_k.shape[1], pages_k.shape[3]
     B = lengths.shape[0]
     pos = lengths
     page_of = jnp.take_along_axis(block_table, (pos // page_size)[:, None],
@@ -208,15 +212,15 @@ def write_token_quant(pages_k: jax.Array, pages_v: jax.Array,
     valid = jnp.arange(page_size)[None, :] <= off[:, None]       # (B, page)
 
     def one(pages, scales, new):
-        deq = pages[idx].astype(jnp.float32)                     # (B, pg, kv, hd)
-        deq = deq * scales[idx][:, None, :, None]
-        deq = deq.at[jnp.arange(B), off].set(new[:, 0].astype(jnp.float32))
-        deq = jnp.where(valid[:, :, None, None], deq, 0.0)
-        amax = jnp.max(jnp.abs(deq), axis=(1, 3))                # (B, kv)
+        deq = pages[layer, idx].astype(jnp.float32)              # (B, kv, pg, hd)
+        deq = deq * scales[layer, idx][:, :, None, None]
+        deq = deq.at[jnp.arange(B), :, off].set(new[:, 0].astype(jnp.float32))
+        deq = jnp.where(valid[:, None, :, None], deq, 0.0)
+        amax = jnp.max(jnp.abs(deq), axis=(2, 3))                # (B, kv)
         scale = quant_scale(amax, kv_dtype)
         q = _quantize(deq, scale, kv_dtype)
-        pages = pages.at[safe_page].set(q, mode="drop")
-        scales = scales.at[safe_page].set(scale, mode="drop")
+        pages = pages.at[layer, safe_page].set(q, mode="drop")
+        scales = scales.at[layer, safe_page].set(scale, mode="drop")
         return pages, scales
 
     pages_k, scales_k = one(pages_k, scales_k, new_k)
@@ -224,27 +228,28 @@ def write_token_quant(pages_k: jax.Array, pages_v: jax.Array,
     return pages_k, pages_v, scales_k, scales_v
 
 
-def _quant_chunk_scatter(pages, scales, page_ids, kpos, newg, inchunk, valid,
-                         kv_dtype):
+def _quant_chunk_scatter(pages, scales, layer, page_ids, newg, inchunk,
+                         valid, kv_dtype):
     """Shared tail of the quantized prompt writes: dequantize the touched
     pages, overlay the chunk tokens, requantize over valid positions, and
     scatter pages + scales (rows with nothing to write are dropped).
 
-    pages: (n_pages, pg, kv, hd); scales: (n_pages, kv); page_ids: (T,);
-    kpos: (T, pg) logical positions; newg: (T, pg, kv, hd) f32 chunk tokens
-    aligned to kpos; inchunk/valid: (T, pg) masks."""
-    n_pages = pages.shape[0]
+    pages: (L, n_pages, kv, pg, hd); scales: (L, n_pages, kv); page_ids:
+    (T,); newg: (T, pg, kv, hd) f32 chunk tokens aligned to the pages'
+    positions; inchunk/valid: (T, pg) masks."""
+    n_pages = pages.shape[1]
     idx = jnp.maximum(page_ids, 0)
-    deq = pages[idx].astype(jnp.float32) * scales[idx][:, None, :, None]
-    deq = jnp.where(inchunk[:, :, None, None], newg, deq)
-    deq = jnp.where(valid[:, :, None, None], deq, 0.0)
-    amax = jnp.max(jnp.abs(deq), axis=(1, 3))                    # (T, kv)
+    deq = pages[layer, idx].astype(jnp.float32) \
+        * scales[layer, idx][:, :, None, None]                   # (T, kv, pg, hd)
+    deq = jnp.where(inchunk[:, None, :, None], jnp.swapaxes(newg, 1, 2), deq)
+    deq = jnp.where(valid[:, None, :, None], deq, 0.0)
+    amax = jnp.max(jnp.abs(deq), axis=(2, 3))                    # (T, kv)
     scale = quant_scale(amax, kv_dtype)
     q = _quantize(deq, scale, kv_dtype)
     writes = jnp.any(inchunk, axis=1) & (page_ids >= 0)
     safe = jnp.where(writes, page_ids, n_pages)
-    pages = pages.at[safe].set(q, mode="drop")
-    scales = scales.at[safe].set(scale, mode="drop")
+    pages = pages.at[layer, safe].set(q, mode="drop")
+    scales = scales.at[layer, safe].set(scale, mode="drop")
     return pages, scales
 
 
@@ -252,14 +257,14 @@ def write_prompt_quant(pages_k: jax.Array, pages_v: jax.Array,
                        scales_k: jax.Array, scales_v: jax.Array,
                        block_row: jax.Array, new_k: jax.Array,
                        new_v: jax.Array, prompt_len: jax.Array, kv_dtype: str,
-                       offset=0
+                       offset=0, layer=0
                        ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """`write_prompt` for a quantized pool.
 
     Touched pages are rewritten whole: tokens earlier chunks already placed
     on the first touched page are dequantized, merged with the new chunk,
     and requantized under the page's fresh scale."""
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    page_size = pages_k.shape[3]
     S = new_k.shape[1]
     n_touch = S // page_size + 2          # static page-span upper bound
     first = jnp.asarray(offset, jnp.int32) // page_size
@@ -274,7 +279,7 @@ def write_prompt_quant(pages_k: jax.Array, pages_v: jax.Array,
 
     def one(pages, scales, new):
         newg = new[0].astype(jnp.float32)[cc]                    # (T, pg, kv, hd)
-        return _quant_chunk_scatter(pages, scales, page_ids, kpos, newg,
+        return _quant_chunk_scatter(pages, scales, layer, page_ids, newg,
                                     inchunk, valid, kv_dtype)
 
     pages_k, scales_k = one(pages_k, scales_k, new_k)
@@ -286,13 +291,13 @@ def write_prompt_ragged_quant(pages_k: jax.Array, pages_v: jax.Array,
                               scales_k: jax.Array, scales_v: jax.Array,
                               block_rows: jax.Array, new_k: jax.Array,
                               new_v: jax.Array, lens: jax.Array,
-                              offsets: jax.Array, kv_dtype: str
+                              offsets: jax.Array, kv_dtype: str, layer=0
                               ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                          jax.Array]:
     """`write_prompt_ragged` for a quantized pool: R slots' chunks in one
     shot. Distinct slots own distinct pages, so the flattened (R * touched)
     page rewrite never collides across rows and stays order-independent."""
-    n_pages, page_size = pages_k.shape[0], pages_k.shape[1]
+    page_size = pages_k.shape[3]
     R, C = new_k.shape[0], new_k.shape[1]
     n_touch = C // page_size + 2
     first = offsets // page_size                                  # (R,)
@@ -313,8 +318,7 @@ def write_prompt_ragged_quant(pages_k: jax.Array, pages_v: jax.Array,
                                    cc[:, :, None, None], axis=1)
         newg = newg.reshape(R * n_touch, page_size, *new.shape[2:])
         return _quant_chunk_scatter(
-            pages, scales, page_ids.reshape(-1),
-            kpos.reshape(R * n_touch, page_size), newg,
+            pages, scales, layer, page_ids.reshape(-1), newg,
             inchunk.reshape(R * n_touch, page_size),
             valid.reshape(R * n_touch, page_size), kv_dtype)
 
@@ -323,11 +327,34 @@ def write_prompt_ragged_quant(pages_k: jax.Array, pages_v: jax.Array,
     return pages_k, pages_v, scales_k, scales_v
 
 
+_WRITERS = {"token": (write_token, write_token_quant),
+            "prompt": (write_prompt, write_prompt_quant),
+            "prompt_ragged": (write_prompt_ragged, write_prompt_ragged_quant)}
+
+
+def write_pools(pools: dict, layer, what: str, *args, **kw) -> dict:
+    """Write new K/V into layer `layer` of one segment's pools
+    ({"k_pages", "v_pages"[, "k_scale", "v_scale"]}) with the writer
+    `what` names ("token", "prompt" or "prompt_ragged"), called with
+    `*args, **kw`. A pool that carries scales is quantized, at the kv_dtype
+    its storage dtype says, and takes the requantizing writer."""
+    plain, quant = _WRITERS[what]
+    if "k_scale" not in pools:
+        k, v = plain(pools["k_pages"], pools["v_pages"], *args, layer=layer,
+                     **kw)
+        return {"k_pages": k, "v_pages": v}
+    kv_dtype = "int8" if pools["k_pages"].dtype == jnp.int8 else "fp8"
+    k, v, ks, vs = quant(pools["k_pages"], pools["v_pages"], pools["k_scale"],
+                         pools["v_scale"], *args, kv_dtype, layer=layer, **kw)
+    return {"k_pages": k, "v_pages": v, "k_scale": ks, "v_scale": vs}
+
+
 def copy_page(pages: jax.Array, src: int, dst: int) -> jax.Array:
     """Copy one physical page across all layers of a segment's pool.
 
-    pages: (count, n_pages, page, kv, hd). src == dst is a no-op copy, used
-    when a fork has no partial tail page to duplicate."""
+    pages: (count, n_pages, ...) — pools and their scales alike. src == dst
+    is a no-op copy, used when a fork has no partial tail page to
+    duplicate."""
     return pages.at[:, dst].set(pages[:, src])
 
 

@@ -430,7 +430,7 @@ def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
         x = x + h
         if kv_writer is not None:
             newc = kv_writer(c, k, v)
-            return _prefill_block_tail(cfg, kind, blk, x, newc, enc_out, mesh)
+            return _block_tail(cfg, kind, blk, x, newc, enc_out, mesh)
         newc = dict(c)
         if cfg.sliding_window:
             w = cfg.sliding_window
@@ -448,7 +448,7 @@ def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
         else:
             newc["k"] = jnp.zeros_like(c["k"]).at[:, :S].set(k)
             newc["v"] = jnp.zeros_like(c["v"]).at[:, :S].set(v)
-        return _prefill_block_tail(cfg, kind, blk, x, newc, enc_out, mesh)
+        return _block_tail(cfg, kind, blk, x, newc, enc_out, mesh)
     if kind == MAMBA2:
         out, conv_s, ssd_s = ssm_lib.mamba2_fwd(
             cfg, blk["mamba"], norm(cfg, blk["norm1"], x), return_state=True)
@@ -466,10 +466,11 @@ def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
     raise ValueError(kind)
 
 
-def _prefill_block_tail(cfg: ModelConfig, kind: str, blk: dict, x, newc,
-                        enc_out, mesh=None):
-    """Post-attention prefill tail shared by the dense and paged KV writers:
-    optional cross-attention cache, then the MoE/MLP block."""
+def _block_tail(cfg: ModelConfig, kind: str, blk: dict, x, newc,
+                enc_out, mesh=None):
+    """Post-attention tail of an attention block, shared by dense and paged
+    prefill and the paged ingest and decode steps: optional cross-attention
+    cache, then the MoE/MLP block."""
     if enc_out is not None and "xattn" in blk:
         xin2 = norm(cfg, blk["norm_x"], x)
         _, ck, cv = attn_lib._project_qkv(cfg, blk["xattn"], xin2,
@@ -550,16 +551,26 @@ def _check_paged_support(cfg: ModelConfig) -> None:
         "paged KV cache does not support cross-attention caches"
 
 
+def _check_attention_only(cfg: ModelConfig) -> None:
+    assert all(kind in (ATTN, MOE, SHARED_ATTN)
+               for kind, _ in segments_of(cfg)), \
+        "chunked prefill supports attention-only stacks"
+
+
 def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
                      page_size: int, max_pages_per_seq: int,
                      spec: bool = False) -> dict:
-    """Paged cache pytree: attention segments store per-layer page pools
+    """Paged cache pytree: attention segments store stacked page pools
     addressed through one shared block table; recurrent segments (SSM/xLSTM)
     keep their O(1) per-slot dense states.
 
-      k_pages/v_pages: (count, n_pages, page_size, n_kv, hd)
+      k_pages/v_pages: (count, n_pages, n_kv, page_size, hd)
       block_table:     (batch, max_pages_per_seq) int32, -1 = unmapped
       lengths:         (batch,)
+
+    The pools are head-major, the layout the paged Pallas kernels read: one
+    (page_size, hd) tile is contiguous, so nothing relays a pool between
+    the layer loop and a kernel.
 
     cfg.kv_quantized stores the pools at the int8/fp8 storage dtype and adds
     per-(page, kv-head) f32 scale leaves k_scale/v_scale: (count, n_pages,
@@ -574,12 +585,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
     segs = []
     for kind, count in segments_of(cfg):
         if kind in (ATTN, MOE, SHARED_ATTN):
-            seg = {
-                "k_pages": mk((count, n_pages, page_size, cfg.n_kv_heads, hd),
-                              adt),
-                "v_pages": mk((count, n_pages, page_size, cfg.n_kv_heads, hd),
-                              adt),
-            }
+            sh = (count, n_pages, cfg.n_kv_heads, page_size, hd)
+            seg = {"k_pages": mk(sh, adt), "v_pages": mk(sh, adt)}
             if cfg.kv_quantized:
                 sh = (count, n_pages, cfg.n_kv_heads)
                 seg["k_scale"] = (mk(sh, jnp.float32) if spec
@@ -593,6 +600,41 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
              if spec else jnp.full((batch, max_pages_per_seq), -1, jnp.int32))
     return {"lengths": mk((batch,), jnp.int32), "block_table": table,
             "segments": segs}
+
+
+def _paged_layers(cfg: ModelConfig, params: dict, cache: dict, x, attn,
+                  other=None, mesh=None):
+    """Run every layer of a paged-cache stack over `x`; returns (x, the new
+    segment caches).
+
+    Each attention segment's stacked pools ride the layer loop's CARRY, and
+    `attn(x, blk, pools, layer, kind) -> (x, pools)` reads and writes layer
+    `layer` (traced) of them in place. Scanned as xs/ys instead, the pools
+    would be sliced out of the stack and written back whole every layer,
+    and XLA would hold a second stacked pool as scratch. Recurrent
+    segments' O(1) per-slot states scan as usual through
+    `other(x, blk, state, kind) -> (x, state)`."""
+    new_segs = []
+    for (kind, count), seg, segc in zip(segments_of(cfg), params["segments"],
+                                        cache["segments"]):
+        if kind == SHARED_ATTN:
+            x, segc = attn(x, params["shared"], segc, 0, kind)
+        elif kind in (ATTN, MOE):
+            def body(carry, inp, kind=kind):
+                x, pools = carry
+                blk, layer = inp
+                x = _constrain(cfg, mesh, x)
+                return attn(x, blk, pools, layer, kind), None
+            (x, segc), _ = _scan_or_unroll(cfg, body, (x, segc),
+                                           (seg, jnp.arange(count)))
+        else:
+            def body(x, inp, kind=kind):
+                blk, c = inp
+                x = _constrain(cfg, mesh, x)
+                return other(x, blk, c, kind)
+            x, segc = _scan_or_unroll(cfg, body, x, (seg, segc))
+        new_segs.append(segc)
+    return x, new_segs
 
 
 def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
@@ -612,46 +654,22 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     block_row = cache["block_table"][slot]
     x = _constrain(cfg, mesh, x)
 
-    def paged_writer(c, k, v):
-        if cfg.kv_quantized:
-            pk, pv, ks, vs = pc.write_prompt_quant(
-                c["k_pages"], c["v_pages"], c["k_scale"], c["v_scale"],
-                block_row, k, v, plen, cfg.kv_dtype)
-            return {"k_pages": pk, "v_pages": pv, "k_scale": ks,
-                    "v_scale": vs}
-        pk, pv = pc.write_prompt(c["k_pages"], c["v_pages"], block_row,
-                                 k, v, plen)
-        return {"k_pages": pk, "v_pages": pv}
+    def attn(x, blk, pools, layer, kind):
+        def write(pools, k, v):
+            return pc.write_pools(pools, layer, "prompt", block_row, k, v,
+                                  plen)
+        return _prefill_block(cfg, kind, blk, pools, x, positions, plens,
+                              None, mesh, kv_writer=write)
 
-    def insert_slot(big, one):
-        return jax.tree.map(
-            lambda bg, on: jax.lax.dynamic_update_slice(
-                bg, on.astype(bg.dtype), (slot,) + (0,) * (bg.ndim - 1)),
-            big, one)
-
-    def block(x, blk, c, kind):
-        if kind in (ATTN, MOE, SHARED_ATTN):
-            return _prefill_block(cfg, kind, blk, c, x, positions, plens,
-                                  None, mesh, kv_writer=paged_writer)
+    def other(x, blk, c, kind):
         x, one = _prefill_block(cfg, kind, blk, c, x, positions, plens,
                                 None, mesh)
-        return x, insert_slot(c, one)
+        return x, jax.tree.map(
+            lambda bg, on: jax.lax.dynamic_update_slice(
+                bg, on.astype(bg.dtype), (slot,) + (0,) * (bg.ndim - 1)),
+            c, one)
 
-    new_segs = []
-    for (kind, count), seg, segc in zip(segments_of(cfg), params["segments"],
-                                        cache["segments"]):
-        if kind == SHARED_ATTN:
-            x, newc = block(x, params["shared"],
-                            jax.tree.map(lambda a: a[0], segc), kind)
-            newc = jax.tree.map(lambda a: a[None], newc)
-        else:
-            def scan_body(x, inp, kind=kind):
-                blk, c = inp
-                x = _constrain(cfg, mesh, x)
-                return block(x, blk, c, kind)
-            x, newc = _scan_or_unroll(cfg, scan_body, x, (seg, segc))
-        new_segs.append(newc)
-
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, other, mesh)
     x = norm(cfg, params["final_norm"], x)
     idx = jnp.clip(plens - 1, 0, S - 1)
     last_h = jax.vmap(lambda h, i: h[i])(x, idx)
@@ -680,9 +698,7 @@ def prefill_chunk_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     prefill for those families.
     """
     _check_paged_support(cfg)
-    assert all(kind in (ATTN, MOE, SHARED_ATTN)
-               for kind, _ in segments_of(cfg)), \
-        "chunked prefill supports attention-only stacks"
+    _check_attention_only(cfg)
     x = embed(cfg, params["embed"], tokens)
     B, C, _ = x.shape
     clen = jnp.asarray(chunk_len, jnp.int32).reshape(())
@@ -690,33 +706,13 @@ def prefill_chunk_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     block_row = cache["block_table"][slot]
     x = _constrain(cfg, mesh, x)
 
-    def block(x, blk, c, kind):
-        xin = norm(cfg, blk["norm1"], x)
-        h, nk, nv, nks, nvs = attn_lib.attention_prefill_chunk_paged(
-            cfg, blk["attn"], xin, c["k_pages"], c["v_pages"], block_row,
-            off, clen, live_pages=live_pages,
-            k_scales=c.get("k_scale"), v_scales=c.get("v_scale"))
-        x = x + h
-        newc = {"k_pages": nk, "v_pages": nv}
-        if nks is not None:
-            newc["k_scale"], newc["v_scale"] = nks, nvs
-        return _prefill_block_tail(cfg, kind, blk, x, newc, None, mesh)
+    def attn(x, blk, pools, layer, kind):
+        h, pools = attn_lib.attention_prefill_chunk_paged(
+            cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools, layer,
+            block_row, off, clen, live_pages=live_pages)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh)
 
-    new_segs = []
-    for (kind, count), seg, segc in zip(segments_of(cfg), params["segments"],
-                                        cache["segments"]):
-        if kind == SHARED_ATTN:
-            x, newc = block(x, params["shared"],
-                            jax.tree.map(lambda a: a[0], segc), kind)
-            newc = jax.tree.map(lambda a: a[None], newc)
-        else:
-            def scan_body(x, inp, kind=kind):
-                blk, c = inp
-                x = _constrain(cfg, mesh, x)
-                return block(x, blk, c, kind)
-            x, newc = _scan_or_unroll(cfg, scan_body, x, (seg, segc))
-        new_segs.append(newc)
-
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh)
     x = norm(cfg, params["final_norm"], x)
     idx = jnp.clip(clen - 1, 0, C - 1)
     last_h = x[:, idx]
@@ -750,9 +746,7 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     prefill. Same attention-only restriction as `prefill_chunk_paged`.
     """
     _check_paged_support(cfg)
-    assert all(kind in (ATTN, MOE, SHARED_ATTN)
-               for kind, _ in segments_of(cfg)), \
-        "chunked prefill supports attention-only stacks"
+    _check_attention_only(cfg)
     x = embed(cfg, params["embed"], tokens)
     R, C, _ = x.shape
     slots = jnp.asarray(slots, jnp.int32)
@@ -761,33 +755,13 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     block_rows = jnp.take(cache["block_table"], slots, axis=0, mode="clip")
     x = _constrain(cfg, mesh, x)
 
-    def block(x, blk, c, kind):
-        xin = norm(cfg, blk["norm1"], x)
-        h, nk, nv, nks, nvs = attn_lib.attention_prefill_ragged_paged(
-            cfg, blk["attn"], xin, c["k_pages"], c["v_pages"], block_rows,
-            offsets, lens, live_pages=live_pages,
-            k_scales=c.get("k_scale"), v_scales=c.get("v_scale"))
-        x = x + h
-        newc = {"k_pages": nk, "v_pages": nv}
-        if nks is not None:
-            newc["k_scale"], newc["v_scale"] = nks, nvs
-        return _prefill_block_tail(cfg, kind, blk, x, newc, None, mesh)
+    def attn(x, blk, pools, layer, kind):
+        h, pools = attn_lib.attention_prefill_ragged_paged(
+            cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools, layer,
+            block_rows, offsets, lens, live_pages=live_pages)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh)
 
-    new_segs = []
-    for (kind, count), seg, segc in zip(segments_of(cfg), params["segments"],
-                                        cache["segments"]):
-        if kind == SHARED_ATTN:
-            x, newc = block(x, params["shared"],
-                            jax.tree.map(lambda a: a[0], segc), kind)
-            newc = jax.tree.map(lambda a: a[None], newc)
-        else:
-            def scan_body(x, inp, kind=kind):
-                blk, c = inp
-                x = _constrain(cfg, mesh, x)
-                return block(x, blk, c, kind)
-            x, newc = _scan_or_unroll(cfg, scan_body, x, (seg, segc))
-        new_segs.append(newc)
-
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh)
     x = norm(cfg, params["final_norm"], x)
     idx = jnp.clip(lens - 1, 0, C - 1)
     last_h = jax.vmap(lambda h, i: h[i])(x, idx)               # (R, D)
@@ -829,28 +803,16 @@ def decode_step_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     table = cache["block_table"]
     x = _constrain(cfg, mesh, x)
 
-    def block(x, blk, c, kind):
-        if kind in (ATTN, MOE, SHARED_ATTN):
-            return _decode_block_paged(cfg, kind, blk, c, x, lengths, table,
-                                       mesh, live_pages=live_pages,
-                                       active=active)
+    def attn(x, blk, pools, layer, kind):
+        h, pools = attn_lib.attention_decode_paged(
+            cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools, layer,
+            table, lengths, live_pages=live_pages, active=active)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh)
+
+    def other(x, blk, c, kind):
         return _decode_block(cfg, kind, blk, c, x, lengths, mesh)
 
-    new_segs = []
-    for (kind, count), seg, segc in zip(segments_of(cfg), params["segments"],
-                                        cache["segments"]):
-        if kind == SHARED_ATTN:
-            x, newc = block(x, params["shared"],
-                            jax.tree.map(lambda a: a[0], segc), kind)
-            newc = jax.tree.map(lambda a: a[None], newc)
-        else:
-            def scan_body(x, inp, kind=kind):
-                blk, c = inp
-                x = _constrain(cfg, mesh, x)
-                return block(x, blk, c, kind)
-            x, newc = _scan_or_unroll(cfg, scan_body, x, (seg, segc))
-        new_segs.append(newc)
-
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, other, mesh)
     x = norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], x)[:, 0]
     if mesh is not None:
@@ -931,26 +893,6 @@ def promote_slot_paged(cfg: ModelConfig, cache: dict, upload_ids,
         jnp.asarray(ctx_len, jnp.int32))
     return {"lengths": lengths, "block_table": cache["block_table"],
             "segments": new_segs}
-
-
-def _decode_block_paged(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
-                        lengths, table, mesh=None,
-                        live_pages: Optional[int] = None, active=None):
-    xin = norm(cfg, blk["norm1"], x)
-    h, nk, nv, nks, nvs = attn_lib.attention_decode_paged(
-        cfg, blk["attn"], xin, c["k_pages"], c["v_pages"], table, lengths,
-        live_pages=live_pages, active=active,
-        k_scales=c.get("k_scale"), v_scales=c.get("v_scale"))
-    x = x + h
-    newc = {"k_pages": nk, "v_pages": nv}
-    if nks is not None:
-        newc["k_scale"], newc["v_scale"] = nks, nvs
-    if kind == MOE:
-        h, _ = moe_lib.moe_fwd(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
-                               mesh=mesh)
-    else:
-        h = mlp(cfg, blk["mlp"], norm(cfg, blk["norm2"], x))
-    return x + h, newc
 
 
 def _decode_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x, lengths,

@@ -105,12 +105,6 @@ def _decode_dense_fn(cfg, params, tokens, cache, active):
     return transformer.decode_step(cfg, params, tokens, cache, active=active)
 
 
-def _decode_paged_fn(cfg, live_pages, params, tokens, cache, active):
-    return transformer.decode_step_paged(cfg, params, tokens, cache,
-                                         active=active,
-                                         live_pages=live_pages)
-
-
 def _prefill_chunk_fn(cfg, live_pages, params, tokens, cache, slot, offset,
                       chunk_len):
     return transformer.prefill_chunk_paged(cfg, params, tokens, cache, slot,
@@ -161,18 +155,15 @@ def _jitted(cfg: ModelConfig, kind: str,
             sampler: Optional[SamplerConfig] = None):
     if kind == "decode":
         return jax.jit(functools.partial(_decode_dense_fn, cfg))
-    if kind == "decode_paged":
-        # live_pages is static (the read width is a shape); the engine
-        # buckets it to powers of two, so recompiles are bounded by
-        # log2(max_pages_per_seq) variants per config
-        return jax.jit(functools.partial(_decode_paged_fn, cfg),
-                       static_argnums=(0,), donate_argnums=(3,))
     if kind == "decode_run":
         # SamplerConfig is frozen/hashable, so the fused variants share the
         # lru_cache exactly like cfg does
         return jax.jit(functools.partial(_decode_dense_run_fn, cfg, sampler),
                        donate_argnums=(2,))
     if kind == "decode_paged_run":
+        # live_pages is static (the read width is a shape); the engine
+        # buckets it to powers of two, so recompiles are bounded by
+        # log2(max_pages_per_seq) variants per config
         return jax.jit(functools.partial(_decode_paged_run_fn, cfg, sampler),
                        static_argnums=(0,), donate_argnums=(3,))
     if kind == "prefill":

@@ -76,8 +76,9 @@ def test_smoke_reference_check_catches_a_skipped_page(smoke, fleet,
     good = fleet[0]["tiny-edge-a1"]
     read = pda_ops.paged_decode_attention
 
-    def skip_first_page(q, k_pages, v_pages, table, lengths):
-        return read(q, k_pages, v_pages, table.at[:, 0].set(-1), lengths)
+    def skip_first_page(q, k_pages, v_pages, table, lengths, layer=0):
+        return read(q, k_pages, v_pages, table.at[:, 0].set(-1), lengths,
+                    layer=layer)
     monkeypatch.setattr(pda_ops, "paged_decode_attention", skip_first_page)
     # a config of its own, so this engine traces its steps with the fault
     cfg = good.cfg.with_(name="tiny-edge-a1-read-fault")

@@ -277,6 +277,16 @@ def _tol(dtype):
         dict(rtol=2e-5, atol=2e-5)
 
 
+def _stacked_pool(key, shape, dtype, layer, n_layers=3):
+    """(n_layers, n_pages, Hkv, ps, hd) head-major pool whose layer `layer`
+    holds what a one-layer token-major pool of `shape` (n_pages, ps, Hkv,
+    hd) drew from `key`; the other layers hold other draws."""
+    one = jnp.swapaxes(jax.random.normal(key, shape, dtype), 1, 2)
+    rest = jax.random.normal(jax.random.fold_in(key, 1),
+                             (n_layers,) + one.shape, dtype)
+    return rest.at[layer].set(one)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("Hq,Hkv,hd,ps,C,offset,clen", [
     (4, 2, 64, 16, 32, 0, 32),     # first chunk, exact fill
@@ -284,24 +294,49 @@ def _tol(dtype):
     (8, 2, 32, 8, 16, 23, 9),      # page-unaligned offset, partial chunk
     (4, 4, 64, 16, 24, 40, 24),    # q_per_kv == 1
 ])
-def test_paged_prefill_kernel_parity(dtype, Hq, Hkv, hd, ps, C, offset, clen):
+@pytest.mark.parametrize("layer", [0, 1])
+def test_paged_prefill_kernel_parity(dtype, Hq, Hkv, hd, ps, C, offset, clen,
+                                     layer):
+    """The single-slot and ragged kernels vs their oracles, at layer 0 and
+    the middle layer of a three-layer stack. Ragged rows: the slot, a COW
+    fork sharing its first page, and an unmapped padding row of length 0;
+    the slot's ragged row is bitwise the single-slot kernel."""
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     n_pages, P = 14, 8
     q = jax.random.normal(ks[0], (1, C, Hq, hd), dtype)
-    kp = jax.random.normal(ks[1], (n_pages, ps, Hkv, hd), dtype)
-    vp = jax.random.normal(ks[2], (n_pages, ps, Hkv, hd), dtype)
+    kp = _stacked_pool(ks[1], (n_pages, ps, Hkv, hd), dtype, layer)
+    vp = _stacked_pool(ks[2], (n_pages, ps, Hkv, hd), dtype, layer)
     need = -(-(offset + clen) // ps)
+    perm = np.asarray(jax.random.permutation(ks[3], n_pages))
     row = np.full((P,), -1, np.int32)
-    row[:need] = np.asarray(
-        jax.random.permutation(ks[3], n_pages)[:need])
+    row[:need] = perm[:need]
+    fork = np.full((P,), -1, np.int32)
+    fork[0], fork[1:need] = row[0], perm[need:2 * need - 1]
     row = jnp.asarray(row)
-    out = ppa_ops.paged_prefill_attention(q, kp, vp, row,
-                                          jnp.int32(offset), jnp.int32(clen))
-    ref = ppa_ref.paged_prefill_attention_ref(q, kp, vp, row, offset, clen)
+    out = ppa_ops.paged_prefill_attention(q, kp, vp, row, jnp.int32(offset),
+                                          jnp.int32(clen), layer=layer)
+    ref = ppa_ref.paged_prefill_attention_ref(q, kp, vp, row, offset, clen,
+                                              layer)
     np.testing.assert_allclose(
         np.asarray(out[:, :clen], np.float32),
         np.asarray(ref[:, :clen], np.float32), **_tol(dtype))
     assert not np.any(np.isnan(np.asarray(out[:, :clen], np.float32)))
+
+    qr = jnp.concatenate(
+        [q, jax.random.normal(jax.random.fold_in(ks[0], 1),
+                              (2, C, Hq, hd), dtype)])
+    rows = jnp.stack([row, jnp.asarray(fork), jnp.full_like(row, -1)])
+    offs = jnp.asarray([offset, offset, 0], jnp.int32)
+    lens = jnp.asarray([clen, clen, 0], jnp.int32)
+    out_r = ppa_ops.paged_prefill_attention_ragged(qr, kp, vp, rows, offs,
+                                                   lens, layer=layer)
+    ref_r = ppa_ref.paged_prefill_attention_ragged_ref(qr, kp, vp, rows,
+                                                       offs, lens, layer)
+    np.testing.assert_allclose(
+        np.asarray(out_r[:2, :clen], np.float32),
+        np.asarray(ref_r[:2, :clen], np.float32), **_tol(dtype))
+    np.testing.assert_array_equal(np.asarray(out_r[:1, :clen], np.float32),
+                                  np.asarray(out[:, :clen], np.float32))
 
 
 def test_paged_prefill_kernel_ignores_poisoned_pages():
@@ -312,21 +347,25 @@ def test_paged_prefill_kernel_ignores_poisoned_pages():
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     n_pages, P = 8, 6
     q = jax.random.normal(ks[0], (1, C, Hq, hd))
-    kp = np.array(jax.random.normal(ks[1], (n_pages, ps, Hkv, hd)))
-    vp = np.array(jax.random.normal(ks[2], (n_pages, ps, Hkv, hd)))
+    kp = np.array(_stacked_pool(ks[1], (n_pages, ps, Hkv, hd), jnp.float32,
+                                1))
+    vp = np.array(_stacked_pool(ks[2], (n_pages, ps, Hkv, hd), jnp.float32,
+                                1))
     total = offset + clen
     need = -(-total // ps)
     row = np.full((P,), -1, np.int32)
     row[:need] = np.arange(need)
     clean = ppa_ops.paged_prefill_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(row),
-        jnp.int32(offset), jnp.int32(clen))
-    kp[need:], vp[need:] = np.nan, np.nan                 # unmapped pages
+        jnp.int32(offset), jnp.int32(clen), layer=1)
+    kp[[0, 2]], vp[[0, 2]] = np.nan, np.nan               # other layers
+    kp[1, need:], vp[1, need:] = np.nan, np.nan           # unmapped pages
     tail = total - (need - 1) * ps
-    kp[need - 1, tail:], vp[need - 1, tail:] = np.nan, np.nan   # past total
+    kp[1, need - 1, :, tail:] = np.nan                    # past total
+    vp[1, need - 1, :, tail:] = np.nan
     out = ppa_ops.paged_prefill_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(row),
-        jnp.int32(offset), jnp.int32(clen))
+        jnp.int32(offset), jnp.int32(clen), layer=1)
     np.testing.assert_array_equal(np.asarray(out[:, :clen]),
                                   np.asarray(clean[:, :clen]))
 

@@ -55,15 +55,16 @@ def test_prompt_quant_roundtrip_error_bounded(kv_dtype):
     n_pages = P + 1
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (1, P * page, kv, hd)) * 3.0
-    pages = jnp.zeros((n_pages, page, kv, hd), pc.kv_storage_dtype(kv_dtype))
-    scales = jnp.ones((n_pages, kv), jnp.float32)
+    pages = jnp.zeros((1, n_pages, kv, page, hd),
+                      pc.kv_storage_dtype(kv_dtype))
+    scales = jnp.ones((1, n_pages, kv), jnp.float32)
     row = jnp.asarray(list(range(P)) + [-1], jnp.int32)
     pages, pages2, scales, scales2 = pc.write_prompt_quant(
         pages, pages, scales, scales, row, x, x, P * page, kv_dtype)
     dq = pc.gather_sequence_dequant(pages, scales, row[None])[:, :P * page]
     # per-(page, head) step = scale; error <= step (int8: half a step from
     # the round, doubled for slack; fp8 adds relative mantissa error)
-    step = np.asarray(scales)[np.asarray(row[:P])]           # (P, kv)
+    step = np.asarray(scales)[0][np.asarray(row[:P])]        # (P, kv)
     step = np.repeat(step[:, None, :], page, axis=1).reshape(
         1, P * page, kv)[..., None]                          # (1, S, kv, 1)
     err = np.abs(np.asarray(dq) - np.asarray(x))
@@ -83,8 +84,8 @@ def test_incremental_writes_match_bulk_within_requant_bound(kv_dtype):
     x = jax.random.normal(key, (1, page, kv, hd)) * 2.0
     row = jnp.asarray([0, -1, -1], jnp.int32)
 
-    zp = jnp.zeros((n_pages, page, kv, hd), pc.kv_storage_dtype(kv_dtype))
-    zs = jnp.ones((n_pages, kv), jnp.float32)
+    zp = jnp.zeros((1, n_pages, kv, page, hd), pc.kv_storage_dtype(kv_dtype))
+    zs = jnp.ones((1, n_pages, kv), jnp.float32)
     bk, bv, bks, bvs = pc.write_prompt_quant(zp, zp, zs, zs, row, x, x,
                                              page, kv_dtype)
     ik, iv, iks, ivs = zp, zp, zs, zs
@@ -94,11 +95,11 @@ def test_incremental_writes_match_bulk_within_requant_bound(kv_dtype):
             ik, iv, iks, ivs, table, jnp.asarray([t], jnp.int32),
             x[:, t:t + 1], x[:, t:t + 1], kv_dtype)
     # after the full page both paths saw the same abs-max
-    np.testing.assert_allclose(np.asarray(iks[0]), np.asarray(bks[0]),
+    np.testing.assert_allclose(np.asarray(iks[0, 0]), np.asarray(bks[0, 0]),
                                rtol=1e-6)
     dq_b = pc.gather_sequence_dequant(bk, bks, table)[:, :page]
     dq_i = pc.gather_sequence_dequant(ik, iks, table)[:, :page]
-    step = np.asarray(bks)[0][None, None, :, None]           # (1,1,kv,1)
+    step = np.asarray(bks)[0, 0][None, None, :, None]        # (1,1,kv,1)
     # int8's step is uniform (scale); fp8's is relative (3-bit mantissa,
     # ~12.5% spacing), so re-rounding drift scales with the value
     rel = 0.0 if kv_dtype == "int8" else 0.30
@@ -111,26 +112,29 @@ def test_quant_write_respects_unmapped_and_inactive_rows():
     rows exactly like the float writer — a stale table row must never
     requantize a page a COW sibling owns."""
     page, kv, hd = 8, 2, 4
-    pages = jnp.zeros((4, page, kv, hd), jnp.int8)
-    scales = jnp.ones((4, kv), jnp.float32)
+    pages = jnp.zeros((2, 4, kv, page, hd), jnp.int8)
+    scales = jnp.ones((2, 4, kv), jnp.float32)
     table = jnp.asarray([[2], [3]], jnp.int32)
     lens = jnp.asarray([0, 0], jnp.int32)
     new = jnp.full((2, 1, kv, hd), 5.0)
     k, v, ks, vs = pc.write_token_quant(
         pages, pages, scales, scales, table, lens, new, new,
-        "int8", active=jnp.asarray([True, False]))
-    assert np.asarray(k[2]).any(), "active row must write its page"
-    assert not np.asarray(k[3]).any(), "inactive row must be dropped"
-    np.testing.assert_array_equal(np.asarray(ks[3]), np.ones((kv,)))
+        "int8", active=jnp.asarray([True, False]), layer=1)
+    assert np.asarray(k[1, 2]).any(), "active row must write its page"
+    assert not np.asarray(k[1, 3]).any(), "inactive row must be dropped"
+    assert not np.asarray(k[0]).any(), "other layers stay untouched"
+    np.testing.assert_array_equal(np.asarray(ks[1, 3]), np.ones((kv,)))
 
 
 # ---------------------------------------------------------------------------
 # quant kernels vs dequant oracle (tight) vs float oracle (loose)
 # ---------------------------------------------------------------------------
 
-def _quant_pool(key, n_pages, page, kv, hd, kv_dtype, n_rows, lens):
+def _quant_pool(key, n_pages, page, kv, hd, kv_dtype, n_rows, lens,
+                layer=0, n_layers=3):
     """Float pool + its quantized counterpart written through the real
-    prompt writer, sharing one chained block table."""
+    prompt writer at layer `layer` of an `n_layers` stack, sharing one
+    chained block table."""
     P = max(-(-int(ln) // page) for ln in lens)
     tbl = np.full((n_rows, P), -1, np.int64)
     nxt = 0
@@ -139,62 +143,93 @@ def _quant_pool(key, n_pages, page, kv, hd, kv_dtype, n_rows, lens):
         tbl[b, :live] = np.arange(nxt, nxt + live)
         nxt += live
     table = jnp.asarray(tbl, jnp.int32)
-    kf = jax.random.normal(key, (n_pages, page, kv, hd)) * 1.5
-    vf = jax.random.normal(jax.random.split(key)[0],
-                           (n_pages, page, kv, hd)) * 1.5
-    kq = jnp.zeros((n_pages, page, kv, hd), pc.kv_storage_dtype(kv_dtype))
+    # layer `layer` holds the values a one-layer token-major pool drew from
+    # `key`; the other layers hold other draws
+    def stacked(k):
+        one = jnp.swapaxes(jax.random.normal(k, (n_pages, page, kv, hd)),
+                           1, 2) * 1.5
+        rest = jax.random.normal(jax.random.fold_in(k, 1),
+                                 (n_layers,) + one.shape)
+        return rest.at[layer].set(one)
+    kf = stacked(key)
+    vf = stacked(jax.random.split(key)[0])
+    kq = jnp.zeros(kf.shape, pc.kv_storage_dtype(kv_dtype))
     vq = jnp.zeros_like(kq)
-    ks = jnp.ones((n_pages, kv), jnp.float32)
-    vs = jnp.ones((n_pages, kv), jnp.float32)
+    ks = jnp.ones((n_layers, n_pages, kv), jnp.float32)
+    vs = jnp.ones((n_layers, n_pages, kv), jnp.float32)
     for b, ln in enumerate(lens):
         if not ln:
             continue
-        seq_k = pc.gather_sequence(kf, table[b:b + 1])[:, :int(ln)]
-        seq_v = pc.gather_sequence(vf, table[b:b + 1])[:, :int(ln)]
+        seq_k = pc.gather_sequence(kf, table[b:b + 1], layer)[:, :int(ln)]
+        seq_v = pc.gather_sequence(vf, table[b:b + 1], layer)[:, :int(ln)]
         kq, vq, ks, vs = pc.write_prompt_quant(
-            kq, vq, ks, vs, table[b], seq_k, seq_v, int(ln), kv_dtype)
+            kq, vq, ks, vs, table[b], seq_k, seq_v, int(ln), kv_dtype,
+            layer=layer)
     return kf, vf, kq, vq, ks, vs, table
 
 
+@pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
-def test_paged_decode_quant_kernel_matches_dequant_oracle(kv_dtype):
+def test_paged_decode_quant_kernel_matches_dequant_oracle(kv_dtype, layer):
+    """Rows: length 0 (unmapped, -1 everywhere), mid-page, full, and a COW
+    fork reading the full row's first two pages; at layer 0 and the middle
+    layer of a three-layer stack."""
     page, P, kv, Hq, hd = 8, 4, 2, 8, 32
     lens = [0, 13, P * page]
     key = jax.random.PRNGKey(2)
     kf, vf, kq, vq, ks, vs, table = _quant_pool(
-        key, 3 * P + 2, page, kv, hd, kv_dtype, 3, lens)
-    q = jax.random.normal(jax.random.PRNGKey(3), (3, 1, Hq, hd))
-    lens = jnp.asarray(lens, jnp.int32)
+        key, 3 * P + 2, page, kv, hd, kv_dtype, 3, lens, layer=layer)
+    table = jnp.concatenate(
+        [table, jnp.asarray([[table[2, 0], table[2, 1], -1, -1]])])
+    lens = jnp.asarray(lens + [2 * page], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(3), (4, 1, Hq, hd))
     out = pda_ops.paged_decode_attention_quant(q, kq, vq, ks, vs, table,
-                                               lens)
+                                               lens, layer=layer)
     ref = pda_ref.paged_decode_attention_quant_ref(q, kq, vq, ks, vs,
-                                                   table, lens)
+                                                   table, lens, layer)
     # same quantized pool, two reduction orders: tight
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out[0]), 0.0)
     # vs the float pool: quantization error only — loose contract
-    ref_f = pda_ref.paged_decode_attention_ref(q, kf, vf, table, lens)
+    ref_f = pda_ref.paged_decode_attention_ref(q, kf, vf, table, lens, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_f),
                                rtol=0.15, atol=0.1)
 
 
+@pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("kv_dtype", ["int8"])
-def test_paged_prefill_quant_kernel_matches_dequant_oracle(kv_dtype):
+def test_paged_prefill_quant_kernel_matches_dequant_oracle(kv_dtype, layer):
+    """The single-slot and ragged quant kernels vs their oracles at layer 0
+    and a middle layer. Ragged rows: the slot, a COW fork of it that shares
+    its first page, and an unmapped padding row of length 0."""
     page, P, kv, Hq, hd = 8, 4, 2, 8, 32
     ctx, C = 11, 8                       # chunk starts mid-page
     key = jax.random.PRNGKey(4)
     kf, vf, kq, vq, ks, vs, table = _quant_pool(
-        key, P + 2, page, kv, hd, kv_dtype, 1, [ctx + C])
-    q = jax.random.normal(jax.random.PRNGKey(5), (1, C, Hq, hd))
+        key, P + 4, page, kv, hd, kv_dtype, 1, [ctx + C], layer=layer)
+    q = jax.random.normal(jax.random.PRNGKey(5), (3, C, Hq, hd))
     out = ppa_ops.paged_prefill_attention_quant(
-        q, kq, vq, ks, vs, table[0], ctx, C)
+        q[:1], kq, vq, ks, vs, table[0], ctx, C, layer=layer)
     ref = ppa_ref.paged_prefill_attention_quant_ref(
-        q, kq, vq, ks, vs, table[0], ctx, C)
+        q[:1], kq, vq, ks, vs, table[0], ctx, C, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-    ref_f = ppa_ref.paged_prefill_attention_ref(q, kf, vf, table[0], ctx, C)
+    ref_f = ppa_ref.paged_prefill_attention_ref(q[:1], kf, vf, table[0],
+                                                ctx, C, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_f),
                                rtol=0.15, atol=0.1)
+    rows = jnp.stack([table[0], table[0].at[1:].set([P, P + 1]),
+                      jnp.full_like(table[0], -1)])
+    offs = jnp.asarray([ctx, ctx, 0], jnp.int32)
+    lens = jnp.asarray([C, C, 0], jnp.int32)
+    out_r = ppa_ops.paged_prefill_attention_ragged_quant(
+        q, kq, vq, ks, vs, rows, offs, lens, layer=layer)
+    ref_r = ppa_ref.paged_prefill_attention_ragged_quant_ref(
+        q, kq, vq, ks, vs, rows, offs, lens, layer)
+    np.testing.assert_allclose(np.asarray(out_r[:2]), np.asarray(ref_r[:2]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out_r[:1]), np.asarray(out))
 
 
 def test_paged_prefill_ragged_quant_rows_match_single():

@@ -26,22 +26,24 @@ def test_paged_decode_equals_contiguous():
     q = jax.random.normal(ks[2], (B, 1, 4, hd))
     lens = jnp.array([13, 40, 64])
 
-    # scatter the contiguous cache into a shuffled page pool
+    # scatter the contiguous cache into a shuffled page pool, head-major,
+    # at layer 1 of a two-layer stack
     n_pages = B * P + 5
-    pages_k = jnp.zeros((n_pages, page, kv, hd))
-    pages_v = jnp.zeros((n_pages, page, kv, hd))
+    pages_k = jnp.zeros((2, n_pages, kv, page, hd))
+    pages_v = jnp.zeros((2, n_pages, kv, page, hd))
     rng = np.random.default_rng(0)
     ids = rng.permutation(n_pages)[: B * P].reshape(B, P)
     for b in range(B):
         for p in range(P):
-            pages_k = pages_k.at[ids[b, p]].set(
-                contiguous_k[b, p * page:(p + 1) * page])
-            pages_v = pages_v.at[ids[b, p]].set(
-                contiguous_v[b, p * page:(p + 1) * page])
+            pages_k = pages_k.at[1, ids[b, p]].set(jnp.swapaxes(
+                contiguous_k[b, p * page:(p + 1) * page], 0, 1))
+            pages_v = pages_v.at[1, ids[b, p]].set(jnp.swapaxes(
+                contiguous_v[b, p * page:(p + 1) * page], 0, 1))
     table = jnp.asarray(ids, jnp.int32)
 
-    gk = pc.gather_sequence(pages_k, table)
-    gv = pc.gather_sequence(pages_v, table)
+    gk = pc.gather_sequence(pages_k, table, layer=1)
+    gv = pc.gather_sequence(pages_v, table, layer=1)
+    assert float(jnp.abs(pc.gather_sequence(pages_k, table)).sum()) == 0.0
     np.testing.assert_allclose(np.asarray(gk), np.asarray(contiguous_k))
     out_pc = da_ref.decode_attention_ref(q, gk, gv, lens)
     out_ct = da_ref.decode_attention_ref(q, contiguous_k, contiguous_v, lens)
@@ -50,16 +52,21 @@ def test_paged_decode_equals_contiguous():
 
 def test_write_token_lands_in_right_page():
     B, kv, hd, page, P = 2, 2, 8, 4, 3
-    pages_k = jnp.zeros((10, page, kv, hd))
-    pages_v = jnp.zeros((10, page, kv, hd))
+    pages_k = jnp.zeros((3, 10, kv, page, hd))
+    pages_v = jnp.zeros((3, 10, kv, page, hd))
     table = jnp.asarray([[7, 2, 5], [1, 3, 9]], jnp.int32)
     lens = jnp.asarray([5, 2])          # -> page idx 1 off 1 ; page idx 0 off 2
     nk = jnp.ones((B, 1, kv, hd))
     nv = jnp.full((B, 1, kv, hd), 2.0)
-    pages_k, pages_v = pc.write_token(pages_k, pages_v, table, lens, nk, nv)
-    assert float(pages_k[2, 1, 0, 0]) == 1.0       # slot 0: table[0,1]=2, off 1
-    assert float(pages_k[1, 2, 0, 0]) == 1.0       # slot 1: table[1,0]=1, off 2
-    assert float(pages_v[2, 1, 0, 0]) == 2.0
+    pages_k, pages_v = pc.write_token(pages_k, pages_v, table, lens, nk, nv,
+                                      layer=1)
+    assert float(pages_k[1, 2, 0, 1, 0]) == 1.0    # slot 0: table[0,1]=2, off 1
+    assert float(pages_k[1, 2, 1, 1, 0]) == 1.0    # ... every kv head
+    assert float(pages_k[1, 1, 0, 2, 0]) == 1.0    # slot 1: table[1,0]=1, off 2
+    assert float(pages_v[1, 2, 0, 1, 0]) == 2.0
+    # exactly the two tokens' rows of layer 1, nothing in other layers
+    assert float(pages_k.sum()) == B * kv * hd
+    assert float(pages_k[0].sum()) == float(pages_k[2].sum()) == 0.0
 
 
 def _check_allocator_conservation(lengths):
@@ -94,35 +101,37 @@ def test_write_prompt_scatter_and_unmapped_drop():
     """write_prompt lands each position in its page; padding beyond the
     prompt length and unmapped (-1) table rows never touch the pool."""
     kv, hd, page, P = 2, 4, 4, 3
-    pages_k = jnp.zeros((8, page, kv, hd))
-    pages_v = jnp.zeros((8, page, kv, hd))
+    pages_k = jnp.zeros((1, 8, kv, page, hd))
+    pages_v = jnp.zeros((1, 8, kv, page, hd))
     row = jnp.asarray([5, 1, -1], jnp.int32)
     S = 12
     k = jnp.arange(1, S + 1, dtype=jnp.float32)[None, :, None, None] \
         * jnp.ones((1, S, kv, hd))
     pk, pv = pc.write_prompt(pages_k, pages_v, row, k, 2 * k,
                              jnp.asarray(6, jnp.int32))
+    pk, pv = pk[0], pv[0]
     # positions 0..3 -> page 5, positions 4..5 -> page 1, rest dropped
-    np.testing.assert_allclose(np.asarray(pk[5, :, 0, 0]), [1, 2, 3, 4])
-    np.testing.assert_allclose(np.asarray(pk[1, :2, 0, 0]), [5, 6])
-    assert float(jnp.abs(pk[1, 2:]).sum()) == 0.0      # beyond prompt_len
+    np.testing.assert_allclose(np.asarray(pk[5, 0, :, 0]), [1, 2, 3, 4])
+    np.testing.assert_allclose(np.asarray(pk[5, 1, :, 0]), [1, 2, 3, 4])
+    np.testing.assert_allclose(np.asarray(pk[1, 0, :2, 0]), [5, 6])
+    assert float(jnp.abs(pk[1, :, 2:]).sum()) == 0.0   # beyond prompt_len
     untouched = [p for p in range(8) if p not in (1, 5)]
     for p in untouched:
         assert float(jnp.abs(pk[p]).sum()) == 0.0
-    np.testing.assert_allclose(np.asarray(pv[5, :, 0, 0]), [2, 4, 6, 8])
+    np.testing.assert_allclose(np.asarray(pv[5, 0, :, 0]), [2, 4, 6, 8])
 
 
 def test_write_token_unmapped_row_is_dropped():
     """A freed slot (block-table row -1) must not corrupt the pool — its
     pages may already belong to another request."""
     kv, hd, page = 1, 2, 4
-    pages_k = jnp.ones((4, page, kv, hd))
-    pages_v = jnp.ones((4, page, kv, hd))
+    pages_k = jnp.ones((1, 4, kv, page, hd))
+    pages_v = jnp.ones((1, 4, kv, page, hd))
     table = jnp.asarray([[-1, -1]], jnp.int32)
     nk = jnp.full((1, 1, kv, hd), 9.0)
     pk, pv = pc.write_token(pages_k, pages_v, table, jnp.asarray([2]), nk, nk)
-    np.testing.assert_allclose(np.asarray(pk), np.ones((4, page, kv, hd)))
-    np.testing.assert_allclose(np.asarray(pv), np.ones((4, page, kv, hd)))
+    np.testing.assert_allclose(np.asarray(pk), np.ones((1, 4, kv, page, hd)))
+    np.testing.assert_allclose(np.asarray(pv), np.ones((1, 4, kv, page, hd)))
 
 
 def test_allocator_fork_shares_full_pages_and_copies_tail():

@@ -1,7 +1,8 @@
 """Paged flash-decode kernel (kernels/paged_decode_attention) vs the
-gather oracle: parity across page sizes, ragged lengths (including a
-length-0 slot), unmapped tail pages, COW-forked block tables, and the
-live-width trim + use_pallas wiring in models/attention."""
+gather oracle: parity across page sizes and layers of a stacked pool,
+ragged lengths (including a length-0 slot), unmapped tail pages, COW-forked
+block tables, and the live-width trim + use_pallas wiring in
+models/attention."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,10 +19,18 @@ def _tol(dtype):
         dict(rtol=2e-5, atol=2e-5)
 
 
-def _pools(key, n_pages, page, Hkv, hd, dtype):
+def _pools(key, n_pages, page, Hkv, hd, dtype, layer=0, n_layers=3):
+    """Stacked head-major (n_layers, n_pages, Hkv, page, hd) K/V pools:
+    layer `layer` holds what a one-layer token-major pool drew from `key`,
+    the other layers other draws."""
+    def stacked(k):
+        one = jnp.swapaxes(
+            jax.random.normal(k, (n_pages, page, Hkv, hd), dtype), 1, 2)
+        rest = jax.random.normal(jax.random.fold_in(k, 1),
+                                 (n_layers,) + one.shape, dtype)
+        return rest.at[layer].set(one)
     k1, k2 = jax.random.split(key)
-    return (jax.random.normal(k1, (n_pages, page, Hkv, hd), dtype),
-            jax.random.normal(k2, (n_pages, page, Hkv, hd), dtype))
+    return stacked(k1), stacked(k2)
 
 
 def _chained_table(lens, page, P, start=0):
@@ -47,18 +56,28 @@ def _chained_table(lens, page, P, start=0):
     pytest.param(2, 16, 4, 128, 3, marks=pytest.mark.slow),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_parity(page, B, Hq, Hkv, hd, P, dtype):
+@pytest.mark.parametrize("layer", [0, 1])
+def test_paged_decode_parity(page, B, Hq, Hkv, hd, P, dtype, layer):
+    """Rows: a length-0 slot (unmapped, -1 everywhere), random lengths, a
+    mid-page partial length, and a COW fork whose half-filled first page
+    is the previous row's first page; read at layer 0 and at the middle
+    layer of a three-layer stack."""
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, 1, Hq, hd), dtype)
-    kp, vp = _pools(ks[1], B * P + 2, page, Hkv, hd, dtype)
+    q = jnp.concatenate([
+        jax.random.normal(ks[0], (B, 1, Hq, hd), dtype),
+        jax.random.normal(jax.random.fold_in(ks[0], 1), (1, 1, Hq, hd),
+                          dtype)])
+    kp, vp = _pools(ks[1], B * P + 2, page, Hkv, hd, dtype, layer)
     # ragged: always include a length-0 slot and a mid-page partial length
     lens = np.array(jax.random.randint(ks[2], (B,), 1, P * page + 1))
     lens[0] = 0
     lens[-1] = page + page // 2 if P > 1 else page // 2
     table = _chained_table(lens, page, P)
-    lens = jnp.asarray(lens, jnp.int32)
-    out = pda_ops.paged_decode_attention(q, kp, vp, table, lens)
-    ref = pda_ref.paged_decode_attention_ref(q, kp, vp, table, lens)
+    table = jnp.concatenate([table, jnp.full((1, P), -1, jnp.int32)
+                             .at[0, 0].set(table[-1, 0])])
+    lens = jnp.asarray(list(lens) + [page // 2], jnp.int32)
+    out = pda_ops.paged_decode_attention(q, kp, vp, table, lens, layer=layer)
+    ref = pda_ref.paged_decode_attention_ref(q, kp, vp, table, lens, layer)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
     assert not np.any(np.isnan(np.asarray(out, np.float32)))
@@ -70,20 +89,23 @@ def test_paged_decode_unmapped_tail_pages():
     B, Hq, Hkv, hd, page, P = 2, 4, 2, 32, 8, 5
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
     q = jax.random.normal(ks[0], (B, 1, Hq, hd))
-    kp, vp = _pools(ks[1], B * P, page, Hkv, hd, jnp.float32)
+    kp, vp = _pools(ks[1], B * P, page, Hkv, hd, jnp.float32, layer=1)
     lens = jnp.array([12, 30], jnp.int32)
     table = _chained_table(np.asarray(lens), page, P)
-    out1 = pda_ops.paged_decode_attention(q, kp, vp, table, lens)
-    # poison every page no row reads through its chain, and the in-page
-    # tail beyond each row's length
+    out1 = pda_ops.paged_decode_attention(q, kp, vp, table, lens, layer=1)
+    # poison every page no row reads through its chain, the in-page tail
+    # beyond each row's length, and every other layer of the stack
     used = set(int(p) for p in np.asarray(table).ravel() if p >= 0)
     kp2, vp2 = np.array(kp), np.array(vp)
-    for pg in range(kp2.shape[0]):
+    kp2[[0, 2]], vp2[[0, 2]] = 999.0, -999.0
+    for pg in range(kp2.shape[1]):
         if pg not in used:
-            kp2[pg], vp2[pg] = 999.0, -999.0
-    kp2[1, 12 % page:], vp2[1, 12 % page:] = 999.0, -999.0   # row 0 tail
+            kp2[1, pg], vp2[1, pg] = 999.0, -999.0
+    kp2[1, 1, :, 12 % page:] = 999.0                          # row 0 tail
+    vp2[1, 1, :, 12 % page:] = -999.0
     out2 = pda_ops.paged_decode_attention(q, jnp.asarray(kp2),
-                                          jnp.asarray(vp2), table, lens)
+                                          jnp.asarray(vp2), table, lens,
+                                          layer=1)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
 
 
@@ -103,8 +125,8 @@ def test_paged_decode_cow_forked_table():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     # unshared equivalent: copy the shared prefix pages to fresh ids
-    kp2 = kp.at[6].set(kp[0]).at[7].set(kp[1])
-    vp2 = vp.at[6].set(vp[0]).at[7].set(vp[1])
+    kp2 = kp.at[:, 6].set(kp[:, 0]).at[:, 7].set(kp[:, 1])
+    vp2 = vp.at[:, 6].set(vp[:, 0]).at[:, 7].set(vp[:, 1])
     t2 = jnp.asarray([[0, 1, 2, -1], [6, 7, 3, 4]], jnp.int32)
     out2 = pda_ops.paged_decode_attention(q, kp2, vp2, t2, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), atol=1e-6)
@@ -158,16 +180,20 @@ def test_attention_decode_paged_kernel_matches_oracle(live_pages):
     table = _chained_table(lens_np, page, P, start=1)
     lens = jnp.asarray(lens_np, jnp.int32)
 
-    out_ref, kr, vr, _, _ = attn_lib.attention_decode_paged(
-        cfg, params, x, kp, vp, table, lens, live_pages=live_pages)
-    out_pal, kk, vk, _, _ = attn_lib.attention_decode_paged(
-        cfg.with_(use_pallas=True), params, x, kp, vp, table, lens,
+    pools = {"k_pages": kp, "v_pages": vp}
+    out_ref, pr = attn_lib.attention_decode_paged(
+        cfg, params, x, pools, 1, table, lens, live_pages=live_pages)
+    out_pal, pk = attn_lib.attention_decode_paged(
+        cfg.with_(use_pallas=True), params, x, pools, 1, table, lens,
         live_pages=live_pages)
     np.testing.assert_allclose(np.asarray(out_pal), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
-    # both paths write the token identically
-    np.testing.assert_array_equal(np.asarray(kr), np.asarray(kk))
-    np.testing.assert_array_equal(np.asarray(vr), np.asarray(vk))
+    # both paths write the token identically, into layer 1 alone
+    for key in pools:
+        np.testing.assert_array_equal(np.asarray(pr[key]),
+                                      np.asarray(pk[key]))
+        np.testing.assert_array_equal(np.asarray(pr[key])[[0, 2]],
+                                      np.asarray(pools[key])[[0, 2]])
 
 
 def test_attention_decode_paged_trim_bit_identical():
@@ -182,12 +208,12 @@ def test_attention_decode_paged_trim_bit_identical():
     lens_np = np.array([9, 21])
     table = _chained_table(lens_np, page, P)
     lens = jnp.asarray(lens_np, jnp.int32)
-    full, _, _, _, _ = attn_lib.attention_decode_paged(cfg, params, x, kp, vp,
-                                                       table, lens)
+    pools = {"k_pages": kp, "v_pages": vp}
+    full, _ = attn_lib.attention_decode_paged(cfg, params, x, pools, 0,
+                                              table, lens)
     live = -(-int(lens_np.max() + 1) // page)
-    trim, _, _, _, _ = attn_lib.attention_decode_paged(cfg, params, x, kp, vp,
-                                                       table, lens,
-                                                       live_pages=live)
+    trim, _ = attn_lib.attention_decode_paged(cfg, params, x, pools, 0,
+                                              table, lens, live_pages=live)
     np.testing.assert_array_equal(np.asarray(full), np.asarray(trim))
 
 
@@ -202,8 +228,9 @@ def test_attention_paged_refuses_a_config_the_kernels_cannot_serve():
                     cfg.resolved_head_dim, jnp.float32)
     table = _chained_table(np.array([5, 9]), page, P)
     with pytest.raises(ValueError, match="softcap"):
-        attn_lib.attention_decode_paged(cfg, params, x, kp, vp, table,
-                                        jnp.asarray([5, 9], jnp.int32))
+        attn_lib.attention_decode_paged(
+            cfg, params, x, {"k_pages": kp, "v_pages": vp}, 0, table,
+            jnp.asarray([5, 9], jnp.int32))
 
 
 @pytest.mark.parametrize("override,path", [
@@ -285,8 +312,8 @@ def _pallas_names(fn, *args):
 def _kernel_args(quant: bool, ragged: bool, decode: bool):
     S = jax.ShapeDtypeStruct
     dt = jnp.int8 if quant else jnp.float32
-    pages = S((6, 8, 1, 128), dt)
-    scales = [S((6, 1), jnp.float32)] * 2 if quant else []
+    pages = S((2, 6, 1, 8, 128), dt)
+    scales = [S((2, 6, 1), jnp.float32)] * 2 if quant else []
     i32 = jnp.int32
     if decode:
         return [S((2, 1, 2, 128), jnp.float32), pages, pages, *scales,

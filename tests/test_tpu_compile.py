@@ -6,14 +6,17 @@ or layouts. These tests compile the main path's Pallas kernels at
 qwen2-1.5b and qwen3-8b head shapes (12/2 and 32/8 heads, head_dim 128,
 page 32, 128-token chunks) for one chip of a *described* v5e:2x2 topology,
 and check that the compiled program holds the Mosaic kernel
-(`tpu_custom_call`). One more compiles the served decode step of the whole
-qwen2-1.5b edge model and checks it fits a 16 GB chip.
+(`tpu_custom_call`). The served decode step of the whole qwen2-1.5b edge
+model is compiled too: it must fit a 16 GB chip, and at the benchmark's
+edge pool its decode and ragged ingest steps must read and write the
+stacked KV pool in place, with no pool-sized scratch, slice or relayout.
 
 The topology is described inside a module fixture (never at import: one
 process at a time may load the TPU library), which skips where it cannot be
 described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +29,7 @@ from repro.kernels.paged_prefill_attention import kernel as ppa_kernel
 
 HEADS = {"qwen2-1.5b": (12, 2), "qwen3-8b": (32, 8)}     # (Hq, Hkv)
 HD, PAGE, N_PAGES, BATCH, TABLE_W, CHUNK, ROWS = 128, 32, 256, 8, 32, 128, 8
+LAYERS = 4                               # stacked pool; the layer is traced
 POOL_DTYPES = {"bf16": jnp.bfloat16, "int8": jnp.int8}
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
@@ -65,19 +69,20 @@ def _compiled_text(fn, *args):
 def test_paged_decode_attention_compiles_for_v5e(spec, model, pool):
     hq, hkv = HEADS[model]
     q = spec((BATCH, 1, hq, HD), jnp.bfloat16)
-    pages = spec((N_PAGES, PAGE, hkv, HD), POOL_DTYPES[pool])
+    pages = spec((LAYERS, N_PAGES, hkv, PAGE, HD), POOL_DTYPES[pool])
     table = spec((BATCH, TABLE_W), jnp.int32)
     lens = spec((BATCH,), jnp.int32)
+    layer = spec((), jnp.int32)
     if pool == "bf16":
         text = _compiled_text(
             lambda *a: pda_kernel.paged_decode_attention_pallas(
-                *a, interpret=False), q, pages, pages, table, lens)
+                *a, interpret=False), q, pages, pages, table, lens, layer)
     else:
-        scales = spec((N_PAGES, hkv), jnp.float32)
+        scales = spec((LAYERS, N_PAGES, hkv), jnp.float32)
         text = _compiled_text(
             lambda *a: pda_kernel.paged_decode_attention_quant_pallas(
                 *a, interpret=False),
-            q, pages, pages, scales, scales, table, lens)
+            q, pages, pages, scales, scales, table, lens, layer)
     assert "tpu_custom_call" in text
 
 
@@ -86,19 +91,21 @@ def test_paged_decode_attention_compiles_for_v5e(spec, model, pool):
 def test_paged_prefill_attention_ragged_compiles_for_v5e(spec, model, pool):
     hq, hkv = HEADS[model]
     q = spec((ROWS, CHUNK, hq, HD), jnp.bfloat16)
-    pages = spec((N_PAGES, PAGE, hkv, HD), POOL_DTYPES[pool])
+    pages = spec((LAYERS, N_PAGES, hkv, PAGE, HD), POOL_DTYPES[pool])
     rows = spec((ROWS, TABLE_W), jnp.int32)
     offs = spec((ROWS,), jnp.int32)
+    layer = spec((), jnp.int32)
     if pool == "bf16":
         text = _compiled_text(
             lambda *a: ppa_kernel.paged_prefill_attention_ragged_pallas(
-                *a, interpret=False), q, pages, pages, rows, offs, offs)
+                *a, interpret=False), q, pages, pages, rows, offs, offs,
+            layer)
     else:
-        scales = spec((N_PAGES, hkv), jnp.float32)
+        scales = spec((LAYERS, N_PAGES, hkv), jnp.float32)
         text = _compiled_text(
             lambda *a: ppa_kernel.paged_prefill_attention_ragged_quant_pallas(
                 *a, interpret=False),
-            q, pages, pages, scales, scales, rows, offs, offs)
+            q, pages, pages, scales, scales, rows, offs, offs, layer)
     assert "tpu_custom_call" in text
 
 
@@ -142,3 +149,91 @@ def test_served_edge_decode_step_compiles_within_v5e_hbm(spec, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def _computations(text):
+    """HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line.strip())
+    return comps
+
+
+# what may hold a pool-sized value: the loop carry moving through the
+# program, free views of it, and the in-place scatter of the new tokens
+CARRY_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def _pool_sized(text, dims):
+    """Instructions outside fusion bodies whose array result has the pool's
+    trailing dims `dims` (one layer's pool, or the stack), other than the
+    carry's ops and scatters (a fusion counts as one when its body's root
+    is a scatter)."""
+    comps = _computations(text)
+    fused = set(re.findall(r"calls=(%[\w.-]+)", text))
+    bad = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = re.match(r"(?:ROOT )?%[\w.-]+ = \w+\[([\d,]*)\]\S* "
+                         r"([\w-]+)\(", line)
+            if m is None or not m.group(1).endswith(dims):
+                continue
+            op = m.group(2)
+            if op in CARRY_OPS or op == "scatter":
+                continue
+            body = re.search(r"calls=(%[\w.-]+)", line)
+            if op == "fusion" and body and any(
+                    ln.startswith("ROOT") and " scatter(" in ln
+                    for ln in comps.get(body.group(1), [])):
+                continue
+            bad.append(line[:200])
+    return bad
+
+
+@pytest.mark.parametrize("step", ["decode", "ingest"])
+def test_served_edge_steps_keep_the_kv_pool_in_place(spec, monkeypatch,
+                                                     step):
+    """The fan-out cell's edge engine (qwen2-1.5b, 64 slots x 2048 tokens,
+    page 32: two 1.88 GB pools): its decode step and its ragged ingest
+    step carry the stacked pools through the layer loop and scatter each
+    layer's new tokens in place. Compiled for a v5e, neither holds more
+    than 1% of the pools' bytes as scratch, and no instruction other than
+    the carry and its scatter has a pool-sized shape: no per-layer slice,
+    write-back or relayout copy of a pool."""
+    from repro.configs.pice_cloud_edge import PAIRINGS
+    from repro.kernels import runtime
+    from repro.models import transformer
+    from repro.serving import engine as engine_lib
+    from repro.serving.sampler import SamplerConfig
+    monkeypatch.setattr(runtime, "default_interpret", lambda: False)
+    cfg = next(iter(PAIRINGS["one-chip"].edges.values())).cfg
+    B, L, live = 64, 2048, 32
+
+    def place(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = place(transformer.init_paged_cache(cfg, B, B * L // PAGE, PAGE,
+                                               L // PAGE, spec=True))
+    i32 = jnp.int32
+    if step == "decode":
+        fn = engine_lib._jitted(cfg, "decode_paged_run", SamplerConfig())
+        args = (spec((B, 1), i32), cache, spec((B,), jnp.bool_),
+                spec((2,), jnp.uint32))
+    else:
+        fn = engine_lib._jitted(cfg, "prefill_ragged")
+        args = (spec((ROWS, CHUNK), i32), cache, spec((ROWS,), i32),
+                spec((ROWS,), i32), spec((ROWS,), i32))
+    compiled = fn.lower(live, params, *args).compile()
+    pools = [seg[k] for seg in cache["segments"] for k in seg]
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pools)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 100
+    dims = ",".join(map(str, pools[0].shape[1:]))
+    assert _pool_sized(compiled.as_text(), dims) == []
