@@ -29,6 +29,7 @@ ARCH_IDS = (
     "internvl2_2b",
     "minitron_8b",
     "qwen2_1p5b",
+    "deepseek_v2_lite",
 )
 
 # public --arch names (hyphenated, as assigned) -> module name
@@ -43,6 +44,7 @@ ALIASES = {
     "internvl2-2b": "internvl2_2b",
     "minitron-8b": "minitron_8b",
     "qwen2-1.5b": "qwen2_1p5b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -241,3 +241,122 @@ def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
     return _paged_decode(q, k_pages, v_pages, (k_scales, v_scales),
                          block_table, lengths, layer, interpret,
                          "paged_decode_attention_quant")
+
+
+# ---------------------------------------------------------------------------
+# Latent mode (multi-head latent attention, DeepSeek-V2)
+#
+# The pool holds one row per token, (L, n_pages, 1, page, W): the
+# normalised c_kv (`rank` lanes), the roped k_pe, zero lanes up to W. All
+# query heads ride one KV "head". Queries arrive absorbed, W lanes each:
+# q_nope through W_UK (rank lanes), q_pe, zeros. Scores are taken over all
+# W lanes of a page tile; values are the first `rank` lanes of the SAME
+# tile, so a page is streamed once. The output stays latent (rank lanes a
+# head); the caller multiplies it through W_UV.
+#
+# A page of 32 rows is a small DMA, so each grid step streams `G` pages:
+# the pool is passed G times, operand j's index map picking page p*G + j
+# of the row (clamped past the live range like the GQA mode's, so pruned
+# pages cost no DMA), and the G tiles are attended as one.
+# ---------------------------------------------------------------------------
+
+def pages_per_step(P: int, most: int) -> int:
+    """Pages streamed per grid step: the largest power of two up to `most`
+    that divides the block-table width P."""
+    g = 1
+    while g * 2 <= most and P % (g * 2) == 0:
+        g *= 2
+    return g
+
+
+def latent_tiles(kv_refs, pages, start, total, ps):
+    """The G page tiles of one grid step as one (G*ps, W) f32 tile, with
+    rows past `total` or on an unmapped page zeroed, and the (1, G*ps) key
+    mask that admits the rest."""
+    G = len(kv_refs)
+    tiles = []
+    for j in range(G):
+        row = start + j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+        tiles.append(page_tile(kv_refs[j], (row < total) & (pages[j] >= 0)))
+    kv = tiles[0] if G == 1 else jnp.concatenate(tiles, axis=0)
+    kidx = jax.lax.broadcasted_iota(jnp.int32, (1, G * ps), 1)
+    kmask = start + kidx < total
+    for j in range(G):
+        kmask &= (kidx < j * ps) | (kidx >= (j + 1) * ps) | (pages[j] >= 0)
+    return kv, kmask
+
+
+def _mla_dec_kernel(layer_ref, tbl_ref, len_ref, q_ref, *refs, G: int,
+                    ps: int, rank: int, scale: float):
+    kv_refs = refs[:G]
+    o_ref, m_scr, l_scr, acc_scr = refs[G:]
+    b = pl.program_id(0)
+    pj = pl.program_id(1)
+
+    @pl.when(pj == 0)
+    def _init():
+        init_softmax(m_scr, l_scr, acc_scr)
+
+    length = len_ref[b]
+    start = pj * (G * ps)
+
+    @pl.when(start < length)
+    def _body():
+        pages = [tbl_ref[b, pj * G + j] for j in range(G)]
+        kv, kmask = latent_tiles(kv_refs, pages, start, length, ps)
+        q = q_ref[0].astype(jnp.float32)                      # (H, W)
+        softmax_update(q, kv, kv[:, :rank], kmask, m_scr, l_scr, acc_scr,
+                       scale)
+
+    @pl.when(pj == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = softmax_result(l_scr, acc_scr).astype(o_ref.dtype)
+
+
+def mla_paged_decode_pallas(q, pages, block_table, lengths, layer=0, *,
+                            rank: int, scale: float, interpret: bool,
+                            most_pages: int = 16):
+    """Latent-mode paged flash decode. q: (B, 1, H, W) absorbed queries;
+    pages: (L, n_pages, 1, page, W) the latent pool; block_table: (B, P)
+    int32 (-1 = unmapped); lengths: (B,) valid tokens; layer: () the pool
+    layer read; rank: latent lanes that are values; scale: the softmax
+    scale. -> (B, 1, H, rank) latent outputs; zero-length rows return
+    zeros."""
+    B, _, H, W = q.shape
+    ps = pages.shape[3]
+    P = block_table.shape[1]
+    G = pages_per_step(P, most_pages)
+    table = block_table.astype(jnp.int32)
+    lens = lengths.astype(jnp.int32)
+
+    def page_map(j):
+        def index(b, p, layer_ref, tbl_ref, len_ref):
+            n_live = jax.lax.div(len_ref[b] + ps - 1, ps)
+            pi = jnp.minimum(p * G + j, jnp.maximum(n_live - 1, 0))
+            return (layer_ref[0], jnp.maximum(tbl_ref[b, pi], 0), 0, 0, 0)
+        return index
+
+    in_specs = [pl.BlockSpec((1, H, W), lambda b, p, *_: (b, 0, 0))]
+    in_specs += [pl.BlockSpec((1, 1, 1, ps, W), page_map(j))
+                 for j in range(G)]
+    kernel = functools.partial(_mla_dec_kernel, G=G, ps=ps, rank=rank,
+                               scale=float(scale))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, P // G),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, H, rank), lambda b, p, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, rank), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
+        interpret=interpret,
+        name="mla_paged_decode",
+    )(layer_operand(layer), table, lens, q[:, 0], *([pages] * G))
+    return out[:, None]

@@ -39,3 +39,15 @@ def paged_decode_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
     return _kernel.paged_decode_attention_quant_pallas(
         q, k_pages, v_pages, k_scales, v_scales, block_table, lengths, layer,
         interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def mla_paged_decode(q, pages, block_table, lengths, layer=0, *, rank: int,
+                     scale: float, interpret: Optional[bool] = None):
+    """Latent-mode paged decode (multi-head latent attention): absorbed
+    queries q (B, 1, H, W) against the latent pool (L, n_pages, 1, page,
+    W), streamed through the block table; scores over all W lanes, values
+    the first `rank` lanes of the same rows. -> (B, 1, H, rank)."""
+    return _kernel.mla_paged_decode_pallas(
+        q, pages, block_table, lengths, layer, rank=rank, scale=scale,
+        interpret=resolve_interpret(interpret))

@@ -54,3 +54,28 @@ def paged_decode_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
     gk = pc.gather_sequence_dequant(k_pages, k_scales, block_table, layer)
     gv = pc.gather_sequence_dequant(v_pages, v_scales, block_table, layer)
     return _attend(q, gk, gv, lengths).astype(q.dtype)
+
+
+def latent_attend(q, g, qpos, total, rank: int, scale: float):
+    """Latent attention over gathered rows. q: (R, T, H, W) absorbed
+    queries at positions qpos (R, T); g: (R, S, 1, W) gathered latent rows,
+    keys admitted below `total` (R,) and at or before each query's
+    position. -> (R, T, H, rank) in f32; rows with no admitted key return
+    zeros."""
+    kv = g[:, :, 0].astype(jnp.float32)                       # (R, S, W)
+    S = kv.shape[1]
+    logits = jnp.einsum("rthw,rsw->rhts", q.astype(jnp.float32), kv) * scale
+    kpos = jnp.arange(S)
+    mask = ((kpos[None, None, :] <= qpos[:, :, None])
+            & (kpos[None, None, :] < total[:, None, None]))[:, None]
+    logits = jnp.where(mask, logits, NEG_INF)
+    probs = jnp.where(mask, jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("rhts,rsc->rthc", probs, kv[..., :rank])
+
+
+def mla_paged_decode_ref(q, pages, block_table, lengths, layer=0, *,
+                         rank: int, scale: float):
+    """Oracle of the latent-mode decode kernel: gather the rows, attend."""
+    g = pc.gather_sequence(pages, block_table, layer)
+    qpos = (lengths - 1)[:, None]
+    return latent_attend(q, g, qpos, lengths, rank, scale).astype(q.dtype)

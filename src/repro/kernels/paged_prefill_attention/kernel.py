@@ -43,8 +43,8 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.paged_decode_attention.kernel import (
-    init_softmax, layer_operand, page_tile, scale_view, softmax_result,
-    softmax_update)
+    init_softmax, latent_tiles, layer_operand, page_tile, pages_per_step,
+    scale_view, softmax_result, softmax_update)
 
 
 def _paged_pref_kernel(layer_ref,              # scalar prefetch: (1,) layer
@@ -218,3 +218,107 @@ def paged_prefill_attention_ragged_quant_pallas(q, k_pages, v_pages, k_scales,
     return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
                           block_rows, offsets, lens, layer, interpret,
                           "paged_prefill_attention_ragged_quant")
+
+
+# ---------------------------------------------------------------------------
+# Latent mode (multi-head latent attention): see the decode kernel's latent
+# mode. One pool tile per page, scores over all its lanes, values its first
+# `rank` lanes; all query heads on one KV "head". A grid step holds `hb`
+# heads of one row's chunk (hb * C query rows) and G pages.
+# ---------------------------------------------------------------------------
+
+def _mla_pref_kernel(layer_ref, rows_ref, info_ref, q_ref, *refs, G: int,
+                     ps: int, C: int, rank: int, scale: float):
+    kv_refs = refs[:G]
+    o_ref, m_scr, l_scr, acc_scr = refs[G:]
+    r = pl.program_id(0)
+    pj = pl.program_id(2)
+
+    @pl.when(pj == 0)
+    def _init():
+        init_softmax(m_scr, l_scr, acc_scr)
+
+    total = info_ref[r, 0] + info_ref[r, 1]    # offset + chunk_len
+    start = pj * (G * ps)
+
+    @pl.when(start < total)
+    def _body():
+        pages = [rows_ref[r, pj * G + j] for j in range(G)]
+        kv, kmask = latent_tiles(kv_refs, pages, start, total, ps)
+        q = q_ref[0, 0].astype(jnp.float32)                   # (hb*C, W)
+        n = q.shape[0]
+        # query row j is chunk position j mod C
+        qpos = info_ref[r, 0] + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0), C)
+        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, G * ps), 1)
+        softmax_update(q, kv, kv[:, :rank], kmask & (kpos <= qpos), m_scr,
+                       l_scr, acc_scr, scale)
+
+    @pl.when(pj == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0, 0] = softmax_result(l_scr, acc_scr).astype(o_ref.dtype)
+
+
+def head_block(H: int, C: int, most_rows: int = 512) -> int:
+    """Query heads per grid step: all of them, halved while the step's
+    hb * C query rows exceed `most_rows` (VMEM holds their f32 scores and
+    accumulators)."""
+    hb = H
+    while hb * C > most_rows and hb % 2 == 0:
+        hb //= 2
+    return hb
+
+
+def mla_paged_prefill_pallas(q, pages, block_rows, offsets, lens, layer=0, *,
+                             rank: int, scale: float, interpret: bool,
+                             most_pages: int = 8):
+    """Latent-mode ragged chunk attention. q: (R, C, H, W) absorbed chunk
+    queries (each row's chunk rows already written to the pool); pages:
+    (L, n_pages, 1, page, W); block_rows: (R, P) int32 (-1 = unmapped);
+    offsets/lens: (R,) int32. -> (R, C, H, rank) latent outputs; row r
+    positions past lens[r] (all of a padding row) are unspecified."""
+    R, C, H, W = q.shape
+    ps = pages.shape[3]
+    P = block_rows.shape[1]
+    G = pages_per_step(P, most_pages)
+    hb = head_block(H, C)
+    rows = block_rows.astype(jnp.int32)
+    info = jnp.stack([jnp.asarray(offsets, jnp.int32),
+                      jnp.asarray(lens, jnp.int32)], axis=1)       # (R, 2)
+    # (R, H/hb, hb*C, W): each step's query rows head-major
+    qg = jnp.moveaxis(q, 2, 1).reshape(R, H // hb, hb * C, W)
+
+    def page_map(j):
+        def index(r, h, p, layer_ref, rows_ref, info_ref):
+            n_live = jax.lax.div(info_ref[r, 0] + info_ref[r, 1] + ps - 1, ps)
+            pi = jnp.minimum(p * G + j, jnp.maximum(n_live - 1, 0))
+            return (layer_ref[0], jnp.maximum(rows_ref[r, pi], 0), 0, 0, 0)
+        return index
+
+    in_specs = [pl.BlockSpec((1, 1, hb * C, W),
+                             lambda r, h, p, *_: (r, h, 0, 0))]
+    in_specs += [pl.BlockSpec((1, 1, 1, ps, W), page_map(j))
+                 for j in range(G)]
+    kernel = functools.partial(_mla_pref_kernel, G=G, ps=ps, C=C, rank=rank,
+                               scale=float(scale))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(R, H // hb, P // G),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, hb * C, rank),
+                               lambda r, h, p, *_: (r, h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((hb * C, 1), jnp.float32),
+            pltpu.VMEM((hb * C, 1), jnp.float32),
+            pltpu.VMEM((hb * C, rank), jnp.float32),
+        ],
+    )
+    # repro-analysis: disable=RA404 reason=head_block keeps hb * C at 512 query rows: ~6 MB of blocks and scratch at 640 lanes (tests/test_tpu_compile.py compiles it for a v5e)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, H // hb, hb * C, rank), q.dtype),
+        interpret=interpret,
+        name="mla_paged_prefill",
+    )(layer_operand(layer), rows, info, qg, *([pages] * G))
+    return jnp.moveaxis(out.reshape(R, H, C, rank), 1, 2)

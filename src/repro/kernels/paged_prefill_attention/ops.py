@@ -73,3 +73,17 @@ def paged_prefill_attention_ragged_quant(q, k_pages, v_pages, k_scales,
     return _kernel.paged_prefill_attention_ragged_quant_pallas(
         q, k_pages, v_pages, k_scales, v_scales, block_rows, offsets, lens,
         layer, interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def mla_paged_prefill(q, pages, block_rows, offsets, lens, layer=0, *,
+                      rank: int, scale: float,
+                      interpret: Optional[bool] = None):
+    """Latent-mode ragged chunk attention (multi-head latent attention): R
+    slots' absorbed chunk queries q (R, C, H, W) against the latent pool
+    (L, n_pages, 1, page, W) through their block rows (R, P); scores over
+    all W lanes, values the first `rank`. -> (R, C, H, rank); positions
+    past lens[r] are unspecified."""
+    return _kernel.mla_paged_prefill_pallas(
+        q, pages, block_rows, offsets, lens, layer, rank=rank, scale=scale,
+        interpret=resolve_interpret(interpret))

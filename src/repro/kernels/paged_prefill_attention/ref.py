@@ -89,3 +89,16 @@ def paged_prefill_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
     return paged_prefill_attention_ragged_quant_ref(
         q, k_pages, v_pages, k_scales, v_scales,
         *_one_row(block_row, offset, chunk_len), layer)
+
+
+def mla_paged_prefill_ref(q, pages, block_rows, offsets, lens, layer=0, *,
+                          rank: int, scale: float):
+    """Oracle of the latent-mode ragged chunk kernel: gather each row's
+    latent rows, attend causally within the chunk and to what the slot
+    already holds."""
+    from repro.kernels.paged_decode_attention.ref import latent_attend
+    g = pc.gather_sequence(pages, block_rows, layer)
+    C = q.shape[1]
+    qpos = offsets[:, None] + jnp.arange(C)[None, :]
+    return latent_attend(q, g, qpos, offsets + lens, rank,
+                         scale).astype(q.dtype)

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 
 from repro.models import cache as cache_lib
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope, dense_init, rmsnorm
+from repro.models.layers import (apply_rope, apply_rope_freqs, dense_init,
+                                 mla_rope_frequencies, mla_rope_mscale,
+                                 mla_softmax_scale, rmsnorm)
 
 NEG_INF = -1e30
 
@@ -32,6 +34,10 @@ def pallas_unsupported(cfg: ModelConfig, paged: bool) -> str:
         return "sliding_window is set and the kernels attend the whole cache"
     from repro.analysis.rules import LANE_MULTIPLE
     from repro.kernels.runtime import default_interpret
+    if cfg.is_mla:
+        if not paged:
+            return "latent attention has paged kernels only"
+        return ""       # latent rows are padded to 128 lanes
     hd = cfg.resolved_head_dim
     if paged and not default_interpret() and hd % LANE_MULTIPLE:
         return (f"head_dim {hd} is not a multiple of {LANE_MULTIPLE}, which "
@@ -53,6 +59,8 @@ def _require_kernels(cfg: ModelConfig, paged: bool = True) -> None:
 
 def init_attention(cfg: ModelConfig, key, cross: bool = False,
                    kv_d_model: Optional[int] = None) -> dict:
+    if cfg.is_mla:
+        return init_mla(cfg, key)
     pd = jnp.dtype(cfg.param_dtype)
     d, hd = cfg.d_model, cfg.resolved_head_dim
     n_q, n_kv = cfg.n_heads, cfg.n_kv_heads
@@ -500,3 +508,179 @@ def _grouped_sdpa(q, k, v, mask, q_per_kv: int, softcap: float = 0.0):
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bngqk,bknh->bqngh", probs.astype(v.dtype), v)
     return out.reshape(B, Tq, Nq, hd)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), no q-LoRA
+#
+#   q = x W_q -> H x (nope + rope); [c_kv, k_pe] = x W_kva; c_kv normed;
+#   [k_nope, v] = c_kv W_kvb -> H x (nope + v); rope on q_pe and the one
+#   k_pe every head shares; scores (q_nope.k_nope + q_pe.k_pe) x scale;
+#   out = softmax . v -> W_o.
+#
+# Rope is rotate-half over the rope dims at YaRN frequencies. (The
+# published DeepseekV2 code de-interleaves the rope dims first: a fixed
+# permutation of W_q's and W_kva's rope columns, so a checkpoint loads
+# into this convention by permuting those columns.)
+#
+# The full-sequence path expands k_nope and v per head. The paged paths
+# cache only [c_kv, k_pe] (paged_cache.LATENT) and absorb W_kvb: queries
+# go through W_UK (q_lat = W_UK q_nope, rank lanes) and meet the cached
+# rows directly, and the attended latent goes through W_UV.
+# ---------------------------------------------------------------------------
+
+def init_mla(cfg: ModelConfig, key) -> dict:
+    pd = jnp.dtype(cfg.param_dtype)
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(k1, (d, H, nope + rope), dtype=pd),
+        "wkv_a": dense_init(k2, (d, r + rope), dtype=pd),
+        "kv_norm": jnp.ones((r,), pd),
+        "wkv_b": dense_init(k3, (r, H, nope + vd), dtype=pd),
+        "wo": dense_init(k4, (H, vd, d), in_axis=1, dtype=pd),
+    }
+
+
+def _mla_rope(cfg: ModelConfig, x, positions):
+    return apply_rope_freqs(x, positions, mla_rope_frequencies(cfg),
+                            mla_rope_mscale(cfg))
+
+
+def _mla_project(cfg: ModelConfig, params: dict, x: jax.Array, positions):
+    """-> q_nope (B,S,H,nope), roped q_pe (B,S,H,rope), normed c_kv
+    (B,S,rank) and roped k_pe (B,S,rope), in x's dtype."""
+    dt = x.dtype
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"].astype(dt))
+    kva = x @ params["wkv_a"].astype(dt)
+    c = rmsnorm(kva[..., :r], params["kv_norm"], cfg.norm_eps)
+    q_pe = _mla_rope(cfg, q[..., nope:], positions)
+    k_pe = _mla_rope(cfg, kva[..., None, r:], positions)[..., 0, :]
+    return q[..., :nope], q_pe, c, k_pe
+
+
+def mla_fwd(cfg: ModelConfig, params: dict, x: jax.Array,
+            positions: jax.Array, causal: bool = True) -> jax.Array:
+    """Latent attention over a full sequence (train / scoring), with k_nope
+    and v expanded per head. x: (B, S, D)."""
+    dt = x.dtype
+    nope = cfg.qk_nope_head_dim
+    q_nope, q_pe, c, k_pe = _mla_project(cfg, params, x, positions)
+    kv = jnp.einsum("bsr,rnh->bsnh", c, params["wkv_b"].astype(dt))
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    f32 = jnp.float32
+    logits = (jnp.einsum("bqnh,bknh->bnqk", q_nope.astype(f32),
+                         k_nope.astype(f32))
+              + jnp.einsum("bqnh,bkh->bnqk", q_pe.astype(f32),
+                           k_pe.astype(f32))) * mla_softmax_scale(cfg)
+    S = x.shape[1]
+    if causal:
+        logits = jnp.where(causal_mask(S, S), logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
+    return jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt))
+
+
+def _mla_paged(cfg: ModelConfig, params: dict, x: jax.Array, positions,
+               write, read):
+    """Shared by the paged latent paths: project, write the new tokens'
+    latent rows with `write(pools, rows)` (rows: (B, S, 1, W)), read with
+    `read(pools, q)` (absorbed q: (B, S, H, W) -> latent out (B, S, H,
+    rank)), then W_UV and W_o. Returns (out, pools)."""
+    dt = x.dtype
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    pad = cfg.latent_width - r - cfg.qk_rope_head_dim
+    q_nope, q_pe, c, k_pe = _mla_project(cfg, params, x, positions)
+    wkv_b = params["wkv_b"].astype(dt)
+    rows = jnp.concatenate([c, k_pe, jnp.zeros(c.shape[:-1] + (pad,), dt)],
+                           axis=-1)[..., None, :]
+    pools = write(rows)
+    q_lat = jnp.einsum("bsnh,rnh->bsnr", q_nope, wkv_b[..., :nope])
+    q = jnp.concatenate(
+        [q_lat, q_pe, jnp.zeros(q_pe.shape[:-1] + (pad,), dt)], axis=-1)
+    lat = read(pools, q).astype(dt)
+    out = jnp.einsum("bsnr,rnh->bsnh", lat, wkv_b[..., nope:])
+    return jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(dt)), pools
+
+
+def _latent_oracle(cfg: ModelConfig, q, pages, layer, rows, positions,
+                   total):
+    """The paged latent read without a kernel: gather, then attend."""
+    from repro.kernels.paged_decode_attention.ref import latent_attend
+    from repro.models import paged_cache as pc
+    g = pc.gather_sequence(pages, rows, layer)
+    return latent_attend(q, g, positions, total, cfg.kv_lora_rank,
+                         mla_softmax_scale(cfg))
+
+
+def mla_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
+                     pools: dict, layer, block_table: jax.Array,
+                     lengths: jax.Array, live_pages: Optional[int] = None,
+                     active: Optional[jax.Array] = None):
+    """`attention_decode_paged` for latent attention: one latent row per
+    new token into layer `layer` of the latent pool, then the absorbed read
+    (kernels/paged_decode_attention latent mode under cfg.use_pallas).
+    Returns (out, pools)."""
+    from repro.models import paged_cache as pc
+    from repro.kernels.paged_decode_attention import ops as pda_ops
+    positions = lengths[:, None] + jnp.arange(x.shape[1])[None, :]
+    table = block_table if live_pages is None \
+        else block_table[:, :live_pages]
+
+    def write(rows):
+        return pc.write_pools(pools, layer, "token", block_table, lengths,
+                              rows, None, active=active)
+
+    def read(pools, q):
+        pages = pools[pc.LATENT]
+        if cfg.use_pallas:
+            return pda_ops.mla_paged_decode(
+                q, pages, table, lengths + 1, layer, rank=cfg.kv_lora_rank,
+                scale=mla_softmax_scale(cfg))
+        return _latent_oracle(cfg, q, pages, layer, table, positions,
+                              lengths + 1)
+    return _mla_paged(cfg, params, x, positions, write, read)
+
+
+def mla_prefill_ragged_paged(cfg: ModelConfig, params: dict, x: jax.Array,
+                             pools: dict, layer, block_rows: jax.Array,
+                             offsets: jax.Array, lens: jax.Array,
+                             live_pages: Optional[int] = None):
+    """`attention_prefill_ragged_paged` for latent attention: R slots'
+    chunks' latent rows written, then each row's queries attend causally
+    within its chunk and to what its slot holds (kernels/
+    paged_prefill_attention latent mode under cfg.use_pallas). Returns
+    (out, pools)."""
+    from repro.models import paged_cache as pc
+    from repro.kernels.paged_prefill_attention import ops as ppa_ops
+    C = x.shape[1]
+    positions = offsets[:, None] + jnp.arange(C)[None, :]
+    rows_t = block_rows if live_pages is None \
+        else block_rows[:, :live_pages]
+
+    def write(rows):
+        return pc.write_pools(pools, layer, "prompt_ragged", block_rows,
+                              rows, None, lens, offsets)
+
+    def read(pools, q):
+        pages = pools[pc.LATENT]
+        if cfg.use_pallas:
+            return ppa_ops.mla_paged_prefill(
+                q, pages, rows_t, offsets, lens, layer,
+                rank=cfg.kv_lora_rank, scale=mla_softmax_scale(cfg))
+        return _latent_oracle(cfg, q, pages, layer, rows_t, positions,
+                              offsets + lens)
+    return _mla_paged(cfg, params, x, positions, write, read)
+
+
+def mla_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
+                            pools: dict, layer, block_row: jax.Array,
+                            offset, chunk_len,
+                            live_pages: Optional[int] = None):
+    """One slot's chunk: the ragged latent path with one row."""
+    return mla_prefill_ragged_paged(
+        cfg, params, x, pools, layer, block_row[None],
+        jnp.asarray(offset, jnp.int32).reshape(1),
+        jnp.asarray(chunk_len, jnp.int32).reshape(1), live_pages=live_pages)

@@ -75,6 +75,30 @@ class ModelConfig:
     # expert-parallel: shard the dispatch buffer + expert weights over the
     # `model` axis on the expert dim (all-to-all dispatch; §Perf)
     moe_ep: bool = False
+    # the serving expert layer (moe.moe_serve) in place of the capacity
+    # layer: dropless, grouped by expert, told which experts it holds
+    moe_dropless: bool = False
+    n_shared_experts: int = 0   # always-on experts, one SwiGLU of n x width
+    first_k_dense: int = 0      # leading layers with a dense MLP of d_ff
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
+    # this chip's share of an expert-parallel deployment: it holds experts
+    # [ep_rank * n, (ep_rank + 1) * n) of n_experts, n = n_experts / ep_size
+    ep_size: int = 1
+    ep_rank: int = 0
+
+    # multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): 0 = off
+    kv_lora_rank: int = 0       # width of the compressed KV latent c_kv
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (factor 0 = plain rope at rope_theta)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (Mamba2)
     ssm_state: int = 0          # state dim per head
@@ -148,6 +172,22 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one latent KV row: c_kv then the roped k_pe, padded to
+        a multiple of 128 (the TPU lane width the paged kernels tile)."""
+        w = self.kv_lora_rank + self.qk_rope_head_dim
+        return -(-w // 128) * 128
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this chip holds (its expert-parallel share)."""
+        return self.n_experts // max(self.ep_size, 1)
+
+    @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff if self.moe_d_ff else self.d_ff
 
@@ -177,13 +217,19 @@ class ModelConfig:
                     pat.append(SHARED_ATTN)
             return tuple(pat)
         if self.is_moe:
-            return tuple([MOE] * self.n_layers)
+            k = self.first_k_dense
+            return tuple([ATTN] * k + [MOE] * (self.n_layers - k))
         return tuple([ATTN] * self.n_layers)
 
     def validate(self) -> None:
         assert self.n_heads % max(self.n_kv_heads, 1) == 0, "GQA requires n_heads % n_kv_heads == 0"
         if self.is_moe:
             assert 0 < self.experts_per_token <= self.n_experts
+            assert self.n_experts % max(self.ep_size, 1) == 0, \
+                "ep_size must divide n_experts"
+            assert 0 <= self.ep_rank < max(self.ep_size, 1)
+        if self.is_mla:
+            assert self.qk_rope_head_dim % 2 == 0 and self.v_head_dim > 0
         if self.family == "hybrid":
             assert self.shared_attn_every > 0
         if self.family in ("encdec",):
@@ -269,6 +315,11 @@ class ModelConfig:
         d, hd = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.n_heads, self.n_kv_heads
         attn = d * hd * n_q + 2 * d * hd * n_kv + hd * n_q * d
+        if self.is_mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            nope, vd = self.qk_nope_head_dim, self.v_head_dim
+            attn = (d * n_q * (nope + rope) + d * (r + rope) + r
+                    + r * n_q * (nope + vd) + n_q * vd * d)
         mlp_dense = 3 * d * self.d_ff if self.d_ff else 0
         total = 0
         pat = self.block_pattern()
@@ -278,6 +329,7 @@ class ModelConfig:
                 total += attn + mlp_dense
             elif kind == MOE:
                 n_e = self.experts_per_token if active_only else self.n_experts
+                n_e += self.n_shared_experts
                 total += attn + 3 * d * self.expert_d_ff * n_e + d * self.n_experts
             elif kind == MAMBA2:
                 inner = self.ssm_expand * d

@@ -1,9 +1,11 @@
 """Common neural-net layers (pure JAX): norms, RoPE, MLPs, embeddings."""
 from __future__ import annotations
 
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 
@@ -71,11 +73,75 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
-    hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                        # (hd/2,)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
-    angles = angles[..., None, :]                              # (..., S, 1, hd/2)
+    return apply_rope_freqs(x, positions, rope_frequencies(x.shape[-1], theta))
+
+
+def yarn_frequencies(dim: int, base: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies of `dim` rotary dims (DeepSeek-V2's
+    DeepseekV2YarnRotaryEmbedding): interpolated (base frequency / factor)
+    below the correction range, extrapolated (the base frequency) above
+    it, with a linear ramp between the dims that complete `beta_fast` and
+    `beta_slow` turns over `original_max_pos` positions. Static numbers."""
+    i = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / base ** i
+    inter = extra / factor
+
+    def corr(turns):
+        return dim * math.log(original_max_pos / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def mla_rope_frequencies(cfg: ModelConfig):
+    """Inverse frequencies of an MLA model's qk_rope_head_dim rotary dims:
+    YaRN when cfg.yarn_factor is set, else plain rope at rope_theta."""
+    dim = cfg.qk_rope_head_dim
+    if not cfg.yarn_factor:
+        return rope_frequencies(dim, cfg.rope_theta)
+    return yarn_frequencies(dim, cfg.rope_theta, cfg.yarn_factor,
+                            cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                            cfg.yarn_beta_slow)
+
+
+def mla_rope_mscale(cfg: ModelConfig) -> float:
+    """YaRN's scale on cos and sin: mscale(mscale) / mscale(mscale_all_dim)
+    (1 where the two are equal, as in DeepSeek-V2)."""
+    if not cfg.yarn_factor:
+        return 1.0
+    return (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-0.5, times YaRN's mscale(mscale_all_dim) squared."""
+    s = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor:
+        m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        s *= m * m
+    return s
+
+
+def apply_rope_freqs(x: jax.Array, positions: jax.Array, inv_freq,
+                     mscale: float = 1.0) -> jax.Array:
+    """Rotate-half rope at the given inverse frequencies (d/2,), with cos
+    and sin scaled by `mscale`. x: (..., S, H, d); positions: broadcastable
+    to (..., S)."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    angles = angles[..., None, :]                       # (..., S, 1, d/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

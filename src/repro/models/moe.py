@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN with capacity-based dispatch (drop-on-overflow).
+"""Mixture-of-Experts FFN: the training layer with capacity-based dispatch
+(drop-on-overflow, `moe_fwd`), and the serving layer (`moe_serve`).
 
 Scatter/gather dispatch (no (T,E,C) one-hot einsum) so it scales to
 128-expert x 1M-token training batches:
@@ -21,19 +22,26 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, init_mlp, mlp
 
 
 def init_moe(cfg: ModelConfig, key) -> dict:
+    """The router over all n_experts; the weights of the experts this chip
+    holds (all of them unless cfg.ep_size > 1); the shared expert, one
+    SwiGLU of n_shared_experts x the expert width, when there is one."""
     pd = jnp.dtype(cfg.param_dtype)
     d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    return {
+    held = cfg.held_experts
+    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    p = {
         "router": dense_init(k1, (d, e), dtype=pd),
-        "w_gate": dense_init(k2, (e, d, f), in_axis=1, dtype=pd),
-        "w_up": dense_init(k3, (e, d, f), in_axis=1, dtype=pd),
-        "w_down": dense_init(k4, (e, f, d), in_axis=1, dtype=pd),
+        "w_gate": dense_init(k2, (held, d, f), in_axis=1, dtype=pd),
+        "w_up": dense_init(k3, (held, d, f), in_axis=1, dtype=pd),
+        "w_down": dense_init(k4, (held, f, d), in_axis=1, dtype=pd),
     }
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(cfg, k5, d, f * cfg.n_shared_experts)
+    return p
 
 
 def moe_capacity(n_tokens: int, cfg: ModelConfig) -> int:
@@ -114,3 +122,70 @@ def moe_fwd(cfg: ModelConfig, params: dict, x: jax.Array, mesh=None
     gathered = jnp.where(keep[:, None], gathered, 0.0)
     out = jnp.zeros((T, D), dt).at[token_idx].add(gathered * flat_w[:, None])
     return out.reshape(B, S, D), aux
+
+
+def route(cfg: ModelConfig, router: jax.Array, xf: jax.Array):
+    """Router over all n_experts, in f32: softmax, greedy top-k, optionally
+    renormalised, times routed_scaling_factor. -> (weights (T, K) f32,
+    experts (T, K) int32)."""
+    logits = jnp.dot(xf, router.astype(xf.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return top_w * cfg.routed_scaling_factor, top_e
+
+
+def moe_serve(cfg: ModelConfig, params: dict, x: jax.Array, stats=None
+              ) -> jax.Array:
+    """The serving expert layer. x: (..., D) -> (..., D).
+
+    Dropless and grouped: the router picks top-k of all n_experts per
+    token; the assignments to the experts this chip holds (its
+    expert-parallel share, cfg.ep_rank of cfg.ep_size) are sorted by
+    expert and run as one grouped matmul per projection
+    (kernels/moe_grouped_matmul under cfg.use_pallas, else
+    `jax.lax.ragged_dot`), weighted and summed back into their tokens.
+    Every held assignment is computed, however unevenly the router spreads
+    them: the sorted buffer has room for every token's k assignments. An
+    assignment to an expert held elsewhere adds nothing here: on one chip
+    the layer runs without its exchange, and this partial sum is what the
+    layer passes on. The shared expert is added once.
+
+    `stats`, a dict, gets "expert_rows": (held,) int32, the assignments
+    each held expert computed."""
+    shape = x.shape
+    D = shape[-1]
+    xf = x.reshape(-1, D)
+    T = xf.shape[0]
+    K, held = cfg.experts_per_token, cfg.held_experts
+    top_w, top_e = route(cfg, params["router"], xf)
+    local = top_e.reshape(-1) - cfg.ep_rank * held              # (T*K,)
+    mine = (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held)                          # held: none
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    # each token has at most min(K, held) assignments here: room for all
+    M = T * min(K, held)
+    order = jnp.argsort(key, stable=True)[:M]
+    tok = order // K
+    w = jnp.where(mine[order], top_w.reshape(-1)[order], 0.0)
+    rows = xf[tok]                                              # (M, D)
+    if cfg.use_pallas:
+        from repro.kernels.moe_grouped_matmul import ops as gmm_ops
+        gmm = gmm_ops.moe_grouped_matmul
+    else:
+        def gmm(lhs, rhs, gs):
+            return jax.lax.ragged_dot(lhs, rhs, gs)
+    dt = x.dtype
+    g = gmm(rows, params["w_gate"].astype(dt), sizes)
+    u = gmm(rows, params["w_up"].astype(dt), sizes)
+    y = gmm(jax.nn.silu(g) * u, params["w_down"].astype(dt), sizes)
+    valid = jnp.arange(M) < jnp.sum(sizes)
+    y = jnp.where(valid[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
+    out = jnp.zeros((T, D), jnp.float32).at[tok].add(y).astype(dt)
+    if "shared" in params:
+        out = out + mlp(cfg, params["shared"], xf)
+    if stats is not None:
+        stats["expert_rows"] = sizes
+    return out.reshape(shape)

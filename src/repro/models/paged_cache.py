@@ -20,6 +20,14 @@ sits between the pool and a kernel):
 Every reader and writer takes a segment's whole stacked pool and a `layer`
 index into its leading axis, and touches only the pages it addresses: the
 model's layer loop carries the pools and never slices a layer out.
+
+A multi-head latent attention (MLA) model keeps one latent pool per
+segment instead of K and V, under `LATENT`:
+  latent_pages: (L, n_pages, 1, page_size, latent_width)
+one row per token per layer, shared by every query head: the normalised
+c_kv (kv_lora_rank lanes), the roped k_pe (qk_rope_head_dim lanes), then
+zero lanes up to a multiple of 128. The plain writers take it as their K
+pool with no V pool; it has no quantized form.
 """
 from __future__ import annotations
 
@@ -44,6 +52,14 @@ import jax.numpy as jnp
 # ---------------------------------------------------------------------------
 
 KV_QMAX = {"int8": 127.0, "fp8": 448.0}
+
+LATENT = "latent_pages"
+
+
+def is_pool(seg: dict) -> bool:
+    """Whether a segment cache holds paged pools (K/V or latent), rather
+    than a recurrent segment's per-slot states."""
+    return "k_pages" in seg or LATENT in seg
 
 
 def kv_storage_dtype(kv_dtype: str):
@@ -103,7 +119,10 @@ def _scatter_rows(pages: jax.Array, layer, page_ids: jax.Array,
     a single contiguous hd row of the head-major pool, which keeps the
     pool in the kernels' layout when the write sits inside a layer loop (a
     (kv, hd) window made XLA pick another layout for the loop's carry and
-    relay the whole pool every layer)."""
+    relay the whole pool every layer). A pool of None (the V pool of a
+    latent segment) writes nothing."""
+    if pages is None:
+        return None
     heads = jnp.arange(pages.shape[2])
     return pages.at[layer, page_ids[..., None], heads,
                     off[..., None]].set(new.astype(pages.dtype), mode="drop")
@@ -133,7 +152,8 @@ def write_token(pages_k: jax.Array, pages_v: jax.Array, block_table: jax.Array,
     # cast-to-pool is a no-op at the default kv_dtype; kv_dtype="bfloat16"
     # stores a narrower non-quantized pool than the compute dtype
     return (_scatter_rows(pages_k, layer, safe_page, off, new_k[:, 0]),
-            _scatter_rows(pages_v, layer, safe_page, off, new_v[:, 0]))
+            _scatter_rows(pages_v, layer, safe_page, off,
+                          None if new_v is None else new_v[:, 0]))
 
 
 def write_prompt(pages_k: jax.Array, pages_v: jax.Array, block_row: jax.Array,
@@ -156,7 +176,8 @@ def write_prompt(pages_k: jax.Array, pages_v: jax.Array, block_row: jax.Array,
     safe_page = jnp.where(valid, page_of, n_pages)       # OOB rows dropped
     off = pos % page_size
     return (_scatter_rows(pages_k, layer, safe_page, off, new_k[0]),
-            _scatter_rows(pages_v, layer, safe_page, off, new_v[0]))
+            _scatter_rows(pages_v, layer, safe_page, off,
+                          None if new_v is None else new_v[0]))
 
 
 def write_prompt_ragged(pages_k: jax.Array, pages_v: jax.Array,
@@ -337,8 +358,13 @@ def write_pools(pools: dict, layer, what: str, *args, **kw) -> dict:
     ({"k_pages", "v_pages"[, "k_scale", "v_scale"]}) with the writer
     `what` names ("token", "prompt" or "prompt_ragged"), called with
     `*args, **kw`. A pool that carries scales is quantized, at the kv_dtype
-    its storage dtype says, and takes the requantizing writer."""
+    its storage dtype says, and takes the requantizing writer. A latent
+    pool takes the plain writer with its rows as K and no V (the caller
+    passes None for the V rows)."""
     plain, quant = _WRITERS[what]
+    if LATENT in pools:
+        rows, _ = plain(pools[LATENT], None, *args, layer=layer, **kw)
+        return {LATENT: rows}
     if "k_scale" not in pools:
         k, v = plain(pools["k_pages"], pools["v_pages"], *args, layer=layer,
                      **kw)

@@ -222,13 +222,16 @@ def _block_fwd_full(cfg: ModelConfig, kind: str, blk: dict, x, positions,
     """Returns (x, aux) for one block over a full sequence."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (ATTN, SHARED_ATTN, MOE):
-        h = attn_lib.attention_fwd(cfg, blk["attn"], norm(cfg, blk["norm1"], x),
-                                   positions, causal=True)
+        attend = attn_lib.mla_fwd if cfg.is_mla else attn_lib.attention_fwd
+        h = attend(cfg, blk["attn"], norm(cfg, blk["norm1"], x), positions,
+                   causal=True)
         x = x + h
         if enc_out is not None and "xattn" in blk:
             x = x + attn_lib.cross_attention_fwd(
                 cfg, blk["xattn"], norm(cfg, blk["norm_x"], x), enc_out)
-        if kind == MOE:
+        if kind == MOE and cfg.moe_dropless:
+            h = moe_lib.moe_serve(cfg, blk["moe"], norm(cfg, blk["norm2"], x))
+        elif kind == MOE:
             h, aux = moe_lib.moe_fwd(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
                                      mesh=mesh)
         else:
@@ -308,6 +311,8 @@ def predict_length(cfg: ModelConfig, params: dict, hidden: jax.Array) -> jax.Arr
 
 def _seg_cache(cfg: ModelConfig, kind: str, count: int, batch: int,
                max_len: int, spec: bool):
+    assert not (cfg.is_mla and kind in (ATTN, MOE)), \
+        "latent attention caches through the paged backend only"
     hd = cfg.resolved_head_dim
     adt = jnp.dtype(cfg.dtype)
     mk = (lambda sh, dt: jax.ShapeDtypeStruct(sh, dt)) if spec else (
@@ -467,17 +472,21 @@ def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
 
 
 def _block_tail(cfg: ModelConfig, kind: str, blk: dict, x, newc,
-                enc_out, mesh=None):
+                enc_out, mesh=None, stats=None):
     """Post-attention tail of an attention block, shared by dense and paged
     prefill and the paged ingest and decode steps: optional cross-attention
-    cache, then the MoE/MLP block."""
+    cache, then the MoE/MLP block (the serving expert layer, which fills
+    `stats`, when cfg.moe_dropless)."""
     if enc_out is not None and "xattn" in blk:
         xin2 = norm(cfg, blk["norm_x"], x)
         _, ck, cv = attn_lib._project_qkv(cfg, blk["xattn"], xin2,
                                           kv_x=enc_out)
         newc["cross_k"], newc["cross_v"] = ck, cv
         x = x + attn_lib.cross_attention_cached(cfg, blk["xattn"], xin2, ck, cv)
-    if kind == MOE:
+    if kind == MOE and cfg.moe_dropless:
+        h = moe_lib.moe_serve(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
+                              stats)
+    elif kind == MOE:
         h, _ = moe_lib.moe_fwd(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
                                mesh=mesh)
     else:
@@ -565,6 +574,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
     keep their O(1) per-slot dense states.
 
       k_pages/v_pages: (count, n_pages, n_kv, page_size, hd)
+      latent_pages:    (count, n_pages, 1, page_size, latent_width), in
+                       place of K and V for latent attention (cfg.is_mla)
       block_table:     (batch, max_pages_per_seq) int32, -1 = unmapped
       lengths:         (batch,)
 
@@ -582,9 +593,15 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
     adt = pc.kv_storage_dtype(cfg.resolved_kv_dtype)
     mk = (lambda sh, dt: jax.ShapeDtypeStruct(sh, dt)) if spec else (
         lambda sh, dt: jnp.zeros(sh, dt))
+    assert not (cfg.is_mla and cfg.kv_quantized), \
+        "the latent pool keeps no dequant scales: int8/fp8 latent pages " \
+        "are not supported"
     segs = []
     for kind, count in segments_of(cfg):
-        if kind in (ATTN, MOE, SHARED_ATTN):
+        if kind in (ATTN, MOE, SHARED_ATTN) and cfg.is_mla:
+            segs.append({pc.LATENT: mk(
+                (count, n_pages, 1, page_size, cfg.latent_width), adt)})
+        elif kind in (ATTN, MOE, SHARED_ATTN):
             sh = (count, n_pages, cfg.n_kv_heads, page_size, hd)
             seg = {"k_pages": mk(sh, adt), "v_pages": mk(sh, adt)}
             if cfg.kv_quantized:
@@ -603,30 +620,38 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
 
 
 def _paged_layers(cfg: ModelConfig, params: dict, cache: dict, x, attn,
-                  other=None, mesh=None):
+                  other=None, mesh=None, stats=None):
     """Run every layer of a paged-cache stack over `x`; returns (x, the new
     segment caches).
 
     Each attention segment's stacked pools ride the layer loop's CARRY, and
-    `attn(x, blk, pools, layer, kind) -> (x, pools)` reads and writes layer
-    `layer` (traced) of them in place. Scanned as xs/ys instead, the pools
-    would be sliced out of the stack and written back whole every layer,
-    and XLA would hold a second stacked pool as scratch. Recurrent
-    segments' O(1) per-slot states scan as usual through
-    `other(x, blk, state, kind) -> (x, state)`."""
+    `attn(x, blk, pools, layer, kind, stats) -> (x, pools)` reads and
+    writes layer `layer` (traced) of them in place. Scanned as xs/ys
+    instead, the pools would be sliced out of the stack and written back
+    whole every layer, and XLA would hold a second stacked pool as scratch.
+    Recurrent segments' O(1) per-slot states scan as usual through
+    `other(x, blk, state, kind) -> (x, state)`.
+
+    `stats`, a dict, gets "expert_rows": the assignments each held expert
+    computed, summed over the serving expert layers (cfg.moe_dropless)."""
     new_segs = []
+    rows = []
     for (kind, count), seg, segc in zip(segments_of(cfg), params["segments"],
                                         cache["segments"]):
         if kind == SHARED_ATTN:
-            x, segc = attn(x, params["shared"], segc, 0, kind)
+            x, segc = attn(x, params["shared"], segc, 0, kind, None)
         elif kind in (ATTN, MOE):
             def body(carry, inp, kind=kind):
                 x, pools = carry
                 blk, layer = inp
                 x = _constrain(cfg, mesh, x)
-                return attn(x, blk, pools, layer, kind), None
-            (x, segc), _ = _scan_or_unroll(cfg, body, (x, segc),
-                                           (seg, jnp.arange(count)))
+                st = {}
+                out = attn(x, blk, pools, layer, kind, st)
+                return out, st.get("expert_rows")
+            (x, segc), ys = _scan_or_unroll(cfg, body, (x, segc),
+                                            (seg, jnp.arange(count)))
+            if ys is not None:
+                rows.append(jnp.sum(ys, axis=0))
         else:
             def body(x, inp, kind=kind):
                 blk, c = inp
@@ -634,6 +659,8 @@ def _paged_layers(cfg: ModelConfig, params: dict, cache: dict, x, attn,
                 return other(x, blk, c, kind)
             x, segc = _scan_or_unroll(cfg, body, x, (seg, segc))
         new_segs.append(segc)
+    if stats is not None and rows:
+        stats["expert_rows"] = sum(rows)
     return x, new_segs
 
 
@@ -654,7 +681,10 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     block_row = cache["block_table"][slot]
     x = _constrain(cfg, mesh, x)
 
-    def attn(x, blk, pools, layer, kind):
+    assert not cfg.is_mla, \
+        "latent attention ingests through chunked prefill (prefill_chunk)"
+
+    def attn(x, blk, pools, layer, kind, stats):
         def write(pools, k, v):
             return pc.write_pools(pools, layer, "prompt", block_row, k, v,
                                   plen)
@@ -681,8 +711,8 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
 
 def prefill_chunk_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
                         cache: dict, slot, offset, chunk_len,
-                        live_pages: Optional[int] = None, mesh=None
-                        ) -> Tuple[jax.Array, dict]:
+                        live_pages: Optional[int] = None, mesh=None,
+                        stats=None) -> Tuple[jax.Array, dict]:
     """Ingest one prompt chunk (tokens: (1, C) right-padded to `chunk_len`
     valid) into the shared paged cache at batch row `slot`, whose block-table
     row must already map pages through offset + chunk_len tokens. Chunk
@@ -706,13 +736,16 @@ def prefill_chunk_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     block_row = cache["block_table"][slot]
     x = _constrain(cfg, mesh, x)
 
-    def attn(x, blk, pools, layer, kind):
-        h, pools = attn_lib.attention_prefill_chunk_paged(
-            cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools, layer,
-            block_row, off, clen, live_pages=live_pages)
-        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh)
+    chunk = attn_lib.mla_prefill_chunk_paged if cfg.is_mla \
+        else attn_lib.attention_prefill_chunk_paged
 
-    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh)
+    def attn(x, blk, pools, layer, kind, stats):
+        h, pools = chunk(cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools,
+                         layer, block_row, off, clen, live_pages=live_pages)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats)
+
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh,
+                                stats=stats)
     x = norm(cfg, params["final_norm"], x)
     idx = jnp.clip(clen - 1, 0, C - 1)
     last_h = x[:, idx]
@@ -724,8 +757,8 @@ def prefill_chunk_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
 
 def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
                          cache: dict, slots, offsets, lens,
-                         live_pages: Optional[int] = None, mesh=None
-                         ) -> Tuple[jax.Array, dict]:
+                         live_pages: Optional[int] = None, mesh=None,
+                         stats=None) -> Tuple[jax.Array, dict]:
     """Batched ragged chunk ingest: R slots' next prompt chunks in ONE call.
 
     tokens: (R, C) — row r is slot `slots[r]`'s next chunk, right-padded to
@@ -755,13 +788,17 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     block_rows = jnp.take(cache["block_table"], slots, axis=0, mode="clip")
     x = _constrain(cfg, mesh, x)
 
-    def attn(x, blk, pools, layer, kind):
-        h, pools = attn_lib.attention_prefill_ragged_paged(
-            cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools, layer,
-            block_rows, offsets, lens, live_pages=live_pages)
-        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh)
+    ragged = attn_lib.mla_prefill_ragged_paged if cfg.is_mla \
+        else attn_lib.attention_prefill_ragged_paged
 
-    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh)
+    def attn(x, blk, pools, layer, kind, stats):
+        h, pools = ragged(cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools,
+                          layer, block_rows, offsets, lens,
+                          live_pages=live_pages)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats)
+
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh,
+                                stats=stats)
     x = norm(cfg, params["final_norm"], x)
     idx = jnp.clip(lens - 1, 0, C - 1)
     last_h = jax.vmap(lambda h, i: h[i])(x, idx)               # (R, D)
@@ -784,7 +821,7 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
 def decode_step_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
                       cache: dict, mesh=None,
                       active: Optional[jax.Array] = None,
-                      live_pages: Optional[int] = None
+                      live_pages: Optional[int] = None, stats=None
                       ) -> Tuple[jax.Array, dict]:
     """tokens: (B, 1) -> (logits (B, vocab), updated paged cache).
 
@@ -803,16 +840,20 @@ def decode_step_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     table = cache["block_table"]
     x = _constrain(cfg, mesh, x)
 
-    def attn(x, blk, pools, layer, kind):
-        h, pools = attn_lib.attention_decode_paged(
-            cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools, layer,
-            table, lengths, live_pages=live_pages, active=active)
-        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh)
+    decode = attn_lib.mla_decode_paged if cfg.is_mla \
+        else attn_lib.attention_decode_paged
+
+    def attn(x, blk, pools, layer, kind, stats):
+        h, pools = decode(cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools,
+                          layer, table, lengths, live_pages=live_pages,
+                          active=active)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats)
 
     def other(x, blk, c, kind):
         return _decode_block(cfg, kind, blk, c, x, lengths, mesh)
 
-    x, new_segs = _paged_layers(cfg, params, cache, x, attn, other, mesh)
+    x, new_segs = _paged_layers(cfg, params, cache, x, attn, other, mesh,
+                                stats)
     x = norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], x)[:, 0]
     if mesh is not None:
@@ -831,6 +872,7 @@ def fork_slot_paged(cfg: ModelConfig, cache: dict, src_slot, dst_slot,
     partial tail page of every attention layer (pass tail_src_page ==
     tail_dst_page for a no-op when the prefix is page-aligned) and the O(1)
     per-slot recurrent states — then mirrors the source row's cached length.
+    A latent pool's tail page is copied the same way.
     Also serves plain COW page copies: call with src_slot == dst_slot and
     the (old, new) page pair from `PageAllocator.cow_page`.
     """
@@ -839,20 +881,10 @@ def fork_slot_paged(cfg: ModelConfig, cache: dict, src_slot, dst_slot,
     new_segs = []
     for (kind, count), segc in zip(segments_of(cfg), cache["segments"]):
         if kind in (ATTN, MOE, SHARED_ATTN):
-            seg = {
-                "k_pages": pc.copy_page(segc["k_pages"], tail_src_page,
-                                        tail_dst_page),
-                "v_pages": pc.copy_page(segc["v_pages"], tail_src_page,
-                                        tail_dst_page),
-            }
-            if "k_scale" in segc:
-                # copy_page is generic over (count, n_pages, ...) leaves, so
-                # the tail page's dequant scales ride the same op
-                seg["k_scale"] = pc.copy_page(segc["k_scale"], tail_src_page,
-                                              tail_dst_page)
-                seg["v_scale"] = pc.copy_page(segc["v_scale"], tail_src_page,
-                                              tail_dst_page)
-            new_segs.append(seg)
+            # copy_page is generic over (count, n_pages, ...) leaves: K and
+            # V pools, a latent pool, and the tail page's dequant scales
+            new_segs.append({k: pc.copy_page(a, tail_src_page, tail_dst_page)
+                             for k, a in segc.items()})
         else:
             new_segs.append(jax.tree.map(
                 lambda a: a.at[:, dst_slot].set(a[:, src_slot]), segc))
@@ -898,6 +930,8 @@ def promote_slot_paged(cfg: ModelConfig, cache: dict, upload_ids,
 def _decode_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x, lengths,
                   mesh=None):
     if kind in (ATTN, MOE, SHARED_ATTN):
+        assert not cfg.is_mla, \
+            "latent attention decodes through the paged backend only"
         xin = norm(cfg, blk["norm1"], x)
         h, nk, nv = attn_lib.attention_decode(cfg, blk["attn"], xin, c["k"],
                                               c["v"], lengths,
@@ -909,7 +943,9 @@ def _decode_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x, lengths,
             xin2 = norm(cfg, blk["norm_x"], x)
             x = x + attn_lib.cross_attention_cached(cfg, blk["xattn"], xin2,
                                                     c["cross_k"], c["cross_v"])
-        if kind == MOE:
+        if kind == MOE and cfg.moe_dropless:
+            h = moe_lib.moe_serve(cfg, blk["moe"], norm(cfg, blk["norm2"], x))
+        elif kind == MOE:
             h, _ = moe_lib.moe_fwd(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
                                    mesh=mesh)
         else:

@@ -43,6 +43,7 @@ from repro.core import trace
 from repro.models import attention as attn_lib
 from repro.models import transformer
 from repro.models.config import ModelConfig
+from repro.models import paged_cache as pc
 from repro.models.paged_cache import PageAllocator
 from repro.serving.requests import BoundedRecord
 from repro.serving.sampler import SamplerConfig, sample, token_logprob
@@ -114,9 +115,13 @@ def _prefill_chunk_fn(cfg, live_pages, params, tokens, cache, slot, offset,
 
 def _prefill_ragged_fn(cfg, live_pages, params, tokens, cache, slots, offsets,
                        lens):
-    return transformer.prefill_ragged_paged(cfg, params, tokens, cache, slots,
-                                            offsets, lens,
-                                            live_pages=live_pages)
+    """(logits, cache, expert rows: the serving expert layers' assignments
+    per held expert, summed over layers, or None without such layers)."""
+    st = {}
+    logits, cache = transformer.prefill_ragged_paged(
+        cfg, params, tokens, cache, slots, offsets, lens,
+        live_pages=live_pages, stats=st)
+    return logits, cache, st.get("expert_rows")
 
 
 def _promote_fn(cfg, cache, upload_ids, payloads, slot, ctx_len):
@@ -141,13 +146,15 @@ def _decode_dense_run_fn(cfg, sampler, params, tokens, cache, active, key):
 
 def _decode_paged_run_fn(cfg, sampler, live_pages, params, tokens, cache,
                          active, key):
+    st = {}
     logits, cache = transformer.decode_step_paged(cfg, params, tokens, cache,
                                                   active=active,
-                                                  live_pages=live_pages)
+                                                  live_pages=live_pages,
+                                                  stats=st)
     key, sub = jax.random.split(key)
     toks = sample(logits, sub, sampler)
     lps = token_logprob(logits, toks)
-    return toks, lps, key, cache
+    return toks, lps, key, cache, st.get("expert_rows")
 
 
 @functools.lru_cache(maxsize=None)
@@ -341,6 +348,10 @@ class InferenceEngine:
         # decode step dispatched last step(), read back at the next step()
         self._pending_decode: Optional[Tuple[List[int], jax.Array,
                                              jax.Array]] = None
+        # with the tracer on: (expert rows on the device, the attributes of
+        # the decode span that dispatched them), read back at the harvest
+        self._pending_rows: Optional[Tuple[jax.Array, dict]] = None
+        self._decode_attrs: Optional[dict] = None
         self._table_dirty = False
         # host-tier swap telemetry (paged backend, host_swap)
         self.swap_outs = 0
@@ -365,6 +376,9 @@ class InferenceEngine:
 
         if kv_backend == "paged":
             cfg.validate_paged(page_size, max_len)
+            assert not cfg.is_mla or cfg.prefill_chunk, \
+                "latent attention ingests through chunked prefill: set " \
+                "prefill_chunk"
             self.page_size = page_size
             self.pages_per_seq = max_len // page_size
             self.n_pages = n_pages or max_batch * self.pages_per_seq
@@ -399,7 +413,7 @@ class InferenceEngine:
             # segment's pool + scale leaves (drives kv_bytes_read)
             per_page = 0
             for seg in self.cache["segments"]:
-                if "k_pages" not in seg:
+                if not pc.is_pool(seg):
                     continue
                 for k in seg:
                     n = seg[k].shape[0] * seg[k].dtype.itemsize
@@ -507,7 +521,7 @@ class InferenceEngine:
             # repro-analysis: disable=RA103 reason=eviction swap-out snapshot; one batched readback per demotion, off the decode hot loop
             host = jax.device_get(
                 [{k: seg[k][:, ids] for k in seg}
-                 for seg in self.cache["segments"] if "k_pages" in seg])
+                 for seg in self.cache["segments"] if pc.is_pool(seg)])
             self.swap_outs += 1
             self.swap_bytes += sum(a.nbytes for seg in host
                                    for a in seg.values())
@@ -714,6 +728,14 @@ class InferenceEngine:
         self._track_peak()
         return slot
 
+    def _latent_pages(self, sp, ends: List[int]) -> None:
+        """A latent-pool engine's `latent_pages` span attribute: the pages
+        a call streams, summed over rows and layers, for rows whose reads
+        end at `ends`."""
+        if self.cfg.is_mla:
+            sp.attrs["latent_pages"] = self.cfg.n_layers * sum(
+                -(-e // self.page_size) for e in ends)
+
     def _live_pages(self, active: List[int]) -> int:
         """Static read width for this decode step: enough block-table
         columns to cover every active slot's cache plus the token being
@@ -741,7 +763,13 @@ class InferenceEngine:
     def _chunk_live(self, end: int) -> int:
         """Static covering read width through position `end`, bucketed to
         the next power of two (shared by the decode step and chunk ingest
-        so both paths honor one recompile contract)."""
+        so both paths honor one recompile contract). A latent-attention
+        engine reads the whole table: its kernels skip a row's pages past
+        its live range inside the grid, many pages a step, so a trimmed
+        width would save a few grid steps while each width is another
+        compiled program of every layer."""
+        if self.cfg.is_mla:
+            return self.pages_per_seq
         need = -(-min(end, self.max_len) // self.page_size)
         live = 1
         while live < need:
@@ -1083,9 +1111,15 @@ class InferenceEngine:
             return False
         commits, toks_d, lps_d = self._pending_decode
         self._pending_decode = None
+        rows = self._pending_rows
+        self._pending_rows = None
         with trace.span("engine.harvest", engine=self.name):
             with trace.span("engine.readback", engine=self.name):
-                toks, lps = jax.device_get((toks_d, lps_d))
+                if rows is None:
+                    toks, lps = jax.device_get((toks_d, lps_d))
+                else:
+                    toks, lps, n = jax.device_get((toks_d, lps_d, rows[0]))
+                    rows[1]["expert_rows"] = n.tolist()
             for i in commits:
                 # in-engine nothing deactivates a slot between dispatch and
                 # harvest; the guard covers direct _evict_victim calls
@@ -1126,7 +1160,8 @@ class InferenceEngine:
         logprob), cache donated, readback deferred to the next step's
         harvest. The PRNG key chains through the device so no sync is
         needed to keep `self.key`'s split stream identical to the eager
-        loop's."""
+        loop's. With the tracer on, the open `engine.decode` span
+        (`_decode_attrs`) gets the step's `expert_rows` at that harvest."""
         if self.kv_backend == "paged":
             # KV read traffic this step: mapped pages per active slot times
             # per-page pool+scale bytes (repeated-block DMAs past the live
@@ -1134,9 +1169,11 @@ class InferenceEngine:
             self.kv_bytes_read += self._page_kv_bytes * sum(
                 -(-self.slots[i].ctx_len // self.page_size)
                 for i in plan.active_ids)
-            toks, lps, self.key, self.cache = self._decode_run(
+            toks, lps, self.key, self.cache, rows = self._decode_run(
                 plan.live, self.params, jnp.asarray(plan.last), self.cache,
                 jnp.asarray(plan.mask), self.key)
+            self._pending_rows = None if self._decode_attrs is None \
+                or rows is None else (rows, self._decode_attrs)
         else:
             toks, lps, self.key, self.cache = self._decode_run(
                 self.params, jnp.asarray(plan.last), self.cache,
@@ -1189,9 +1226,15 @@ class InferenceEngine:
             self.kv_bytes_read += self._page_kv_bytes * sum(
                 -(-(off + len(chunk)) // self.page_size)
                 for _, off, chunk in rows)
-            logits, self.cache = self._prefill_ragged(
+            logits, self.cache, n = self._prefill_ragged(
                 live, self.params, jnp.asarray(toks), self.cache,
                 jnp.asarray(slots), jnp.asarray(offs), jnp.asarray(lens))
+            if sp:
+                self._latent_pages(sp, [off + len(chunk)
+                                        for _, off, chunk in rows])
+                if n is not None:
+                    # repro-analysis: disable=RA103 reason=read only while the tracer is on
+                    sp.attrs["expert_rows"] = jax.device_get(n).tolist()
         # final chunk landed: first token — the same (1, V) sample a
         # monolithic add_request takes (row slices of the batched logits
         # are bitwise the single-slot logits)
@@ -1293,6 +1336,8 @@ class InferenceEngine:
                     if sp:
                         sp.attrs["ctx"] = [self.slots[i].ctx_len
                                            for i in plan.active_ids]
+                        self._latent_pages(sp, sp.attrs["ctx"])
+                    self._decode_attrs = sp.attrs if sp else None
                     self._dispatch_decode(plan)
                 worked = True
             return worked
@@ -1322,7 +1367,7 @@ class InferenceEngine:
                             range(1, min(max_context or self.max_len,
                                          self.max_len) + 1)})
             for live in lives:
-                _, _, _, self.cache = self._decode_run(
+                _, _, _, self.cache, _ = self._decode_run(
                     live, self.params, jnp.asarray(last), self.cache,
                     jnp.asarray(mask), key0)
                 count += 1
@@ -1336,7 +1381,7 @@ class InferenceEngine:
                 sent = np.full((max(rbs),), self.max_batch, np.int32)
                 for rb in sorted(rbs):
                     for live in lives:
-                        _, self.cache = self._prefill_ragged(
+                        _, self.cache, _ = self._prefill_ragged(
                             live, self.params,
                             jnp.zeros((rb, self.prefill_chunk), jnp.int32),
                             self.cache, jnp.asarray(sent[:rb]),
@@ -1383,7 +1428,7 @@ class InferenceEngine:
                                       + seg[k].shape[2:], seg[k].dtype)
                          for k in seg}
                         for seg in self.cache["segments"]
-                        if "k_pages" in seg]
+                        if pc.is_pool(seg)]
                     self.cache = self._promote(
                         self.cache,
                         jnp.full((U,), self.n_pages, jnp.int32), payloads,

@@ -160,7 +160,8 @@ def test_param_counts_match_assignment():
     """Full-size configs should land near their nameplate parameter counts."""
     expect = {"qwen3-8b": (7, 10), "mixtral-8x7b": (40, 50),
               "qwen3-moe-30b-a3b": (27, 33), "granite-3-8b": (7, 10),
-              "minitron-8b": (7, 11), "qwen2-1.5b": (1.2, 2.2)}
+              "minitron-8b": (7, 11), "qwen2-1.5b": (1.2, 2.2),
+              "deepseek-v2-lite": (15.5, 16.0)}
     for arch, (lo, hi) in expect.items():
         b = CONFIGS[arch].param_count() / 1e9
         assert lo <= b <= hi, f"{arch}: {b:.2f}B outside [{lo},{hi}]"

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import kernel as fa_kernel
+from repro.kernels.moe_grouped_matmul import kernel as gmm_kernel
 from repro.kernels.paged_decode_attention import kernel as pda_kernel
 from repro.kernels.paged_prefill_attention import kernel as ppa_kernel
 
@@ -107,6 +108,44 @@ def test_paged_prefill_attention_ragged_compiles_for_v5e(spec, model, pool):
                 *a, interpret=False),
             q, pages, pages, scales, scales, rows, offs, offs, layer)
     assert "tpu_custom_call" in text
+
+
+# deepseek-v2-lite: 16 query heads on one latent row of 512 + 64 lanes
+# padded to 640; 8 held experts of width 1408 at d_model 2048
+MLA_H, MLA_W, MLA_RANK = 16, 640, 512
+
+
+def test_latent_paged_kernels_compile_for_v5e(spec):
+    pages = spec((LAYERS, N_PAGES, 1, PAGE, MLA_W), jnp.bfloat16)
+    lens = spec((BATCH,), jnp.int32)
+    layer = spec((), jnp.int32)
+    text = _compiled_text(
+        lambda *a: pda_kernel.mla_paged_decode_pallas(
+            *a, rank=MLA_RANK, scale=0.1147, interpret=False),
+        spec((BATCH, 1, MLA_H, MLA_W), jnp.bfloat16), pages,
+        spec((BATCH, TABLE_W), jnp.int32), lens, layer)
+    assert "tpu_custom_call" in text and "mla_paged_decode" in text
+    offs = spec((ROWS,), jnp.int32)
+    text = _compiled_text(
+        lambda *a: ppa_kernel.mla_paged_prefill_pallas(
+            *a, rank=MLA_RANK, scale=0.1147, interpret=False),
+        spec((ROWS, CHUNK, MLA_H, MLA_W), jnp.bfloat16), pages,
+        spec((ROWS, TABLE_W), jnp.int32), offs, offs, layer)
+    assert "tpu_custom_call" in text and "mla_paged_prefill" in text
+
+
+@pytest.mark.parametrize("rows", [288, 6144])
+def test_grouped_expert_matmul_compiles_for_v5e(spec, rows):
+    """A decode step's 48 x 6 and a 1024-token ingest's assignment rows,
+    through the gate and down projections of 8 held experts."""
+    sizes = spec((8,), jnp.int32)
+    for k, n in ((2048, 1408), (1408, 2048)):
+        text = _compiled_text(
+            lambda *a: gmm_kernel.moe_grouped_matmul_pallas(
+                *a, interpret=False),
+            spec((rows, k), jnp.bfloat16), spec((8, k, n), jnp.bfloat16),
+            sizes)
+        assert "tpu_custom_call" in text and "moe_grouped_matmul" in text
 
 
 @pytest.mark.parametrize("model", sorted(HEADS))

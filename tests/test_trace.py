@@ -187,6 +187,48 @@ def test_engine_step_phases_and_kernel_rows(params):
     assert "busy_s" not in vars(eng)
 
 
+MLA = ModelConfig(
+    name="tiny-mla", family="moe", n_layers=3, d_model=64, n_heads=4,
+    n_kv_heads=4, d_ff=96, moe_d_ff=32, vocab_size=256, max_seq_len=4096,
+    n_experts=16, experts_per_token=3, moe_dropless=True,
+    n_shared_experts=1, first_k_dense=1, norm_topk_prob=False, ep_size=4,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=16,
+    rope_theta=10000.0, dtype="float32", remat=False, prefill_chunk=16)
+
+
+def test_latent_pages_and_expert_rows_on_the_engine_spans():
+    """A latent-attention expert engine's ingest and decode spans say how
+    many latent pages the call streamed (over rows and layers) and how many
+    assignments each held expert computed (over the expert layers): 3 of
+    16 experts a token, 4 held here, 2 expert layers."""
+    params = transformer.init_params(MLA, jax.random.PRNGKey(1))
+    eng = InferenceEngine(MLA, params, max_batch=2, max_len=64,
+                          kv_backend="paged", page_size=8, eos_id=-1,
+                          name="mla")
+    trace.enable()
+    eng.generate([[5, 6, 7] * 6, [8, 9, 10]], max_new=4)
+    trace.disable()
+    recs = trace.records()
+    ing = _by_name(recs, "engine.ingest")
+    dec = _by_name(recs, "engine.decode")
+    assert ing and dec
+    for r in ing:
+        ends = [o + n for o, n in r.attrs["rows"]]
+        assert r.attrs["latent_pages"] == 3 * sum(-(-e // 8) for e in ends)
+    for r in dec:
+        assert r.attrs["latent_pages"] == 3 * sum(-(-c // 8)
+                                                  for c in r.attrs["ctx"])
+    for r in ing + dec:
+        rows = r.attrs["expert_rows"]
+        assert len(rows) == 4 and sum(rows) <= 2 * 3 * 2 * 16
+    # two decode rows a step, but the step's 2 slots are all routed: at
+    # most 2 tokens x 3 experts x 2 layers, all held ones counted
+    assert sum(sum(r.attrs["expert_rows"]) for r in dec) > 0
+    # with the tracer off, nothing is read back for it
+    eng.generate([[1, 2, 3]], max_new=2)
+    assert eng._pending_rows is None
+
+
 def test_frontend_queue_wait_and_fork_arrival(params):
     fe = EngineFrontend(_engine(params, max_batch=3))
     trace.enable()
