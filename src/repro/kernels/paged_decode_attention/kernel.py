@@ -10,34 +10,48 @@ hot spot (KV reads are >50% of decode latency); this kernel removes the
 gather entirely:
 
   * `(layer, block_table, lengths)` are scalar-prefetched, and the block
-    table IS the K/V `index_map`: grid step (b, h, p) streams the
-    `(page_size, hd)` tile of layer `layer`, physical page
-    `block_table[b, p]`, KV head h HBM->VMEM directly from the stacked
-    pool. No contiguous copy, and no per-layer slice of the pool, ever
-    exists: the model's layer loop hands every layer the whole stacked
-    pool and its own (traced) layer index.
-  * steps past a slot's live pages re-map to its last live page — Pallas
-    elides the DMA for a revisited block — and `pl.when` skips their
-    compute, so per-step read volume is O(sum ceil(len/page)) pages, not
-    O(B * max_pages_per_seq).
-  * unmapped (-1) pages and in-page positions past `length` are pruned /
-    masked; COW-shared pages (fan-out forks) are just page ids that happen
-    to repeat across rows — each reader streams the page once, instead of
-    the gather re-materializing it N times.
-  * all `q_per_kv` query heads of one KV head ride each streamed page tile
-    (same GQA arithmetic-intensity reuse as the dense decode kernel), with
-    a running-softmax scratch accumulated across pages (flash-decode).
+    table IS the K/V `index_map`: each grid step (b, h, p) attends the
+    `T = DECODE_PAGES` pages `p*T ...` of row b, KV head h, streamed
+    HBM->VMEM directly from the stacked pool. The pool is passed G =
+    min(P, T) times; operand j's index map picks the `(page_size, hd)`
+    tile of layer `layer`, physical page `block_table[b, p*T + j]`. No
+    contiguous copy, and no per-layer slice of the pool, ever exists: the
+    model's layer loop hands every layer the whole stacked pool and its
+    own (traced) layer index.
+  * a grid step, and each block operand in it, has a fixed cost far above
+    what one 32-row page tile takes to stream, so a step carries many
+    pages, and a table narrower than T streams only its P pages (the
+    tile's others are zeros). One tile of T pages at every width keeps
+    the fold of the running softmax, and so the read, bit-identical at
+    any covering width. A table width that T does not divide ends in a
+    part step; a row's read ends at the table width, as the oracle's does.
+  * a page past the row's live range or unmapped (-1) maps to one fixed
+    block of the layer (page 0, head 0), the same for every row and step:
+    Pallas elides the DMA for a block equal to the previous step's, so
+    pruned pages cost no bandwidth, and a step with no live token skips
+    its compute (`pl.when`). Per-step read volume is O(sum ceil(len/page))
+    pages, not O(B * max_pages_per_seq).
+  * the page tiles of a step are masked — rows past `length`, pages that
+    are unmapped — and folded into a running-softmax scratch
+    (flash-decode) as one `(T * page_size, hd)` tile; COW-shared pages (fan-out forks) are
+    just page ids that repeat across rows — each reader streams the page
+    once, instead of the gather re-materializing it N times.
+  * all `q_per_kv` query heads of one KV head ride each step's tile (same
+    GQA arithmetic-intensity reuse as the dense decode kernel).
   * a quantized (int8/fp8) pool streams at its storage width and each page
-    tile is dequantized in VMEM with its per-(page, kv-head) scale, which
-    rides the same clamped index map as its page.
+    tile is dequantized in VMEM with its per-(page, kv-head) scale, whose
+    block rides the same index map as its page.
 
-Grid: (B, Hkv, P) with P = block-table width (callers should pre-trim it
-to the live width). Rows with length 0 return zeros.
+Grid: (B, Hkv, ceil(P / T)) with P = block-table width (callers should
+pre-trim it to the live width). Rows with length 0 return zeros.
 
-The pool is stored head-major, so one grid step's K/V tile is the
-contiguous `(page_size, hd)` block `(1, 1, 1, page_size, hd)`: the TPU
-compiler accepts it when hd % 128 == 0 and page_size % 8 == 0 (the engine
-checks both before it picks this path).
+The pool is stored head-major, so each K/V block is the contiguous
+`(page_size, hd)` tile `(1, 1, 1, page_size, hd)`: the TPU compiler accepts
+it when hd % 128 == 0 and page_size % 8 == 0 (the engine checks both before
+it picks this path).
+
+The latent mode (multi-head latent attention) below shares the multi-page
+tile routine (`page_tiles`) and the running softmax.
 """
 from __future__ import annotations
 
@@ -115,42 +129,96 @@ def softmax_result(l_scr, acc_scr):
     return acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
 
 
+# Pages a decode grid step attends (the latent mode's: at most): 2 x 16
+# bf16 tiles of (32, 128) are 256 KB a step, double-buffered 512 KB, well
+# inside scoped VMEM.
+DECODE_PAGES = 16
+
+
+def pages_per_step(P: int, most: int) -> int:
+    """Pages streamed per grid step: the largest power of two up to `most`
+    that divides the block-table width P."""
+    g = 1
+    while g * 2 <= most and P % (g * 2) == 0:
+        g *= 2
+    return g
+
+
+def decode_pages(P: int, latent: bool) -> int:
+    """Pages a decode grid step streams at block-table width P: the GQA
+    mode's up to DECODE_PAGES (the pages of its tile that P holds), the
+    latent mode's largest power of two up to it that divides P."""
+    return pages_per_step(P, DECODE_PAGES) if latent \
+        else min(P, DECODE_PAGES)
+
+
+def page_tiles(pages, start, total, ps, *operands, h=None, tile=None):
+    """The page tiles of one grid step, each operand's as one (T*ps, W) f32
+    tile, with rows past `total` or on an unmapped page zeroed, and the
+    (1, T*ps) key mask that admits the rest.
+
+    pages: the G page ids the step streams; tile: T, the pages the step
+    attends (default G), the ones past G all zeros, which `total` must
+    mask; operands: `(refs, s_refs)` pairs, the G page blocks
+    (1, 1, 1, ps, W) of one pool (K, V or the latent rows) and their G
+    scale blocks, or None for an unquantized pool; h: the KV head a scale
+    is picked for."""
+    G = len(pages)
+    T = tile or G
+    tiles = [[] for _ in operands]
+    for j in range(G):
+        row = start + j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
+        valid = (row < total) & (pages[j] >= 0)
+        for out, (refs, s_refs) in zip(tiles, operands):
+            out.append(page_tile(refs[j], valid,
+                                 None if s_refs is None else s_refs[j], h))
+    for out in tiles:
+        out += [jnp.zeros_like(out[0])] * (T - G)
+    kvs = [t[0] if T == 1 else jnp.concatenate(t, axis=0) for t in tiles]
+    kidx = jax.lax.broadcasted_iota(jnp.int32, (1, T * ps), 1)
+    kmask = start + kidx < total
+    for j in range(G):
+        kmask &= (kidx < j * ps) | (kidx >= (j + 1) * ps) | (pages[j] >= 0)
+    return kvs, kmask
+
+
 def _paged_dec_kernel(layer_ref,               # scalar prefetch: (1,) layer
                       tbl_ref,                 # scalar prefetch: (B, P) pages
                       len_ref,                 # scalar prefetch: (B,) lengths
-                      q_ref, k_ref, v_ref, *refs,
-                      np_: int, ps: int, scale: float, quant: bool):
+                      q_ref, *refs, G: int, T: int, P: int, ps: int,
+                      scale: float, quant: bool):
+    k_refs, v_refs = refs[:G], refs[G:2 * G]
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
+        ks_refs, vs_refs = refs[2 * G:3 * G], refs[3 * G:4 * G]
+        refs = refs[4 * G:]
     else:
-        (o_ref, m_scr, l_scr, acc_scr), ks_ref, vs_ref = refs, None, None
+        ks_refs = vs_refs = None
+        refs = refs[2 * G:]
+    o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     h = pl.program_id(1)
-    pi = pl.program_id(2)
+    pj = pl.program_id(2)
 
-    @pl.when(pi == 0)
+    @pl.when(pj == 0)
     def _init():
         init_softmax(m_scr, l_scr, acc_scr)
 
-    length = len_ref[b]
-    page = tbl_ref[b, pi]
-    s_start = pi * ps
+    length = jnp.minimum(len_ref[b], P * ps)
+    start = pj * (T * ps)
 
-    # live page with tokens to attend: unmapped (-1) and past-length pages
-    # contribute nothing and are skipped (their block was not re-fetched
-    # either — see the clamped index_map in _paged_decode)
-    @pl.when((s_start < length) & (page >= 0))
+    # a step with no token to attend is skipped (its blocks were not
+    # fetched either — see the index maps in _paged_decode)
+    @pl.when(start < length)
     def _body():
-        valid = s_start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) \
-            < length                                            # (ps, 1)
-        kmask = s_start + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1) \
-            < length                                            # (1, ps)
+        pages = [tbl_ref[b, jnp.minimum(pj * T + j, P - 1)]
+                 for j in range(G)]
+        (k, v), kmask = page_tiles(pages, start, length, ps,
+                                   (k_refs, ks_refs), (v_refs, vs_refs),
+                                   h=h, tile=T)
         q = q_ref[0, 0].astype(jnp.float32)                     # (rep, hd)
-        k = page_tile(k_ref, valid, ks_ref, h)
-        v = page_tile(v_ref, valid, vs_ref, h)
         softmax_update(q, k, v, kmask, m_scr, l_scr, acc_scr, scale)
 
-    @pl.when(pi == np_ - 1)
+    @pl.when(pj == pl.num_programs(2) - 1)
     def _finish():
         o_ref[0, 0] = softmax_result(l_scr, acc_scr).astype(o_ref.dtype)
 
@@ -164,6 +232,7 @@ def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths, layer,
     B, _, Hq, hd = q.shape
     Hkv, ps = k_pages.shape[2], k_pages.shape[3]
     P = block_table.shape[1]
+    G, T = decode_pages(P, latent=False), DECODE_PAGES
     rep = Hq // Hkv
     table = block_table.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
@@ -171,35 +240,35 @@ def _paged_decode(q, k_pages, v_pages, scales, block_table, lengths, layer,
     # (B, Hkv, q_per_kv, hd): group q heads by their kv head
     qg = q[:, 0].reshape(B, Hkv, rep, hd)
 
-    def page_of(b, p, tbl_ref, len_ref):
-        # steps past the live range re-stream the last live page: Pallas
-        # skips the DMA for a block index equal to the previous step's, so
-        # pruned pages cost neither bandwidth nor compute
-        n_live = jax.lax.div(len_ref[b] + ps - 1, ps)
-        pi = jnp.minimum(p, jnp.maximum(n_live - 1, 0))
-        return jnp.maximum(tbl_ref[b, pi], 0)
+    def page_map(j, heads: bool):
+        # operand j streams page p*T + j of the row; a page past the live
+        # range (past P included) or unmapped maps to one fixed block
+        # (page 0, head 0) of the layer, which every such step shares, so
+        # its DMA is elided
+        def index(b, h, p, layer_ref, tbl_ref, len_ref):
+            pi = p * T + j
+            page = tbl_ref[b, jnp.minimum(pi, P - 1)]
+            live = (pi * ps < jnp.minimum(len_ref[b], P * ps)) \
+                & (page >= 0)
+            at = (layer_ref[0], jnp.where(live, page, 0))
+            return at + ((jnp.where(live, h, 0), 0, 0) if heads else (0, 0))
+        return index
 
-    def kv_map(b, h, p, layer_ref, tbl_ref, len_ref):
-        return (layer_ref[0], page_of(b, p, tbl_ref, len_ref), h, 0, 0)
-
-    def scale_map(b, h, p, layer_ref, tbl_ref, len_ref):
-        return (layer_ref[0], page_of(b, p, tbl_ref, len_ref), 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, rep, hd), lambda b, h, p, *_: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, 1, ps, hd), kv_map),
-        pl.BlockSpec((1, 1, 1, ps, hd), kv_map),
-    ]
-    operands = [qg, k_pages, v_pages]
+    in_specs = [pl.BlockSpec((1, 1, rep, hd),
+                             lambda b, h, p, *_: (b, h, 0, 0))]
+    in_specs += [pl.BlockSpec((1, 1, 1, ps, hd), page_map(j, True))
+                 for j in range(G)] * 2
+    operands = [qg] + [k_pages] * G + [v_pages] * G
     if scales is not None:
-        in_specs += [pl.BlockSpec((1, 1, 1, Hkv), scale_map)] * 2
-        operands += [scale_view(s) for s in scales]
-    kernel = functools.partial(_paged_dec_kernel, np_=P, ps=ps,
+        in_specs += [pl.BlockSpec((1, 1, 1, Hkv), page_map(j, False))
+                     for j in range(G)] * 2
+        operands += [scale_view(s) for s in scales for _ in range(G)]
+    kernel = functools.partial(_paged_dec_kernel, G=G, T=T, P=P, ps=ps,
                                scale=1.0 / float(hd) ** 0.5,
                                quant=scales is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv, P),
+        grid=(B, Hkv, pl.cdiv(P, T)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, rep, hd),
                                lambda b, h, p, *_: (b, h, 0, 0)),
@@ -236,8 +305,8 @@ def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
 
     k/v_pages: (L, n_pages, Hkv, page, hd) int8 / fp8; k/v_scales: (L,
     n_pages, Hkv) f32 per-(page, kv-head) dequant scales, streamed as
-    (1, 1, 1, Hkv) blocks through the same clamped block-table index map as
-    their page."""
+    (1, 1, 1, Hkv) blocks through the same block-table index map as their
+    page."""
     return _paged_decode(q, k_pages, v_pages, (k_scales, v_scales),
                          block_table, lengths, layer, interpret,
                          "paged_decode_attention_quant")
@@ -256,35 +325,10 @@ def paged_decode_attention_quant_pallas(q, k_pages, v_pages, k_scales,
 #
 # A page of 32 rows is a small DMA, so each grid step streams `G` pages:
 # the pool is passed G times, operand j's index map picking page p*G + j
-# of the row (clamped past the live range like the GQA mode's, so pruned
-# pages cost no DMA), and the G tiles are attended as one.
+# of the row (clamped to the row's last live page past its live range, so
+# pruned pages cost no DMA), and the G tiles are attended as one
+# (`page_tiles`, shared with the GQA mode).
 # ---------------------------------------------------------------------------
-
-def pages_per_step(P: int, most: int) -> int:
-    """Pages streamed per grid step: the largest power of two up to `most`
-    that divides the block-table width P."""
-    g = 1
-    while g * 2 <= most and P % (g * 2) == 0:
-        g *= 2
-    return g
-
-
-def latent_tiles(kv_refs, pages, start, total, ps):
-    """The G page tiles of one grid step as one (G*ps, W) f32 tile, with
-    rows past `total` or on an unmapped page zeroed, and the (1, G*ps) key
-    mask that admits the rest."""
-    G = len(kv_refs)
-    tiles = []
-    for j in range(G):
-        row = start + j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        tiles.append(page_tile(kv_refs[j], (row < total) & (pages[j] >= 0)))
-    kv = tiles[0] if G == 1 else jnp.concatenate(tiles, axis=0)
-    kidx = jax.lax.broadcasted_iota(jnp.int32, (1, G * ps), 1)
-    kmask = start + kidx < total
-    for j in range(G):
-        kmask &= (kidx < j * ps) | (kidx >= (j + 1) * ps) | (pages[j] >= 0)
-    return kv, kmask
-
 
 def _mla_dec_kernel(layer_ref, tbl_ref, len_ref, q_ref, *refs, G: int,
                     ps: int, rank: int, scale: float):
@@ -303,7 +347,7 @@ def _mla_dec_kernel(layer_ref, tbl_ref, len_ref, q_ref, *refs, G: int,
     @pl.when(start < length)
     def _body():
         pages = [tbl_ref[b, pj * G + j] for j in range(G)]
-        kv, kmask = latent_tiles(kv_refs, pages, start, length, ps)
+        (kv,), kmask = page_tiles(pages, start, length, ps, (kv_refs, None))
         q = q_ref[0].astype(jnp.float32)                      # (H, W)
         softmax_update(q, kv, kv[:, :rank], kmask, m_scr, l_scr, acc_scr,
                        scale)
@@ -314,8 +358,7 @@ def _mla_dec_kernel(layer_ref, tbl_ref, len_ref, q_ref, *refs, G: int,
 
 
 def mla_paged_decode_pallas(q, pages, block_table, lengths, layer=0, *,
-                            rank: int, scale: float, interpret: bool,
-                            most_pages: int = 16):
+                            rank: int, scale: float, interpret: bool):
     """Latent-mode paged flash decode. q: (B, 1, H, W) absorbed queries;
     pages: (L, n_pages, 1, page, W) the latent pool; block_table: (B, P)
     int32 (-1 = unmapped); lengths: (B,) valid tokens; layer: () the pool
@@ -325,7 +368,7 @@ def mla_paged_decode_pallas(q, pages, block_table, lengths, layer=0, *,
     B, _, H, W = q.shape
     ps = pages.shape[3]
     P = block_table.shape[1]
-    G = pages_per_step(P, most_pages)
+    G = decode_pages(P, latent=True)
     table = block_table.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
 

@@ -43,7 +43,7 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.paged_decode_attention.kernel import (
-    init_softmax, latent_tiles, layer_operand, page_tile, pages_per_step,
+    init_softmax, layer_operand, page_tile, page_tiles, pages_per_step,
     scale_view, softmax_result, softmax_update)
 
 
@@ -244,7 +244,7 @@ def _mla_pref_kernel(layer_ref, rows_ref, info_ref, q_ref, *refs, G: int,
     @pl.when(start < total)
     def _body():
         pages = [rows_ref[r, pj * G + j] for j in range(G)]
-        kv, kmask = latent_tiles(kv_refs, pages, start, total, ps)
+        (kv,), kmask = page_tiles(pages, start, total, ps, (kv_refs, None))
         q = q_ref[0, 0].astype(jnp.float32)                   # (hb*C, W)
         n = q.shape[0]
         # query row j is chunk position j mod C

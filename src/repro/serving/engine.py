@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import trace
+from repro.kernels.paged_decode_attention import kernel as pda_kernel
 from repro.models import attention as attn_lib
 from repro.models import transformer
 from repro.models.config import ModelConfig
@@ -736,6 +737,14 @@ class InferenceEngine:
             sp.attrs["latent_pages"] = self.cfg.n_layers * sum(
                 -(-e // self.page_size) for e in ends)
 
+    def _pages_per_step(self, sp, live: int) -> None:
+        """A decode's `pages_per_step` span attribute where it reads
+        through the paged kernels: the pages one grid step of its read
+        streams at the step's static live width."""
+        if self.read_path == "pallas" and self.kv_backend == "paged":
+            sp.attrs["pages_per_step"] = pda_kernel.decode_pages(
+                live, self.cfg.is_mla)
+
     def _live_pages(self, active: List[int]) -> int:
         """Static read width for this decode step: enough block-table
         columns to cover every active slot's cache plus the token being
@@ -1337,6 +1346,7 @@ class InferenceEngine:
                         sp.attrs["ctx"] = [self.slots[i].ctx_len
                                            for i in plan.active_ids]
                         self._latent_pages(sp, sp.attrs["ctx"])
+                        self._pages_per_step(sp, plan.live)
                     self._decode_attrs = sp.attrs if sp else None
                     self._dispatch_decode(plan)
                 worked = True

@@ -168,19 +168,21 @@ def _quant_pool(key, n_pages, page, kv, hd, kv_dtype, n_rows, lens,
     return kf, vf, kq, vq, ks, vs, table
 
 
+@pytest.mark.parametrize("P", [4, 32])
 @pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
-def test_paged_decode_quant_kernel_matches_dequant_oracle(kv_dtype, layer):
+def test_paged_decode_quant_kernel_matches_dequant_oracle(kv_dtype, layer, P):
     """Rows: length 0 (unmapped, -1 everywhere), mid-page, full, and a COW
     fork reading the full row's first two pages; at layer 0 and the middle
-    layer of a three-layer stack."""
-    page, P, kv, Hq, hd = 8, 4, 2, 8, 32
+    layer of a three-layer stack. A grid step streams 16 pages: at 4 pages
+    a row the scales of a part step, at 32 a full row over two steps."""
+    page, kv, Hq, hd = 8, 2, 8, 32
     lens = [0, 13, P * page]
     key = jax.random.PRNGKey(2)
     kf, vf, kq, vq, ks, vs, table = _quant_pool(
         key, 3 * P + 2, page, kv, hd, kv_dtype, 3, lens, layer=layer)
-    table = jnp.concatenate(
-        [table, jnp.asarray([[table[2, 0], table[2, 1], -1, -1]])])
+    table = jnp.concatenate([table, jnp.full((1, P), -1, jnp.int32)
+                             .at[0, :2].set(table[2, :2])])
     lens = jnp.asarray(lens + [2 * page], jnp.int32)
     q = jax.random.normal(jax.random.PRNGKey(3), (4, 1, Hq, hd))
     out = pda_ops.paged_decode_attention_quant(q, kq, vq, ks, vs, table,

@@ -54,30 +54,48 @@ def _chained_table(lens, page, P, start=0):
     (2, 4, 4, 64, 4),
     # the wide-head case adds compile wall time, not coverage, on CPU
     pytest.param(2, 16, 4, 128, 3, marks=pytest.mark.slow),
+    # widths a grid step's 16 pages fill, half fill, and cover twice
+    (3, 8, 2, 32, 8),
+    (2, 8, 2, 32, 16),
+    (2, 4, 2, 32, 32),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("layer", [0, 1])
 def test_paged_decode_parity(page, B, Hq, Hkv, hd, P, dtype, layer):
     """Rows: a length-0 slot (unmapped, -1 everywhere), random lengths, a
-    mid-page partial length, and a COW fork whose half-filled first page
-    is the previous row's first page; read at layer 0 and at the middle
-    layer of a three-layer stack."""
+    mid-page partial length, a COW fork whose half-filled first page is
+    the previous row's first page, and a row with an unmapped (-1) page
+    inside its live range, which reads as that row without the page; read
+    at layer 0 and at the middle layer of a three-layer stack. A grid step
+    streams 16 pages, so rows end inside a step and the fork's and the
+    hole's pages share one."""
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jnp.concatenate([
         jax.random.normal(ks[0], (B, 1, Hq, hd), dtype),
         jax.random.normal(jax.random.fold_in(ks[0], 1), (1, 1, Hq, hd),
+                          dtype),
+        jax.random.normal(jax.random.fold_in(ks[0], 2), (1, 1, Hq, hd),
                           dtype)])
-    kp, vp = _pools(ks[1], B * P + 2, page, Hkv, hd, dtype, layer)
+    n_pages = B * P + 2
+    kp, vp = _pools(ks[1], n_pages, page, Hkv, hd, dtype, layer)
     # ragged: always include a length-0 slot and a mid-page partial length
     lens = np.array(jax.random.randint(ks[2], (B,), 1, P * page + 1))
     lens[0] = 0
     lens[-1] = page + page // 2 if P > 1 else page // 2
     table = _chained_table(lens, page, P)
-    table = jnp.concatenate([table, jnp.full((1, P), -1, jnp.int32)
-                             .at[0, 0].set(table[-1, 0])])
-    lens = jnp.asarray(list(lens) + [page // 2], jnp.int32)
-    out = pda_ops.paged_decode_attention(q, kp, vp, table, lens, layer=layer)
-    ref = pda_ref.paged_decode_attention_ref(q, kp, vp, table, lens, layer)
+    fork = jnp.full((1, P), -1, jnp.int32).at[0, 0].set(table[-1, 0])
+    # the hole row maps the two pages no chain uses around an unmapped one
+    hole = jnp.full((1, P), -1, jnp.int32).at[0, :3].set(
+        jnp.asarray([n_pages - 2, -1, n_pages - 1]))
+    packed = jnp.full((1, P), -1, jnp.int32).at[0, :2].set(
+        jnp.asarray([n_pages - 2, n_pages - 1]))
+    lens = list(lens) + [page // 2]
+    out = pda_ops.paged_decode_attention(
+        q, kp, vp, jnp.concatenate([table, fork, hole]),
+        jnp.asarray(lens + [2 * page + page // 2], jnp.int32), layer=layer)
+    ref = pda_ref.paged_decode_attention_ref(
+        q, kp, vp, jnp.concatenate([table, fork, packed]),
+        jnp.asarray(lens + [page + page // 2], jnp.int32), layer)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
     assert not np.any(np.isnan(np.asarray(out, np.float32)))
@@ -107,6 +125,21 @@ def test_paged_decode_unmapped_tail_pages():
                                           jnp.asarray(vp2), table, lens,
                                           layer=1)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
+
+
+def test_paged_decode_reads_no_further_than_the_table():
+    """A table narrower than a row's length reads the row's first P pages
+    alone, as the oracle does, also where P ends inside a grid step."""
+    B, Hq, Hkv, hd, page, P = 2, 4, 2, 32, 8, 6
+    ks = jax.random.split(jax.random.PRNGKey(6), 2)
+    q = jax.random.normal(ks[0], (B, 1, Hq, hd))
+    kp, vp = _pools(ks[1], 2 * B * P, page, Hkv, hd, jnp.float32)
+    lens = jnp.array([3 * page, 2 * P * page], jnp.int32)
+    table = _chained_table(np.asarray(lens), page, 2 * P)[:, :P]
+    out = pda_ops.paged_decode_attention(q, kp, vp, table, lens)
+    ref = pda_ref.paged_decode_attention_ref(q, kp, vp, table, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_paged_decode_cow_forked_table():

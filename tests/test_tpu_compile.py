@@ -15,6 +15,7 @@ The topology is described inside a module fixture (never at import: one
 process at a time may load the TPU library), which skips where it cannot be
 described.
 """
+import functools
 import os
 import re
 
@@ -65,26 +66,46 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _pallas_grids(fn, *args):
+    """The grid of each Pallas kernel in fn's program."""
+    return [e.params["grid_mapping"].grid
+            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+# (Hq, Hkv, slots, table width, layers, pool pages) of each decode read;
+# the fan-out cell's edge engine reads 64 slots of a 28-layer pool of
+# 64 x 2048 tokens, at a live width of 32 pages
+DECODE_READS = {m: (*HEADS[m], BATCH, TABLE_W, LAYERS, N_PAGES)
+                for m in HEADS}
+DECODE_READS["qwen2-1.5b-fanout"] = (12, 2, 64, 32, 28, 4096)
+
+
 @pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
-@pytest.mark.parametrize("model", sorted(HEADS))
+@pytest.mark.parametrize("model", sorted(DECODE_READS))
 def test_paged_decode_attention_compiles_for_v5e(spec, model, pool):
-    hq, hkv = HEADS[model]
-    q = spec((BATCH, 1, hq, HD), jnp.bfloat16)
-    pages = spec((LAYERS, N_PAGES, hkv, PAGE, HD), POOL_DTYPES[pool])
-    table = spec((BATCH, TABLE_W), jnp.int32)
-    lens = spec((BATCH,), jnp.int32)
+    """The GQA decode kernel lowers for a v5e, streaming G = DECODE_PAGES
+    pages a grid step at these widths: the page axis of its grid is the
+    table width over G."""
+    hq, hkv, batch, width, layers, n_pages = DECODE_READS[model]
+    q = spec((batch, 1, hq, HD), jnp.bfloat16)
+    pages = spec((layers, n_pages, hkv, PAGE, HD), POOL_DTYPES[pool])
+    table = spec((batch, width), jnp.int32)
+    lens = spec((batch,), jnp.int32)
     layer = spec((), jnp.int32)
     if pool == "bf16":
-        text = _compiled_text(
-            lambda *a: pda_kernel.paged_decode_attention_pallas(
-                *a, interpret=False), q, pages, pages, table, lens, layer)
+        fn = functools.partial(pda_kernel.paged_decode_attention_pallas,
+                               interpret=False)
+        args = (q, pages, pages, table, lens, layer)
     else:
-        scales = spec((LAYERS, N_PAGES, hkv), jnp.float32)
-        text = _compiled_text(
-            lambda *a: pda_kernel.paged_decode_attention_quant_pallas(
-                *a, interpret=False),
-            q, pages, pages, scales, scales, table, lens, layer)
-    assert "tpu_custom_call" in text
+        scales = spec((layers, n_pages, hkv), jnp.float32)
+        fn = functools.partial(
+            pda_kernel.paged_decode_attention_quant_pallas, interpret=False)
+        args = (q, pages, pages, scales, scales, table, lens, layer)
+    G = pda_kernel.decode_pages(width, latent=False)
+    assert G == pda_kernel.DECODE_PAGES > 1 and width % G == 0
+    assert _pallas_grids(fn, *args) == [(batch, hkv, width // G)]
+    assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
 @pytest.mark.parametrize("pool", sorted(POOL_DTYPES))

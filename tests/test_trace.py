@@ -187,6 +187,32 @@ def test_engine_step_phases_and_kernel_rows(params):
     assert "busy_s" not in vars(eng)
 
 
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_decode_spans_say_how_many_pages_a_grid_step_streams(use_pallas):
+    """Through the paged kernels, each decode span carries the pages one
+    grid step of its read streams at the step's live width (a power of two
+    covering each row's context); the oracle read has no grid and records
+    none."""
+    from repro.kernels.paged_decode_attention import kernel as pda_kernel
+    cfg = TINY.with_(use_pallas=use_pallas)
+    eng = InferenceEngine(cfg, transformer.init_params(
+        cfg, jax.random.PRNGKey(0)), max_batch=2, max_len=64,
+        kv_backend="paged", page_size=8, eos_id=-1)
+    assert eng.read_path == ("pallas" if use_pallas else "oracle")
+    trace.enable()
+    eng.generate([list(range(5, 20))], max_new=3)
+    trace.disable()
+    dec = _by_name(trace.records(), "engine.decode")
+    assert dec
+    widths = [1 << (-(-max(r.attrs["ctx"]) // 8) - 1).bit_length()
+              for r in dec]
+    assert widths[0] == 2 and widths[-1] == 4
+    want = [pda_kernel.decode_pages(P, latent=False) for P in widths] \
+        if use_pallas else []
+    assert [r.attrs["pages_per_step"] for r in dec
+            if "pages_per_step" in r.attrs] == want
+
+
 MLA = ModelConfig(
     name="tiny-mla", family="moe", n_layers=3, d_model=64, n_heads=4,
     n_kv_heads=4, d_ff=96, moe_d_ff=32, vocab_size=256, max_seq_len=4096,
