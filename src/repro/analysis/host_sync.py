@@ -27,7 +27,7 @@ _NP_TRANSFER = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",
                 "onp.asarray", "onp.array"}
 # attribute-call suffixes on `self.` / locals that return device arrays
 _DEVICE_METHOD_PREFIXES = ("_decode_run", "_prefill", "_score", "_fork",
-                           "_insert", "_feed_chunk", "_ingest_chunk")
+                           "_insert", "_feed_chunk")
 _DEVICE_FN_NAMES = {"sample", "token_logprob"}
 
 

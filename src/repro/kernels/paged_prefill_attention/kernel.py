@@ -1,8 +1,8 @@
 """Pallas TPU paged chunked-prefill kernel: prompt chunks vs a paged KV
 pool.
 
-The serving engine's chunked prefill (models/transformer.prefill_ragged_paged
-and prefill_chunk_paged) ingests prompts in fixed C-token chunks; each
+The serving engine's chunked prefill (models/transformer.prefill_ragged_paged)
+ingests prompts in fixed C-token chunks, one row per ingesting slot; each
 chunk's queries attend causally within the chunk AND against every page its
 slot already wrote — a ragged cross-chunk read the jnp oracle serves by
 gathering the slot's whole block row into a contiguous buffer per layer per
@@ -29,9 +29,9 @@ kernel (and sharing its page-tile and running-softmax helpers):
   * a quantized (int8/fp8) pool is dequantized in VMEM per page tile with
     its streamed per-(page, kv-head) scale, as in the decode kernel.
 
-The single-slot entry points are the ragged kernel with one row. Query rows
-past `chunk_len` are computed against whatever the mask admits and must be
-discarded by the caller.
+A single slot's chunk is a one-row batch. Query rows past `chunk_len` are
+computed against whatever the mask admits and must be discarded by the
+caller.
 """
 from __future__ import annotations
 
@@ -99,7 +99,7 @@ def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
                    layer, interpret: bool, name: str):
     """Shared ragged wrapper; `scales` is None or (k_scales, v_scales).
     `name` names the kernel in compiled programs and profiler traces (the
-    public wrapper's name: `paged_prefill_attention[_ragged][_quant]`)."""
+    public wrapper's name: `paged_prefill_attention_ragged[_quant]`)."""
     R, C, Hq, hd = q.shape
     Hkv, ps = k_pages.shape[2], k_pages.shape[3]
     P = block_rows.shape[1]
@@ -161,29 +161,11 @@ def _paged_prefill(q, k_pages, v_pages, scales, block_rows, offsets, lens,
     return jnp.moveaxis(out.reshape(R, Hq, C, hd), 1, 2)
 
 
-def _one_row(block_row, offset, chunk_len):
-    """Single-slot arguments as a one-row ragged batch."""
-    return (block_row[None],
-            jnp.asarray(offset, jnp.int32).reshape(1),
-            jnp.asarray(chunk_len, jnp.int32).reshape(1))
-
-
-def paged_prefill_attention_pallas(q, k_pages, v_pages, block_row, offset,
-                                   chunk_len, layer=0, *, interpret: bool):
-    """q: (1, C, Hq, hd) one slot's chunk queries; k/v_pages: (L, n_pages,
-    Hkv, page, hd) with the chunk already written to layer `layer`;
-    block_row: (P,) int32 page ids (-1 = unmapped); offset/chunk_len: ()
-    int32. -> (1, C, Hq, hd); rows past chunk_len are unspecified."""
-    return _paged_prefill(q, k_pages, v_pages, None,
-                          *_one_row(block_row, offset, chunk_len), layer,
-                          interpret, "paged_prefill_attention")
-
-
 def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
                                           offsets, lens, layer=0, *,
                                           interpret: bool):
-    """Multi-slot ragged chunk attention: the batched-ingest extension of
-    `paged_prefill_attention_pallas`.
+    """Multi-slot ragged chunk attention, the engine's one chunk-ingest
+    read (a single slot's chunk is a one-row batch).
 
     q: (R, C, Hq, hd) — row r is one ingesting slot's chunk queries (chunk
     K/V already written); k/v_pages: (L, n_pages, Hkv, page, hd), read at
@@ -196,18 +178,6 @@ def paged_prefill_attention_ragged_pallas(q, k_pages, v_pages, block_rows,
     return _paged_prefill(q, k_pages, v_pages, None, block_rows, offsets,
                           lens, layer, interpret,
                           "paged_prefill_attention_ragged")
-
-
-def paged_prefill_attention_quant_pallas(q, k_pages, v_pages, k_scales,
-                                         v_scales, block_row, offset,
-                                         chunk_len, layer=0, *,
-                                         interpret: bool):
-    """`paged_prefill_attention_pallas` over a quantized pool (k/v_scales:
-    (L, n_pages, Hkv) f32, streamed as (1, 1, 1, Hkv) blocks through the
-    same clamped block-row index map as their page)."""
-    return _paged_prefill(q, k_pages, v_pages, (k_scales, v_scales),
-                          *_one_row(block_row, offset, chunk_len), layer,
-                          interpret, "paged_prefill_attention_quant")
 
 
 def paged_prefill_attention_ragged_quant_pallas(q, k_pages, v_pages, k_scales,

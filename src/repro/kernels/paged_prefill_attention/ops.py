@@ -11,31 +11,13 @@ from repro.kernels.runtime import resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_prefill_attention(q, k_pages, v_pages, block_row, offset, chunk_len,
-                            layer=0, interpret: Optional[bool] = None):
-    """Chunked-prefill GQA attention over a paged KV pool, streamed through
-    the block row (no gather).
-
-    q: (1, C, Hq, hd) one slot's chunk queries (chunk K/V already written
-    to the pages); k/v_pages: (L, n_pages, Hkv, page_size, hd), the stacked
-    head-major pool, read at layer `layer` (traced); block_row:
-    (P,) int32 page ids (-1 = unmapped); offset: () tokens already cached
-    before the chunk; chunk_len: () valid chunk tokens. Pre-trim
-    `block_row` to the live width (ceil((offset + chunk_len) / page_size)
-    columns, bucketed) so the grid does not walk columns the slot's read
-    never needs. Rows past chunk_len are unspecified — discard them.
-    """
-    return _kernel.paged_prefill_attention_pallas(
-        q, k_pages, v_pages, block_row, offset, chunk_len, layer,
-        interpret=resolve_interpret(interpret))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefill_attention_ragged(q, k_pages, v_pages, block_rows, offsets,
                                    lens, layer=0,
                                    interpret: Optional[bool] = None):
-    """Batched ragged chunked-prefill GQA attention: R slots' chunks against
-    their own page chains in one call (the engine's batched-ingest op).
+    """Batched ragged chunked-prefill GQA attention over a paged KV pool,
+    streamed through the block rows (no gather): R slots' chunks against
+    their own page chains in one call (the engine's chunk-ingest op; one
+    slot's chunk is a one-row batch).
 
     q: (R, C, Hq, hd) — row r is slot r's next chunk queries (each row's
     chunk K/V already written to the pages); k/v_pages: (L, n_pages, Hkv,
@@ -51,25 +33,15 @@ def paged_prefill_attention_ragged(q, k_pages, v_pages, block_rows, offsets,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_prefill_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
-                                  block_row, offset, chunk_len, layer=0,
-                                  interpret: Optional[bool] = None):
-    """`paged_prefill_attention` over an int8/fp8 pool: pages stream at the
-    storage width and are dequantized in-VMEM with their per-(page, kv-head)
-    scales (k/v_scales: (L, n_pages, Hkv) f32). Numerics follow the
-    quantized tolerance contract in docs/serving.md, not the bit-exact one."""
-    return _kernel.paged_prefill_attention_quant_pallas(
-        q, k_pages, v_pages, k_scales, v_scales, block_row, offset, chunk_len,
-        layer, interpret=resolve_interpret(interpret))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefill_attention_ragged_quant(q, k_pages, v_pages, k_scales,
                                          v_scales, block_rows, offsets, lens,
                                          layer=0,
                                          interpret: Optional[bool] = None):
-    """`paged_prefill_attention_ragged` over an int8/fp8 pool (see
-    `paged_prefill_attention_quant`)."""
+    """`paged_prefill_attention_ragged` over an int8/fp8 pool: pages stream
+    at the storage width and are dequantized in-VMEM with their
+    per-(page, kv-head) scales (k/v_scales: (L, n_pages, Hkv) f32).
+    Numerics follow the quantized tolerance contract in docs/serving.md,
+    not the bit-exact one."""
     return _kernel.paged_prefill_attention_ragged_quant_pallas(
         q, k_pages, v_pages, k_scales, v_scales, block_rows, offsets, lens,
         layer, interpret=resolve_interpret(interpret))

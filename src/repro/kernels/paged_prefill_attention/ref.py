@@ -41,11 +41,6 @@ def _attend_rows(q, gk, gv, offsets, lens):
     return jnp.einsum("bnqk,bknh->bqnh", probs.astype(v.dtype), v)
 
 
-def _one_row(block_row, offset, chunk_len):
-    return (block_row[None], jnp.asarray(offset, jnp.int32).reshape(1),
-            jnp.asarray(chunk_len, jnp.int32).reshape(1))
-
-
 def paged_prefill_attention_ragged_ref(q, k_pages, v_pages, block_rows,
                                        offsets, lens, layer=0):
     """Ragged multi-slot oracle: R independent chunk reads in one batch.
@@ -62,16 +57,6 @@ def paged_prefill_attention_ragged_ref(q, k_pages, v_pages, block_rows,
                         offsets, lens)
 
 
-def paged_prefill_attention_ref(q, k_pages, v_pages, block_row, offset,
-                                chunk_len, layer=0):
-    """The ragged oracle for one slot. q: (1, C, Hq, hd) chunk queries;
-    block_row: (P,) int32 page ids (-1 = unmapped); offset: () tokens
-    already cached before this chunk; chunk_len: () valid tokens in the
-    chunk. Returns (1, C, Hq, hd); rows past chunk_len are unspecified."""
-    return paged_prefill_attention_ragged_ref(
-        q, k_pages, v_pages, *_one_row(block_row, offset, chunk_len), layer)
-
-
 def paged_prefill_attention_ragged_quant_ref(q, k_pages, v_pages, k_scales,
                                              v_scales, block_rows, offsets,
                                              lens, layer=0):
@@ -81,14 +66,6 @@ def paged_prefill_attention_ragged_quant_ref(q, k_pages, v_pages, k_scales,
     gk = pc.gather_sequence_dequant(k_pages, k_scales, block_rows, layer)
     gv = pc.gather_sequence_dequant(v_pages, v_scales, block_rows, layer)
     return _attend_rows(q, gk, gv, offsets, lens).astype(q.dtype)
-
-
-def paged_prefill_attention_quant_ref(q, k_pages, v_pages, k_scales, v_scales,
-                                      block_row, offset, chunk_len, layer=0):
-    """Quantized-pool oracle for the single-slot chunk kernel."""
-    return paged_prefill_attention_ragged_quant_ref(
-        q, k_pages, v_pages, k_scales, v_scales,
-        *_one_row(block_row, offset, chunk_len), layer)
 
 
 def mla_paged_prefill_ref(q, pages, block_rows, offsets, lens, layer=0, *,

@@ -405,45 +405,6 @@ def attention_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
     return _paged_out(params, out, x.dtype), pools
 
 
-def attention_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
-                                  pools: dict, layer, block_row: jax.Array,
-                                  offset, chunk_len,
-                                  live_pages: Optional[int] = None):
-    """One prompt chunk against a paged KV pool (chunked prefill).
-
-    x: (1, C, D) — C new tokens of ONE slot, right-padded to `chunk_len`
-    valid; pools/layer: as in attention_decode_paged; block_row: (P,) the
-    slot's block-table row; offset: () tokens already written for this slot
-    (the chunk's first logical position). Writes the chunk's K/V at
-    offset..offset+chunk_len-1, then attends each chunk query causally
-    within the chunk AND against everything the slot already holds (ragged
-    cross-chunk read). Returns (out, pools).
-
-    The oracle/fallback reads through the same gather + `_grouped_sdpa`
-    formulation as the paged decode step — deliberately: the grouped einsum
-    is reduction-order stable across query counts, so a chunk of C tokens
-    produces bitwise the outputs of C single-token decode steps (fork-suffix
-    and eviction-resume replays stay bit-identical to uninterrupted decode),
-    and at C > 1 it matches the monolithic `_prefill_block` SDPA bitwise.
-    `cfg.use_pallas` routes the read through the paged-prefill Pallas kernel
-    (kernels/paged_prefill_attention), which streams only the slot's mapped
-    pages HBM->VMEM through the scalar-prefetched block row.
-    """
-    from repro.models import paged_cache as pc
-    from repro.kernels.paged_prefill_attention import ops as ppa_ops
-    B, C, _ = x.shape
-    positions = jnp.asarray(offset, jnp.int32) + jnp.arange(C)[None]
-    q, k, v = _paged_qkv(cfg, params, x, positions)
-    pools = pc.write_pools(pools, layer, "prompt", block_row, k, v,
-                           chunk_len, offset=offset)
-    row = block_row if live_pages is None else block_row[:live_pages]
-    out = _paged_read(cfg, q, pools, layer, row[None], positions,
-                      (ppa_ops.paged_prefill_attention,
-                       ppa_ops.paged_prefill_attention_quant),
-                      (row, offset, chunk_len))
-    return _paged_out(params, out, x.dtype), pools
-
-
 def attention_prefill_ragged_paged(cfg: ModelConfig, params: dict,
                                    x: jax.Array, pools: dict, layer,
                                    block_rows: jax.Array, offsets: jax.Array,
@@ -461,12 +422,18 @@ def attention_prefill_ragged_paged(cfg: ModelConfig, params: dict,
     everything that slot already holds. Returns (out, pools); row r
     positions past lens[r] are unspecified, as are padding rows (lens == 0).
 
-    Numerics contract: both read paths are row-independent — the oracle is
-    the same gather + `_grouped_sdpa` formulation as the single-slot chunk
-    path (batching adds rows, never changes a row's reduction order), and the
-    ragged Pallas kernel walks each row's pages exactly as the single-slot
-    kernel does — so batched ingest is bitwise the one-chunk-per-step
-    scheduler, which is in turn bitwise monolithic prefill.
+    Numerics contract: each row is independent of the others in the batch
+    on both read paths — batching adds rows, never changes a row's
+    reduction order, and the ragged Pallas kernel walks each row's pages
+    alone. The oracle/fallback reads through the same gather +
+    `_grouped_sdpa` formulation as the paged decode step — deliberately:
+    the grouped einsum is reduction-order stable across query counts, so a
+    chunk of C tokens produces bitwise the outputs of C single-token decode
+    steps (fork-suffix and eviction-resume replays stay bit-identical to
+    uninterrupted decode), and a chunked ingest matches monolithic prefill.
+    `cfg.use_pallas` routes the read through the paged-prefill Pallas
+    kernel (kernels/paged_prefill_attention), which streams only each
+    row's mapped pages HBM->VMEM through its scalar-prefetched block row.
     """
     from repro.models import paged_cache as pc
     from repro.kernels.paged_prefill_attention import ops as ppa_ops
@@ -673,14 +640,3 @@ def mla_prefill_ragged_paged(cfg: ModelConfig, params: dict, x: jax.Array,
         return _latent_oracle(cfg, q, pages, layer, rows_t, positions,
                               offsets + lens)
     return _mla_paged(cfg, params, x, positions, write, read)
-
-
-def mla_prefill_chunk_paged(cfg: ModelConfig, params: dict, x: jax.Array,
-                            pools: dict, layer, block_row: jax.Array,
-                            offset, chunk_len,
-                            live_pages: Optional[int] = None):
-    """One slot's chunk: the ragged latent path with one row."""
-    return mla_prefill_ragged_paged(
-        cfg, params, x, pools, layer, block_row[None],
-        jnp.asarray(offset, jnp.int32).reshape(1),
-        jnp.asarray(chunk_len, jnp.int32).reshape(1), live_pages=live_pages)

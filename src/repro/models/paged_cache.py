@@ -158,19 +158,17 @@ def write_token(pages_k: jax.Array, pages_v: jax.Array, block_table: jax.Array,
 
 def write_prompt(pages_k: jax.Array, pages_v: jax.Array, block_row: jax.Array,
                  new_k: jax.Array, new_v: jax.Array, prompt_len: jax.Array,
-                 offset=0, layer=0) -> Tuple[jax.Array, jax.Array]:
-    """Scatter a prefilled prompt (or prompt chunk) K/V into one sequence's
-    pages of layer `layer`.
+                 layer=0) -> Tuple[jax.Array, jax.Array]:
+    """Scatter a monolithically prefilled prompt's K/V into one sequence's
+    pages of layer `layer`, from logical position 0.
 
     pages_*: (L, n_pages, kv, page, hd); block_row: (P,) this sequence's
     block-table row; new_*: (1, S, kv, hd) right-padded; prompt_len: ()
-    valid count in new_*; offset: () logical position of new_*[0, 0] —
-    chunked prefill writes chunk i at offset i * chunk, spanning page
-    boundaries freely.
+    valid count in new_*.
     """
     n_pages, page_size = pages_k.shape[1], pages_k.shape[3]
     S = new_k.shape[1]
-    pos = jnp.asarray(offset, jnp.int32) + jnp.arange(S)
+    pos = jnp.arange(S)
     page_of = jnp.take(block_row, pos // page_size, mode="clip")
     valid = (jnp.arange(S) < prompt_len) & (page_of >= 0)
     safe_page = jnp.where(valid, page_of, n_pages)       # OOB rows dropped
@@ -191,7 +189,7 @@ def write_prompt_ragged(pages_k: jax.Array, pages_v: jax.Array,
     positions offsets[r]..offsets[r]+lens[r]-1 through that slot's block-table
     row. Distinct slots own distinct pages, so rows never collide and the
     scatter is order-independent — row r's writes are bitwise what a
-    `write_prompt` call for that row alone would produce.
+    one-row call for that row alone would produce.
 
     pages_*: (L, n_pages, kv, page, hd); block_rows: (R, P); new_*: (R, C,
     kv, hd); lens/offsets: (R,). Padding rows (lens == 0) write nothing.
@@ -278,25 +276,19 @@ def write_prompt_quant(pages_k: jax.Array, pages_v: jax.Array,
                        scales_k: jax.Array, scales_v: jax.Array,
                        block_row: jax.Array, new_k: jax.Array,
                        new_v: jax.Array, prompt_len: jax.Array, kv_dtype: str,
-                       offset=0, layer=0
+                       layer=0
                        ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """`write_prompt` for a quantized pool.
-
-    Touched pages are rewritten whole: tokens earlier chunks already placed
-    on the first touched page are dequantized, merged with the new chunk,
-    and requantized under the page's fresh scale."""
+    """`write_prompt` for a quantized pool: each touched page is quantized
+    whole under its fresh scale."""
     page_size = pages_k.shape[3]
     S = new_k.shape[1]
-    n_touch = S // page_size + 2          # static page-span upper bound
-    first = jnp.asarray(offset, jnp.int32) // page_size
-    logical = first + jnp.arange(n_touch)                        # (T,)
+    n_touch = -(-S // page_size)          # pages the padded prompt spans
+    logical = jnp.arange(n_touch)                                # (T,)
     page_ids = jnp.take(block_row, logical, mode="clip")
     kpos = logical[:, None] * page_size + jnp.arange(page_size)[None, :]
-    chunk_idx = kpos - jnp.asarray(offset, jnp.int32)            # (T, pg)
-    inchunk = (chunk_idx >= 0) & (chunk_idx < prompt_len)
-    valid = (kpos < jnp.asarray(offset, jnp.int32) + prompt_len) \
-        & (page_ids >= 0)[:, None]
-    cc = jnp.clip(chunk_idx, 0, S - 1)
+    inchunk = kpos < prompt_len                                  # (T, pg)
+    valid = inchunk & (page_ids >= 0)[:, None]
+    cc = jnp.clip(kpos, 0, S - 1)
 
     def one(pages, scales, new):
         newg = new[0].astype(jnp.float32)[cc]                    # (T, pg, kv, hd)

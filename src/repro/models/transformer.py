@@ -709,52 +709,6 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     return logits, new_cache
 
 
-def prefill_chunk_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
-                        cache: dict, slot, offset, chunk_len,
-                        live_pages: Optional[int] = None, mesh=None,
-                        stats=None) -> Tuple[jax.Array, dict]:
-    """Ingest one prompt chunk (tokens: (1, C) right-padded to `chunk_len`
-    valid) into the shared paged cache at batch row `slot`, whose block-table
-    row must already map pages through offset + chunk_len tokens. Chunk
-    queries attend causally within the chunk and against the slot's
-    already-written context (ragged cross-chunk read); `live_pages` (static)
-    trims the read to the covering block-table columns exactly like the
-    decode step. Returns (logits (1, V) at the last valid chunk token,
-    cache) — only the final chunk's logits seed sampling.
-
-    Chunked ingestion requires an attention-only stack: recurrent segments
-    (SSM / xLSTM) would need their scan state carried across chunks, which
-    their fwd paths do not expose — the engine falls back to monolithic
-    prefill for those families.
-    """
-    _check_paged_support(cfg)
-    _check_attention_only(cfg)
-    x = embed(cfg, params["embed"], tokens)
-    B, C, _ = x.shape
-    clen = jnp.asarray(chunk_len, jnp.int32).reshape(())
-    off = jnp.asarray(offset, jnp.int32).reshape(())
-    block_row = cache["block_table"][slot]
-    x = _constrain(cfg, mesh, x)
-
-    chunk = attn_lib.mla_prefill_chunk_paged if cfg.is_mla \
-        else attn_lib.attention_prefill_chunk_paged
-
-    def attn(x, blk, pools, layer, kind, stats):
-        h, pools = chunk(cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools,
-                         layer, block_row, off, clen, live_pages=live_pages)
-        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats)
-
-    x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh,
-                                stats=stats)
-    x = norm(cfg, params["final_norm"], x)
-    idx = jnp.clip(clen - 1, 0, C - 1)
-    last_h = x[:, idx]
-    logits = unembed(cfg, params["embed"], last_h[:, None])[:, 0]
-    new_cache = {"lengths": cache["lengths"].at[slot].set(off + clen),
-                 "block_table": cache["block_table"], "segments": new_segs}
-    return logits, new_cache
-
-
 def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
                          cache: dict, slots, offsets, lens,
                          live_pages: Optional[int] = None, mesh=None,
@@ -770,13 +724,19 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     offsets[r] + lens[r] tokens. Returns (logits (R, V) at each row's last
     valid chunk token, cache); padding rows' logits are unspecified.
 
-    This is the plan/run engine's one-device-call-per-step ingest
-    (flashinfer's BatchPrefillWithPagedKVCacheWrapper layout): the scheduler
-    plans (slot, offset, len) rows on the host, then every ingesting slot
-    advances together. Per-row numerics are bitwise the one-chunk-per-step
-    `prefill_chunk_paged` path — batching adds rows, never changes a row's
-    reduction order — which keeps chunked ingest bit-identical to monolithic
-    prefill. Same attention-only restriction as `prefill_chunk_paged`.
+    This is the plan/run engine's one chunk-ingest call (flashinfer's
+    BatchPrefillWithPagedKVCacheWrapper layout): the scheduler plans (slot,
+    offset, len) rows on the host, then every ingesting slot advances
+    together; a single slot's chunk (a parked prefix's) is a one-row batch.
+    Each row is independent of the others in the batch — batching adds
+    rows, never changes a row's reduction order — and a chunked ingest
+    matches monolithic prefill (identical greedy tokens, logprobs within
+    float32 rounding).
+
+    Chunked ingestion requires an attention-only stack: recurrent segments
+    (SSM / xLSTM) would need their scan state carried across chunks, which
+    their fwd paths do not expose — the engine falls back to monolithic
+    prefill for those families.
     """
     _check_paged_support(cfg)
     _check_attention_only(cfg)
@@ -803,12 +763,12 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
     idx = jnp.clip(lens - 1, 0, C - 1)
     last_h = jax.vmap(lambda h, i: h[i])(x, idx)               # (R, D)
     # per-row unembed via lax.map, NOT one (R, 1, D) einsum: XLA collapses
-    # the latter into an M=R GEMM whose accumulation can differ from the
-    # serial path's M=1 matvec by an ulp (opt-level dependent); mapping
-    # keeps every row the exact (1, 1, D) shape `prefill_chunk_paged`
-    # lowers, preserving the bitwise row-identity contract. R is small
-    # (bucketed batch rows) and only one row per request ever seeds a
-    # sample, so the serialization is negligible.
+    # the latter into an M=R GEMM whose accumulation can differ from an
+    # M=1 matvec by an ulp (opt-level dependent); mapping keeps every row
+    # the exact (1, 1, D) shape whatever R is, preserving the bitwise
+    # row-independence contract. R is small (bucketed batch rows) and only
+    # one row per request ever seeds a sample, so the serialization is
+    # negligible.
     logits = jax.lax.map(
         lambda h: unembed(cfg, params["embed"], h[None, None])[0, 0], last_h)
     # padding rows target index `batch` and are dropped

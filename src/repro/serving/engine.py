@@ -107,13 +107,6 @@ def _decode_dense_fn(cfg, params, tokens, cache, active):
     return transformer.decode_step(cfg, params, tokens, cache, active=active)
 
 
-def _prefill_chunk_fn(cfg, live_pages, params, tokens, cache, slot, offset,
-                      chunk_len):
-    return transformer.prefill_chunk_paged(cfg, params, tokens, cache, slot,
-                                           offset, chunk_len,
-                                           live_pages=live_pages)
-
-
 def _prefill_ragged_fn(cfg, live_pages, params, tokens, cache, slots, offsets,
                        lens):
     """(logits, cache, expert rows: the serving expert layers' assignments
@@ -179,17 +172,13 @@ def _jitted(cfg: ModelConfig, kind: str,
     if kind == "prefill_paged":
         return jax.jit(functools.partial(transformer.prefill_paged, cfg),
                        donate_argnums=(2,))
-    if kind == "prefill_chunk":
-        # live_pages is static (the read width is a shape), bucketed like
-        # the decode step; token shape is always (1, cfg.prefill_chunk), so
-        # chunked engines compile one chunk variant per live-width bucket
-        # instead of one prefill per prompt-length bucket
-        return jax.jit(functools.partial(_prefill_chunk_fn, cfg),
-                       static_argnums=(0,), donate_argnums=(3,))
     if kind == "prefill_ragged":
         # batched ragged ingest: one call advances EVERY ingesting slot's
-        # next chunk; row count is bucketed to powers of two (lo=1), so
+        # next chunk. live_pages is static (the read width is a shape),
+        # bucketed like the decode step; the token shape is (R,
+        # cfg.prefill_chunk) with R bucketed to powers of two (lo=1), so
         # variants are bounded by log2(max_batch) x log2(live widths)
+        # instead of one prefill per prompt-length bucket
         return jax.jit(functools.partial(_prefill_ragged_fn, cfg),
                        static_argnums=(0,), donate_argnums=(3,))
     if kind == "promote":
@@ -289,8 +278,7 @@ class InferenceEngine:
                  max_len: int = 1024, sampler: SamplerConfig = SamplerConfig(),
                  eos_id: int = 0, name: str = "engine",
                  kv_backend: str = "dense", page_size: int = 32,
-                 n_pages: Optional[int] = None, prefix_sharing: bool = True,
-                 ragged_ingest: bool = True, host_swap: bool = True):
+                 n_pages: Optional[int] = None, host_swap: bool = True):
         assert kv_backend in ("dense", "paged"), kv_backend
         # The attention read path is chosen here, once, for every jitted
         # step this engine runs: the Pallas kernels when the config asks
@@ -313,14 +301,6 @@ class InferenceEngine:
         self.eos_id = eos_id
         self.name = name
         self.kv_backend = kv_backend
-        # escape hatch: prefix_sharing=False makes generate_fanout submit
-        # monolithically, restoring exact dense<->paged A/B at the pipeline
-        # level (the fork path's teacher-forced suffixes are a different —
-        # equally valid — float reduction order than one monolithic prefill)
-        self.prefix_sharing = prefix_sharing
-        # escape hatch: ragged_ingest=False keeps the legacy one-chunk-per-
-        # step ingest scheduler (A/B reference for the batched ragged path)
-        self.ragged_ingest = ragged_ingest
         self.slots = [Slot() for _ in range(max_batch)]
         self.key = jax.random.PRNGKey(0)
         self.tokens_generated = 0
@@ -401,7 +381,6 @@ class InferenceEngine:
                 for kind, _ in transformer.segments_of(cfg))
             self.prefill_chunk = cfg.prefill_chunk if chunkable else 0
             if self.prefill_chunk:
-                self._prefill_chunk = _jitted(cfg, "prefill_chunk")
                 self._prefill_ragged = _jitted(cfg, "prefill_ragged")
             # host-tier page swap (demote on eviction, promote on resume)
             # rides the same attention-only gate as chunked prefill:
@@ -786,38 +765,26 @@ class InferenceEngine:
         return min(live, self.pages_per_seq)
 
     def _feed_chunk(self, slot: int, chunk: List[int], offset: int):
-        """One (1, prefill_chunk)-shaped ingest call: pad, pick the covering
-        live width, write+attend the chunk at `offset`. Returns the chunk's
-        last-valid-token logits (1, V)."""
+        """One slot's chunk as a one-row ragged ingest: pad, pick the
+        covering live width, write+attend the chunk at `offset`. Returns
+        the chunk's last-valid-token logits (1, V)."""
         with trace.span("engine.feed_chunk", engine=self.name,
                         offset=offset, length=len(chunk)):
             padded = np.zeros((1, self.prefill_chunk), np.int32)
             padded[0, :len(chunk)] = chunk
             live = self._chunk_live(offset + len(chunk))
             self._sync_table()
-            logits, self.cache = self._prefill_chunk(
-                live, self.params, jnp.asarray(padded), self.cache,
-                jnp.asarray(slot, jnp.int32), jnp.asarray(offset, jnp.int32),
-                jnp.asarray(len(chunk), jnp.int32))
-        return logits
-
-    def _ingest_chunk(self, slot: int):
-        """Feed the slot's next prompt chunk into the paged cache. After the
-        final chunk, the first token is sampled from the chunk's logits —
-        the same (1, V) sample a monolithic `add_request` takes, so the
-        engine's PRNG stream (and therefore sampled output) is unchanged."""
-        s = self.slots[slot]
-        chunk = s.prefill_toks[:self.prefill_chunk]
-        s.prefill_toks = s.prefill_toks[self.prefill_chunk:]
-        logits = self._feed_chunk(slot, chunk, s.ctx_len)
-        s.ctx_len += len(chunk)
-        if not s.prefill_toks:
-            self._first_token(slot, logits)
+            # numpy rows, as `_run_ingest` builds them: jnp.asarray of a
+            # list with a dtype compiles a conversion on first use
+            row = [jnp.asarray(np.array([v], np.int32))
+                   for v in (slot, offset, len(chunk))]
+            logits, self.cache, _ = self._prefill_ragged(
+                live, self.params, jnp.asarray(padded), self.cache, *row)
         return logits
 
     def _first_token(self, slot: int, logits):
         """Sample, read back and commit a slot's first token from (1, V)
-        prefill logits: the one draw admission and the serial ingest take."""
+        prefill logits: the one draw admission takes."""
         with trace.span("engine.first_token", engine=self.name):
             self.key, sub = jax.random.split(self.key)
             tok = sample(logits, sub, self.sampler)
@@ -830,10 +797,10 @@ class InferenceEngine:
     def _prefill_into_chunks(self, slot: int, toks: List[int]):
         """Synchronous chunked ingest of a whole prompt (prefill_prefix and
         direct callers outside the step loop); returns final-chunk logits.
-        Performs no PRNG splits, matching `_ingest_chunk`'s contract that
-        only the first-token sample advances the key stream. An empty
-        prompt ingests one zero-length chunk so callers always get logits
-        (matching the monolithic path's zero-padded prefill)."""
+        Performs no PRNG splits: only the first-token sample advances the
+        key stream. An empty prompt ingests one zero-length chunk so
+        callers always get logits (matching the monolithic path's
+        zero-padded prefill)."""
         C = self.prefill_chunk
         logits = None
         for start in range(0, max(len(toks), 1), C):
@@ -1192,17 +1159,15 @@ class InferenceEngine:
     def _run_ingest(self) -> bool:
         """Batched ragged chunk ingest: EVERY ingesting slot's next chunk in
         one `prefill_ragged_paged` dispatch (qo_indptr-style rows of
-        (slot, offset, len)), instead of one slot per step. Slots whose
-        final chunk lands here draw their first token eagerly — same split
-        order as the serial scheduler — and join the decode batch next
-        step."""
+        (slot, offset, len)). Slots whose final chunk lands here draw their
+        first token eagerly, most urgent first, and join the decode batch
+        next step."""
         ing = [i for i, s in enumerate(self.slots)
                if s.active and s.prefill_toks]
         if not ing:
             return False
-        # finish draws happen in this order; it matches the serial
-        # scheduler's pick order (priority first, then admission age), so
-        # aligned sampled streams stay aligned
+        # finish draws happen in this order: priority first, then
+        # admission age
         ing.sort(key=lambda j: (-self.slots[j].priority,
                                 self.slots[j].arrival))
         C = self.prefill_chunk
@@ -1245,8 +1210,8 @@ class InferenceEngine:
                     # repro-analysis: disable=RA103 reason=read only while the tracer is on
                     sp.attrs["expert_rows"] = jax.device_get(n).tolist()
         # final chunk landed: first token — the same (1, V) sample a
-        # monolithic add_request takes (row slices of the batched logits
-        # are bitwise the single-slot logits)
+        # monolithic add_request takes (a row of the batched logits is
+        # bitwise that row ingested alone)
         done = [(r, i) for r, (i, _, _) in enumerate(rows)
                 if self.slots[i].active and not self.slots[i].prefill_toks]
         if not done:
@@ -1274,17 +1239,12 @@ class InferenceEngine:
         readback to the next step. Returns True if work was done (including
         a harvest-only step that drains the last in-flight decode).
 
-        Batched ingest (`ragged_ingest`, default): every ingesting slot
-        advances one chunk per step through a single ragged device call, so
-        decode latency between steps stays bounded by one chunk of prefill
-        compute and a long prompt still cannot head-of-line-block the batch.
-        Slots whose final chunk lands this step sample their first token
-        eagerly (TTFT semantics unchanged) and join the decode batch next
-        step; with `ragged_ingest=False` the legacy one-chunk-per-step
-        scheduler runs instead, with its same-step join. Either way the
-        ORDER of PRNG draws (finish draws, then the decode split) is
-        unchanged, so greedy outputs and aligned sampled streams match the
-        old loop bitwise.
+        Chunked engines ingest through a single ragged device call a step:
+        every ingesting slot advances one chunk, so decode latency between
+        steps stays bounded by one chunk of prefill compute and a long
+        prompt cannot head-of-line-block the batch. Slots whose final chunk
+        lands this step sample their first token eagerly (finish draws,
+        then the decode split) and join the decode batch next step.
 
         Slots with a pending suffix (fork path, monolithic engines) are
         teacher-forced: the step feeds `pending[0]` instead of the last
@@ -1293,9 +1253,9 @@ class InferenceEngine:
         first real sample.
 
         The step's span (`engine.step`, with the active slots at its start)
-        holds one child per phase: harvest (with its readback), the legacy
-        serial ingest, plan (page growth and the decode plan), table push,
-        ingest, first token (with its readback) and decode dispatch."""
+        holds one child per phase: harvest (with its readback), plan (page
+        growth and the decode plan), table push, ingest, first token (with
+        its readback) and decode dispatch."""
         with trace.span("engine.step", engine=self.name,
                         max_batch=self.max_batch) as sp:
             if self.step_hook is not None:
@@ -1308,21 +1268,6 @@ class InferenceEngine:
             worked = self._harvest()
             if not any(s.active for s in self.slots):
                 return worked
-            batched = self.prefill_chunk and self.ragged_ingest \
-                and self.kv_backend == "paged"
-            if not batched and self.prefill_chunk:
-                # legacy scheduler: one chunk for the most urgent ingesting
-                # slot, which joins the decode batch this same step
-                pref = [i for i, s in enumerate(self.slots)
-                        if s.active and s.prefill_toks]
-                if pref:
-                    # highest priority first (a latency-critical latecomer's
-                    # chunks jump the queue of a long opportunistic ingest),
-                    # oldest admission within a class
-                    self._ingest_chunk(min(
-                        pref, key=lambda j: (-self.slots[j].priority,
-                                             self.slots[j].arrival)))
-                    worked = True
             with trace.span("engine.plan", engine=self.name):
                 active = [i for i, s in enumerate(self.slots)
                           if s.active and not s.prefill_toks]
@@ -1338,7 +1283,7 @@ class InferenceEngine:
                 # active-masked, so they cannot touch a COW sibling's pages.
                 with trace.span("engine.table_push", engine=self.name):
                     self._sync_table()
-            if batched:
+            if self.prefill_chunk:
                 worked = self._run_ingest() or worked
             if plan is not None:
                 with trace.span("engine.decode", engine=self.name) as sp:
@@ -1363,9 +1308,10 @@ class InferenceEngine:
         max_context bounds the decode live-width buckets to warm (default
         max_len); prompt_lens warms monolithic prefill buckets (dense and
         non-chunked paged engines); ingest_rows warms batched ragged ingest
-        row-bucket variants (chunked paged engines). All warm dispatches
-        are state no-ops: all-inactive masks and out-of-range slot rows
-        drop every write, and `self.key` is never advanced."""
+        row-bucket variants (chunked paged engines), and the one-row bucket
+        that `prefill_prefix` ingests through is always warmed. All warm
+        dispatches are state no-ops: all-inactive masks and out-of-range
+        slot rows drop every write, and `self.key` is never advanced."""
         assert not any(s.active or s.parked for s in self.slots), \
             "warmup requires an idle engine"
         key0 = jax.random.PRNGKey(0)    # throwaway: self.key stays untouched
@@ -1381,8 +1327,8 @@ class InferenceEngine:
                     live, self.params, jnp.asarray(last), self.cache,
                     jnp.asarray(mask), key0)
                 count += 1
-            if self.prefill_chunk and self.ragged_ingest:
-                rbs = set()
+            if self.prefill_chunk:
+                rbs = {1}
                 for n in ingest_rows:
                     r = 1
                     while r < min(n, self.max_batch):
@@ -1398,18 +1344,6 @@ class InferenceEngine:
                             jnp.zeros((rb,), jnp.int32),
                             jnp.zeros((rb,), jnp.int32))
                         count += 1
-            if self.prefill_chunk and (self.prefix_sharing
-                                       or not self.ragged_ingest):
-                # single-slot chunk variants: the serial fallback scheduler
-                # and prefill_prefix (the PICE fan-out's shared prefix) run
-                # them (zero-length chunk: every write drops)
-                for live in lives:
-                    _, self.cache = self._prefill_chunk(
-                        live, self.params,
-                        jnp.zeros((1, self.prefill_chunk), jnp.int32),
-                        self.cache, jnp.asarray(0, jnp.int32),
-                        jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
-                    count += 1
             if prompt_lens and not self.prefill_chunk:
                 for S in sorted({min(_bucket(n), self.max_len)
                                  for n in prompt_lens}):
@@ -1497,9 +1431,8 @@ class InferenceEngine:
         expansion forks a copy-on-write block-table row off it, so the pool
         holds one prefix instead of N; per-group suffixes are teacher-forced
         before sampling. Falls back to independent submissions on the dense
-        backend, a 1-slot engine, or prefix_sharing=False."""
-        if (self.kv_backend != "paged" or self.max_batch < 2
-                or not self.prefix_sharing):
+        backend or a 1-slot engine."""
+        if self.kv_backend != "paged" or self.max_batch < 2:
             return self.generate([list(prefix) + list(s) for s in suffixes],
                                  max_new=max_new,
                                  priorities=[priority] * len(suffixes),

@@ -638,8 +638,7 @@ class EngineFrontend:
         where the engine does."""
         engine = self.engine
         t_call = trace.now() if trace.enabled() else None
-        if (engine.kv_backend != "paged" or engine.max_batch < 2
-                or not engine.prefix_sharing):
+        if engine.kv_backend != "paged" or engine.max_batch < 2:
             return await self.generate_async(
                 [list(prefix) + list(s) for s in suffixes], max_new=max_new,
                 priorities=[priority] * len(suffixes), deadline_s=deadline_s,

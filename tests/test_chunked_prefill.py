@@ -194,16 +194,20 @@ def test_chunked_fanout_suffix_replay_matches_token_by_token(params):
     assert all(c == 0 for c in chunked.alloc.refcount)
 
 
-def test_chunked_fanout_sampled_empty_suffix(params):
+@pytest.mark.parametrize("plen", [15, 16, 70])
+def test_chunked_fanout_sampled_empty_suffix(params, plen):
     """Empty-suffix fan-out: every fork samples its first token at
-    admission in both engines, so even stochastic draws line up."""
+    admission in both engines, so even stochastic draws line up. The
+    prefix is shorter than a chunk, exactly one, or several: each of its
+    chunks enters as a one-row ragged ingest."""
     sampler = SamplerConfig(temperature=0.8, top_k=16)
+    prefix = FANOUT_PREFIX[:plen]
     a = _engine(params, chunk=0, max_batch=4,
                 sampler=sampler).generate_fanout(
-        FANOUT_PREFIX, [[] for _ in range(3)], max_new=8)
+        prefix, [[] for _ in range(3)], max_new=8)
     b = _engine(params, chunk=16, max_batch=4,
                 sampler=sampler).generate_fanout(
-        FANOUT_PREFIX, [[] for _ in range(3)], max_new=8)
+        prefix, [[] for _ in range(3)], max_new=8)
     _assert_same(a, b)
 
 
@@ -297,10 +301,11 @@ def _stacked_pool(key, shape, dtype, layer, n_layers=3):
 @pytest.mark.parametrize("layer", [0, 1])
 def test_paged_prefill_kernel_parity(dtype, Hq, Hkv, hd, ps, C, offset, clen,
                                      layer):
-    """The single-slot and ragged kernels vs their oracles, at layer 0 and
-    the middle layer of a three-layer stack. Ragged rows: the slot, a COW
-    fork sharing its first page, and an unmapped padding row of length 0;
-    the slot's ragged row is bitwise the single-slot kernel."""
+    """The ragged kernel vs its oracle, at layer 0 and the middle layer of
+    a three-layer stack, for one slot alone (the one-row batch a prefix
+    prefill dispatches) and in a three-row batch: the slot, a COW fork
+    sharing its first page, and an unmapped padding row of length 0. The
+    slot's row in the batch is bitwise its one-row call."""
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     n_pages, P = 14, 8
     q = jax.random.normal(ks[0], (1, C, Hq, hd), dtype)
@@ -313,10 +318,10 @@ def test_paged_prefill_kernel_parity(dtype, Hq, Hkv, hd, ps, C, offset, clen,
     fork = np.full((P,), -1, np.int32)
     fork[0], fork[1:need] = row[0], perm[need:2 * need - 1]
     row = jnp.asarray(row)
-    out = ppa_ops.paged_prefill_attention(q, kp, vp, row, jnp.int32(offset),
-                                          jnp.int32(clen), layer=layer)
-    ref = ppa_ref.paged_prefill_attention_ref(q, kp, vp, row, offset, clen,
-                                              layer)
+    one = (row[None], jnp.asarray([offset], jnp.int32),
+           jnp.asarray([clen], jnp.int32))
+    out = ppa_ops.paged_prefill_attention_ragged(q, kp, vp, *one, layer=layer)
+    ref = ppa_ref.paged_prefill_attention_ragged_ref(q, kp, vp, *one, layer)
     np.testing.assert_allclose(
         np.asarray(out[:, :clen], np.float32),
         np.asarray(ref[:, :clen], np.float32), **_tol(dtype))
@@ -353,19 +358,19 @@ def test_paged_prefill_kernel_ignores_poisoned_pages():
                                 1))
     total = offset + clen
     need = -(-total // ps)
-    row = np.full((P,), -1, np.int32)
-    row[:need] = np.arange(need)
-    clean = ppa_ops.paged_prefill_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(row),
-        jnp.int32(offset), jnp.int32(clen), layer=1)
+    row = np.full((1, P), -1, np.int32)
+    row[0, :need] = np.arange(need)
+    one = (jnp.asarray(row), jnp.asarray([offset], jnp.int32),
+           jnp.asarray([clen], jnp.int32))
+    clean = ppa_ops.paged_prefill_attention_ragged(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), *one, layer=1)
     kp[[0, 2]], vp[[0, 2]] = np.nan, np.nan               # other layers
     kp[1, need:], vp[1, need:] = np.nan, np.nan           # unmapped pages
     tail = total - (need - 1) * ps
     kp[1, need - 1, :, tail:] = np.nan                    # past total
     vp[1, need - 1, :, tail:] = np.nan
-    out = ppa_ops.paged_prefill_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(row),
-        jnp.int32(offset), jnp.int32(clen), layer=1)
+    out = ppa_ops.paged_prefill_attention_ragged(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), *one, layer=1)
     np.testing.assert_array_equal(np.asarray(out[:, :clen]),
                                   np.asarray(clean[:, :clen]))
 

@@ -280,17 +280,6 @@ def test_fanout_dense_backend_falls_back(params):
     assert a == b
 
 
-def test_prefix_sharing_opt_out_is_monolithic(params):
-    """prefix_sharing=False restores exact monolithic submissions (the
-    pipeline-level dense<->paged A/B escape hatch)."""
-    a = _engine(params, kv_backend="paged", page_size=16,
-                prefix_sharing=False).generate_fanout(
-        FANOUT_PROMPT, [[1], [2]], max_new=4)
-    b = _engine(params, kv_backend="paged", page_size=16).generate(
-        [FANOUT_PROMPT + [1], FANOUT_PROMPT + [2]], max_new=4)
-    assert a == b
-
-
 def test_release_prefix_frees_parked_pages(params):
     eng = _engine(params, kv_backend="paged", page_size=16)
     slot = eng.prefill_prefix(FANOUT_PROMPT)

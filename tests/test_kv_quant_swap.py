@@ -202,23 +202,26 @@ def test_paged_decode_quant_kernel_matches_dequant_oracle(kv_dtype, layer, P):
 @pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("kv_dtype", ["int8"])
 def test_paged_prefill_quant_kernel_matches_dequant_oracle(kv_dtype, layer):
-    """The single-slot and ragged quant kernels vs their oracles at layer 0
-    and a middle layer. Ragged rows: the slot, a COW fork of it that shares
-    its first page, and an unmapped padding row of length 0."""
+    """The ragged quant kernel vs its oracles at layer 0 and a middle
+    layer, for one slot alone (a one-row batch) and in a three-row batch:
+    the slot, a COW fork of it that shares its first page, and an unmapped
+    padding row of length 0."""
     page, P, kv, Hq, hd = 8, 4, 2, 8, 32
     ctx, C = 11, 8                       # chunk starts mid-page
     key = jax.random.PRNGKey(4)
     kf, vf, kq, vq, ks, vs, table = _quant_pool(
         key, P + 4, page, kv, hd, kv_dtype, 1, [ctx + C], layer=layer)
     q = jax.random.normal(jax.random.PRNGKey(5), (3, C, Hq, hd))
-    out = ppa_ops.paged_prefill_attention_quant(
-        q[:1], kq, vq, ks, vs, table[0], ctx, C, layer=layer)
-    ref = ppa_ref.paged_prefill_attention_quant_ref(
-        q[:1], kq, vq, ks, vs, table[0], ctx, C, layer)
+    one = (table[:1], jnp.asarray([ctx], jnp.int32),
+           jnp.asarray([C], jnp.int32))
+    out = ppa_ops.paged_prefill_attention_ragged_quant(
+        q[:1], kq, vq, ks, vs, *one, layer=layer)
+    ref = ppa_ref.paged_prefill_attention_ragged_quant_ref(
+        q[:1], kq, vq, ks, vs, *one, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-    ref_f = ppa_ref.paged_prefill_attention_ref(q[:1], kf, vf, table[0],
-                                                ctx, C, layer)
+    ref_f = ppa_ref.paged_prefill_attention_ragged_ref(q[:1], kf, vf, *one,
+                                                       layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_f),
                                rtol=0.15, atol=0.1)
     rows = jnp.stack([table[0], table[0].at[1:].set([P, P + 1]),
@@ -235,8 +238,9 @@ def test_paged_prefill_quant_kernel_matches_dequant_oracle(kv_dtype, layer):
 
 
 def test_paged_prefill_ragged_quant_rows_match_single():
-    """Each ragged row is bitwise the single-slot quant kernel on the same
-    pool (batching adds rows, never changes a row's reduction order)."""
+    """Each ragged row is bitwise the same row ingested alone, as a one-row
+    batch, on the same pool (batching adds rows, never changes a row's
+    reduction order)."""
     page, P, kv, Hq, hd = 8, 3, 2, 4, 32
     lens_total = [19, 8]
     chunk = [8, 8]
@@ -250,8 +254,10 @@ def test_paged_prefill_ragged_quant_rows_match_single():
         q, kq, vq, ks, vs, table, jnp.asarray(offs, jnp.int32),
         jnp.asarray(chunk, jnp.int32))
     for r in range(2):
-        single = ppa_ops.paged_prefill_attention_quant(
-            q[r:r + 1], kq, vq, ks, vs, table[r], offs[r], chunk[r])
+        single = ppa_ops.paged_prefill_attention_ragged_quant(
+            q[r:r + 1], kq, vq, ks, vs, table[r:r + 1],
+            jnp.asarray(offs[r:r + 1], jnp.int32),
+            jnp.asarray(chunk[r:r + 1], jnp.int32))
         np.testing.assert_array_equal(
             np.asarray(out[r:r + 1, :chunk[r]]),
             np.asarray(single[:, :chunk[r]]))

@@ -342,7 +342,7 @@ def _pallas_names(fn, *args):
     return names
 
 
-def _kernel_args(quant: bool, ragged: bool, decode: bool):
+def _kernel_args(quant: bool, decode: bool):
     S = jax.ShapeDtypeStruct
     dt = jnp.int8 if quant else jnp.float32
     pages = S((2, 6, 1, 8, 128), dt)
@@ -351,22 +351,18 @@ def _kernel_args(quant: bool, ragged: bool, decode: bool):
     if decode:
         return [S((2, 1, 2, 128), jnp.float32), pages, pages, *scales,
                 S((2, 3), i32), S((2,), i32)]
-    if ragged:
-        return [S((2, 8, 2, 128), jnp.float32), pages, pages, *scales,
-                S((2, 3), i32), S((2,), i32), S((2,), i32)]
-    return [S((1, 8, 2, 128), jnp.float32), pages, pages, *scales,
-            S((3,), i32), S((), i32), S((), i32)]
+    return [S((2, 8, 2, 128), jnp.float32), pages, pages, *scales,
+            S((2, 3), i32), S((2,), i32), S((2,), i32)]
 
 
 @pytest.mark.parametrize("wrapper", [
     "paged_decode_attention", "paged_decode_attention_quant",
-    "paged_prefill_attention", "paged_prefill_attention_quant",
     "paged_prefill_attention_ragged",
     "paged_prefill_attention_ragged_quant"])
 def test_paged_kernels_carry_their_wrappers_name(wrapper):
     from repro.kernels.paged_prefill_attention import ops as ppa_ops
     decode = wrapper.startswith("paged_decode")
     fn = getattr(pda_ops if decode else ppa_ops, wrapper)
-    args = _kernel_args("quant" in wrapper, "ragged" in wrapper, decode)
+    args = _kernel_args("quant" in wrapper, decode)
     names = _pallas_names(lambda *a: fn(*a, interpret=True), *args)
     assert names == [wrapper]

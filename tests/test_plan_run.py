@@ -1,12 +1,12 @@
-"""Plan/run engine step: batched ragged ingest vs the serial fallback vs
-monolithic prefill (three-way agreement: identical tokens, logprobs within
-float32 rounding), the one-table-push-per-step contract, admission-stamp
-pruning under churn, surfaced prompt truncation, and the bounded score
-buffer."""
+"""Plan/run engine step: batched ragged ingest vs monolithic prefill
+(identical tokens, logprobs within float32 rounding), the
+one-table-push-per-step contract, admission-stamp pruning under churn,
+surfaced prompt truncation, and the bounded score buffer."""
 import jax
 import numpy as np
 import pytest
 
+from repro.core import trace
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.serving.engine import InferenceEngine
@@ -34,12 +34,12 @@ def _engine(params, chunk=0, **kw):
     return InferenceEngine(cfg, params, **kw)
 
 
-# The three schedulers batch different rows into each call (one chunk per
-# step, R rows per step, or one monolithic prefill), and XLA picks kernels
-# and reduction orders by shape, so logprobs agree to float32 rounding, not
-# bit for bit: on XLA:CPU under JAX 0.9.0 they differ by at most 1.9e-6.
-# 1e-5 is five times that and far below any change of the chosen token.
-# Tokens stay exactly equal.
+# Ragged ingest and monolithic prefill batch different rows into each call
+# (R chunk rows per step, or one whole prompt), and XLA picks kernels and
+# reduction orders by shape, so logprobs agree to float32 rounding, not bit
+# for bit: on XLA:CPU under JAX 0.9.0 they differ by at most 1.9e-6. 1e-5
+# is five times that and far below any change of the chosen token. Tokens
+# stay exactly equal.
 LOGPROB_ATOL = 1e-5
 
 
@@ -60,70 +60,55 @@ def _assert_same_replay(a, b):
 
 
 # ---------------------------------------------------------------------------
-# three-way agreement: batched ragged == serial one-chunk == monolithic
+# agreement: batched ragged ingest == monolithic prefill
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("chunk", [16, 48])
 @pytest.mark.parametrize("page", [8, 16])
 def test_three_way_greedy(params, chunk, page):
     mono = _engine(params, chunk=0, page_size=page)
-    serial = _engine(params, chunk=chunk, page_size=page,
-                     ragged_ingest=False)
     batched = _engine(params, chunk=chunk, page_size=page)
     om = mono.generate(PROMPTS, max_new=12)
-    os_ = serial.generate(PROMPTS, max_new=12)
     ob = batched.generate(PROMPTS, max_new=12)
-    _assert_same(om, os_)
     _assert_same(om, ob)
     assert batched.alloc.pages_in_use == 0
-    assert serial.alloc.pages_in_use == 0
 
 
 def test_three_way_sampled_serialized(params):
-    """One slot serializes the PRNG stream position-for-position: all three
-    schedulers take identical draws."""
+    """One slot serializes the PRNG stream position-for-position: ragged
+    ingest and monolithic prefill take identical draws."""
     sampler = SamplerConfig(temperature=0.9, top_k=20)
-    outs = [_engine(params, chunk=c, max_batch=1, ragged_ingest=r,
+    outs = [_engine(params, chunk=c, max_batch=1,
                     sampler=sampler).generate(PROMPTS[:3], max_new=10)
-            for c, r in ((0, True), (16, False), (16, True))]
+            for c in (0, 16)]
     _assert_same(outs[0], outs[1])
-    _assert_same(outs[0], outs[2])
 
 
 def test_three_way_fork_suffixes(params):
-    """Fork fan-out: suffix replay rides the (batched) chunk path; serial
-    and batched must agree (identical tokens, LOGPROB_ATOL), and both match
-    monolithic up to the documented (1, V)-vs-(B, V) unembed ulp on the
-    post-replay logprob."""
+    """Fork fan-out: the shared prefix enters as one-row ragged ingests and
+    the suffix replay rides the batched chunk path; both match monolithic
+    up to the documented (1, V)-vs-(B, V) unembed ulp on the post-replay
+    logprob."""
     prefix = [(i % 100) + 1 for i in range(70)]
     suffixes = [[5, 6, 7], [9], [11] * 20]
     mono = _engine(params, chunk=0, max_batch=4)
-    serial = _engine(params, chunk=16, max_batch=4, ragged_ingest=False)
     batched = _engine(params, chunk=16, max_batch=4)
     om = mono.generate_fanout(prefix, suffixes, max_new=8)
-    os_ = serial.generate_fanout(prefix, suffixes, max_new=8)
     ob = batched.generate_fanout(prefix, suffixes, max_new=8)
-    _assert_same(os_, ob)
     _assert_same_replay(om, ob)
     assert batched.alloc.pages_in_use == 0
 
 
 def test_three_way_eviction_resume(params):
-    """A starved pool preempts and resumes; every scheduler converges to
-    the unconstrained result. Serial and batched may preempt at different
-    step boundaries (batched ingest moves the pressure point), so the one
-    post-resume logprob carries the documented replay ulp — tokens are
-    still bitwise."""
+    """A starved pool preempts and resumes; it converges to the
+    unconstrained result. The one post-resume logprob carries the
+    documented replay ulp — tokens are still bitwise."""
     prompts = [[65, 66, 67, 68], [70, 71], [80, 81, 82]]
     ref = _engine(params, chunk=16, max_len=64,
                   page_size=8).generate(prompts, max_new=24)
-    serial = _engine(params, chunk=16, max_len=64, page_size=8, n_pages=6,
-                     ragged_ingest=False)
     batched = _engine(params, chunk=16, max_len=64, page_size=8, n_pages=6)
-    os_ = serial.generate(prompts, max_new=24)
     ob = batched.generate(prompts, max_new=24)
-    assert serial.evictions > 0 and batched.evictions > 0
-    _assert_same_replay(os_, ob)
+    assert batched.evictions > 0
     _assert_same_replay(ref, ob)
     assert batched.alloc.pages_in_use == 0
 
@@ -198,6 +183,28 @@ def test_warmup_refuses_busy_engine(params):
     eng.add_request(0, [1, 2, 3], max_new=4)
     with pytest.raises(AssertionError):
         eng.warmup()
+
+
+def test_warmup_covers_prefix_prefill_without_one_row_traffic(params):
+    """A shared prefix enters as one-row ragged ingests, so warmup compiles
+    the one-row variants even when the traffic's `ingest_rows` leave 1 out:
+    a prefix spanning several chunks and live widths then compiles
+    nothing. Compile caches are cleared first, so no other test's compiles
+    can stand in for the warmup's."""
+    jax.clear_caches()
+    eng = _engine(params, chunk=16, max_batch=4)
+    eng.warmup(ingest_rows=(4,))
+    trace.enable()
+    try:
+        slot = eng.prefill_prefix([(i % 100) + 1 for i in range(70)])
+        jax.block_until_ready(eng.cache)
+    finally:
+        trace.disable()
+    compiles = [r.attrs.get("span") for r in trace.records()
+                if r.name == "jax.compile"]
+    assert compiles == []
+    assert eng.slots[slot].ctx_len == 70
+    eng.release_prefix(slot)
 
 
 # ---------------------------------------------------------------------------
