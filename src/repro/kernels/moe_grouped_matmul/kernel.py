@@ -3,10 +3,16 @@
 Rows of `lhs` (m, k) are sorted by expert: the first group_sizes[0] rows
 belong to group 0, the next group_sizes[1] to group 1, and so on; rows past
 the last group belong to none. Each group's rows are multiplied by that
-group's weight `rhs[g]` (k, n). Adapted from the megablox `gmm` kernel that
-JAX ships (jax/experimental/pallas/ops/tpu/megablox/gmm.py), cut to what
-the expert layer needs: every group of `rhs` is held here (no group offset),
-no output is carried in, and the tiles divide k and n. Its own call is named
+group's weight `rhs[layer, g]` (k, n). `rhs` is a layer loop's whole stack
+of held-expert weights (L, G, k, n) and `layer` a traced index into it,
+prefetched to SMEM with the group metadata: the weight tiles stream
+straight out of the stack, so no caller slices one layer's experts out of
+it (XLA would materialise that slice, every expert of it, as the custom
+call's operand). A single layer's (G, k, n) is the L = 1, layer 0 case.
+Adapted from the megablox `gmm` kernel that JAX ships
+(jax/experimental/pallas/ops/tpu/megablox/gmm.py), cut to what the expert
+layer needs: every group of a layer is held here (no group offset), no
+output is carried in, and the tiles divide k and n. Its own call is named
 `moe_grouped_matmul`, so a profiler trace tells it apart.
 
   * the grid is (n tiles, m tiles visited, k tiles). An m tile is visited
@@ -77,8 +83,8 @@ def tiling(m: int, k: int, n: int):
     return tm, tile(k, 1536), tile(n, 1536)
 
 
-def _gmm_kernel(meta_offsets, meta_groups, meta_tiles, lhs_ref, rhs_ref,
-                out_ref, acc_ref, *, tm: int, tn: int, tiles_k: int):
+def _gmm_kernel(meta_offsets, meta_groups, meta_tiles, layer_ref, lhs_ref,
+                rhs_ref, out_ref, acc_ref, *, tm: int, tn: int, tiles_k: int):
     grid_id = pl.program_id(1)
     k_i = pl.program_id(2)
 
@@ -101,12 +107,16 @@ def _gmm_kernel(meta_offsets, meta_groups, meta_tiles, lhs_ref, rhs_ref,
                                  ).astype(out_ref.dtype)
 
 
-def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, *, interpret: bool):
-    """lhs: (m, k) rows sorted by group; rhs: (G, k, n); group_sizes: (G,)
-    int32 with sum <= m. -> (m, n) in lhs's dtype: row i of group g is
-    lhs[i] @ rhs[g]; rows past the last group are unwritten."""
+def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, layer=0, *,
+                              interpret: bool):
+    """lhs: (m, k) rows sorted by group; rhs: (L, G, k, n), or one layer's
+    (G, k, n); group_sizes: (G,) int32 with sum <= m; layer: int32 scalar
+    (traced) below L. -> (m, n) in lhs's dtype: row i of group g is
+    lhs[i] @ rhs[layer, g]; rows past the last group are unwritten."""
+    if rhs.ndim == 3:
+        rhs = rhs[None]
     m, k = lhs.shape
-    G, _, n = rhs.shape
+    _, G, _, n = rhs.shape
     tm, tk, tn = tiling(m, k, n)
     pad = -m % tm
     if pad:
@@ -115,13 +125,13 @@ def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, *, interpret: bool):
     meta, visits = group_metadata(group_sizes.astype(jnp.int32), mp, tm)
     tiles_k, tiles_n = k // tk, n // tn
 
-    def lhs_map(n_i, grid_id, k_i, offsets, groups, tiles):
+    def lhs_map(n_i, grid_id, k_i, offsets, groups, tiles, layer):
         return tiles[grid_id], k_i
 
-    def rhs_map(n_i, grid_id, k_i, offsets, groups, tiles):
-        return groups[grid_id], k_i, n_i
+    def rhs_map(n_i, grid_id, k_i, offsets, groups, tiles, layer):
+        return layer[0], groups[grid_id], k_i, n_i
 
-    def out_map(n_i, grid_id, k_i, offsets, groups, tiles):
+    def out_map(n_i, grid_id, k_i, offsets, groups, tiles, layer):
         return tiles[grid_id], n_i
 
     kernel = functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k)
@@ -129,9 +139,9 @@ def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, *, interpret: bool):
         kernel,
         out_shape=jax.ShapeDtypeStruct((mp, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             in_specs=[pl.BlockSpec((tm, tk), lhs_map),
-                      pl.BlockSpec((1, tk, tn), rhs_map)],
+                      pl.BlockSpec((None, 1, tk, tn), rhs_map)],
             out_specs=pl.BlockSpec((tm, tn), out_map),
             grid=(tiles_n, visits, tiles_k),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
@@ -141,5 +151,5 @@ def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, *, interpret: bool):
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
         name="moe_grouped_matmul",
-    )(*meta, lhs, rhs)
+    )(*meta, jnp.asarray(layer, jnp.int32).reshape(1), lhs, rhs)
     return out[:m]

@@ -25,6 +25,9 @@ from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, init_mlp, mlp
 
 
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
 def init_moe(cfg: ModelConfig, key) -> dict:
     """The router over all n_experts; the weights of the experts this chip
     holds (all of them unless cfg.ep_size > 1); the shared expert, one
@@ -137,8 +140,8 @@ def route(cfg: ModelConfig, router: jax.Array, xf: jax.Array):
     return top_w * cfg.routed_scaling_factor, top_e
 
 
-def moe_serve(cfg: ModelConfig, params: dict, x: jax.Array, stats=None
-              ) -> jax.Array:
+def moe_serve(cfg: ModelConfig, params: dict, x: jax.Array, stats=None,
+              layer=None) -> jax.Array:
     """The serving expert layer. x: (..., D) -> (..., D).
 
     Dropless and grouped: the router picks top-k of all n_experts per
@@ -152,6 +155,10 @@ def moe_serve(cfg: ModelConfig, params: dict, x: jax.Array, stats=None
     assignment to an expert held elsewhere adds nothing here: on one chip
     the layer runs without its exchange, and this partial sum is what the
     layer passes on. The shared expert is added once.
+
+    params' expert weights are one layer's (held, k, n), or a layer loop's
+    whole stack (L, held, k, n) in x's dtype, which the grouped matmul
+    reads at `layer` (a traced int32) in place.
 
     `stats`, a dict, gets "expert_rows": (held,) int32, the assignments
     each held expert computed."""
@@ -171,16 +178,21 @@ def moe_serve(cfg: ModelConfig, params: dict, x: jax.Array, stats=None
     tok = order // K
     w = jnp.where(mine[order], top_w.reshape(-1)[order], 0.0)
     rows = xf[tok]                                              # (M, D)
+    dt = x.dtype
+    stack = [params[k] for k in EXPERT_WEIGHTS]
+    if stack[0].ndim == 3:              # one layer's: a stack of one
+        stack, layer = [a[None].astype(dt) for a in stack], 0
+    assert layer is not None and all(a.dtype == dt for a in stack)
     if cfg.use_pallas:
         from repro.kernels.moe_grouped_matmul import ops as gmm_ops
         gmm = gmm_ops.moe_grouped_matmul
     else:
-        def gmm(lhs, rhs, gs):
-            return jax.lax.ragged_dot(lhs, rhs, gs)
-    dt = x.dtype
-    g = gmm(rows, params["w_gate"].astype(dt), sizes)
-    u = gmm(rows, params["w_up"].astype(dt), sizes)
-    y = gmm(jax.nn.silu(g) * u, params["w_down"].astype(dt), sizes)
+        def gmm(lhs, rhs, gs, layer):
+            return jax.lax.ragged_dot(lhs, rhs[layer], gs)
+    w_gate, w_up, w_down = stack
+    g = gmm(rows, w_gate, sizes, layer)
+    u = gmm(rows, w_up, sizes, layer)
+    y = gmm(jax.nn.silu(g) * u, w_down, sizes, layer)
     valid = jnp.arange(M) < jnp.sum(sizes)
     y = jnp.where(valid[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
     out = jnp.zeros((T, D), jnp.float32).at[tok].add(y).astype(dt)

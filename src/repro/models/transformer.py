@@ -411,12 +411,13 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache: dict,
 
 def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
                    positions, prompt_lengths, enc_out, mesh=None,
-                   kv_writer=None):
+                   kv_writer=None, layer=None):
     """Full-sequence pass that also produces the cache entry for this layer.
 
     kv_writer: optional (c, k, v) -> newc override for the attention-KV cache
     entry (the paged backend scatters into its page pool here); the compute
     path is shared so dense and paged prefill produce identical activations.
+    The paged backend's `layer` reads a held-expert stack (_block_tail).
     """
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -435,7 +436,8 @@ def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
         x = x + h
         if kv_writer is not None:
             newc = kv_writer(c, k, v)
-            return _block_tail(cfg, kind, blk, x, newc, enc_out, mesh)
+            return _block_tail(cfg, kind, blk, x, newc, enc_out, mesh,
+                               layer=layer)
         newc = dict(c)
         if cfg.sliding_window:
             w = cfg.sliding_window
@@ -472,11 +474,13 @@ def _prefill_block(cfg: ModelConfig, kind: str, blk: dict, c: dict, x,
 
 
 def _block_tail(cfg: ModelConfig, kind: str, blk: dict, x, newc,
-                enc_out, mesh=None, stats=None):
+                enc_out, mesh=None, stats=None, layer=None):
     """Post-attention tail of an attention block, shared by dense and paged
     prefill and the paged ingest and decode steps: optional cross-attention
     cache, then the MoE/MLP block (the serving expert layer, which fills
-    `stats`, when cfg.moe_dropless)."""
+    `stats`, when cfg.moe_dropless; its experts are the whole stack of the
+    layer loop, read at the traced `layer`, where _paged_layers hands it
+    that)."""
     if enc_out is not None and "xattn" in blk:
         xin2 = norm(cfg, blk["norm_x"], x)
         _, ck, cv = attn_lib._project_qkv(cfg, blk["xattn"], xin2,
@@ -485,7 +489,7 @@ def _block_tail(cfg: ModelConfig, kind: str, blk: dict, x, newc,
         x = x + attn_lib.cross_attention_cached(cfg, blk["xattn"], xin2, ck, cv)
     if kind == MOE and cfg.moe_dropless:
         h = moe_lib.moe_serve(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
-                              stats)
+                              stats, layer)
     elif kind == MOE:
         h, _ = moe_lib.moe_fwd(cfg, blk["moe"], norm(cfg, blk["norm2"], x),
                                mesh=mesh)
@@ -619,6 +623,19 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
             "segments": segs}
 
 
+def _expert_stack(cfg: ModelConfig, kind: str, seg: dict, dtype):
+    """(the segment's params without its held-expert weights, those
+    weights stacked over the segment's layers) for a serving expert
+    segment whose weights are stored in the activations' dtype; else
+    (seg, None): those read one layer's weights, cast to `dtype`."""
+    if kind != MOE or not cfg.moe_dropless \
+            or seg["moe"]["w_gate"].dtype != dtype:
+        return seg, None
+    moe = dict(seg["moe"])
+    experts = {k: moe.pop(k) for k in moe_lib.EXPERT_WEIGHTS}
+    return {**seg, "moe": moe}, experts
+
+
 def _paged_layers(cfg: ModelConfig, params: dict, cache: dict, x, attn,
                   other=None, mesh=None, stats=None):
     """Run every layer of a paged-cache stack over `x`; returns (x, the new
@@ -632,6 +649,13 @@ def _paged_layers(cfg: ModelConfig, params: dict, cache: dict, x, attn,
     Recurrent segments' O(1) per-slot states scan as usual through
     `other(x, blk, state, kind) -> (x, state)`.
 
+    The serving expert layers' held-expert weights stay out of the scanned
+    xs for the same reason (_expert_stack): `blk` holds the segment's whole
+    stack of them, loop-invariant and read-only, and the grouped matmul
+    reads layer `layer` of it in place. Scanned, each layer's experts
+    would be sliced out of the stack and copied whole, since XLA
+    materialises a custom call's operand.
+
     `stats`, a dict, gets "expert_rows": the assignments each held expert
     computed, summed over the serving expert layers (cfg.moe_dropless)."""
     new_segs = []
@@ -641,9 +665,13 @@ def _paged_layers(cfg: ModelConfig, params: dict, cache: dict, x, attn,
         if kind == SHARED_ATTN:
             x, segc = attn(x, params["shared"], segc, 0, kind, None)
         elif kind in (ATTN, MOE):
-            def body(carry, inp, kind=kind):
+            seg, experts = _expert_stack(cfg, kind, seg, x.dtype)
+
+            def body(carry, inp, kind=kind, experts=experts):
                 x, pools = carry
                 blk, layer = inp
+                if experts:
+                    blk = {**blk, "moe": {**blk["moe"], **experts}}
                 x = _constrain(cfg, mesh, x)
                 st = {}
                 out = attn(x, blk, pools, layer, kind, st)
@@ -689,7 +717,7 @@ def prefill_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
             return pc.write_pools(pools, layer, "prompt", block_row, k, v,
                                   plen)
         return _prefill_block(cfg, kind, blk, pools, x, positions, plens,
-                              None, mesh, kv_writer=write)
+                              None, mesh, kv_writer=write, layer=layer)
 
     def other(x, blk, c, kind):
         x, one = _prefill_block(cfg, kind, blk, c, x, positions, plens,
@@ -755,7 +783,8 @@ def prefill_ragged_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
         h, pools = ragged(cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools,
                           layer, block_rows, offsets, lens,
                           live_pages=live_pages)
-        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats,
+                           layer)
 
     x, new_segs = _paged_layers(cfg, params, cache, x, attn, mesh=mesh,
                                 stats=stats)
@@ -807,7 +836,8 @@ def decode_step_paged(cfg: ModelConfig, params: dict, tokens: jax.Array,
         h, pools = decode(cfg, blk["attn"], norm(cfg, blk["norm1"], x), pools,
                           layer, table, lengths, live_pages=live_pages,
                           active=active)
-        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats)
+        return _block_tail(cfg, kind, blk, x + h, pools, None, mesh, stats,
+                           layer)
 
     def other(x, blk, c, kind):
         return _decode_block(cfg, kind, blk, c, x, lengths, mesh)

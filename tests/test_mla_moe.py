@@ -93,16 +93,24 @@ def test_latent_prefill_kernel_matches_oracle():
                                    np.asarray(want)[r, :n], **TOL)
 
 
+@pytest.mark.parametrize("layer", [None, 0, 1, 2])
 @pytest.mark.parametrize("sizes", [[0, 130, 0, 100], [0, 0, 0, 0],
                                    [300, 0, 0, 0], [7, 1, 0, 40]])
-def test_grouped_matmul_matches_oracle(sizes):
+def test_grouped_matmul_matches_oracle(sizes, layer):
     """Empty groups, no rows at all, one group holding every row, groups
-    sharing a row tile; rows past the last group are the caller's."""
+    sharing a row tile; rows past the last group are the caller's. One
+    layer's weights, or a stack of three read at `layer` in place."""
     k1, k2 = jax.random.split(jax.random.PRNGKey(2))
     lhs = jax.random.normal(k1, (300, 64), jnp.float32)
-    rhs = jax.random.normal(k2, (4, 64, 48), jnp.float32)
     gs = jnp.asarray(sizes, jnp.int32)
-    got = gmm_ops.moe_grouped_matmul(lhs, rhs, gs)
+    if layer is None:
+        rhs = jax.random.normal(k2, (4, 64, 48), jnp.float32)
+        got = gmm_ops.moe_grouped_matmul(lhs, rhs, gs)
+    else:
+        stack = jax.random.normal(k2, (3, 4, 64, 48), jnp.float32)
+        got = gmm_ops.moe_grouped_matmul(lhs, stack, gs,
+                                         jnp.asarray(layer, jnp.int32))
+        rhs = stack[layer]
     want = gmm_ref.moe_grouped_matmul_ref(lhs, rhs, gs)
     n = sum(sizes)
     np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
@@ -111,6 +119,25 @@ def test_grouped_matmul_matches_oracle(sizes):
 
 def _moe_params(cfg, seed=0):
     return moe_lib.init_moe(cfg, jax.random.PRNGKey(seed))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_expert_layer_reads_a_stack_at_its_layer(use_pallas):
+    """The serving expert layer handed a three-layer stack of held-expert
+    weights and a traced layer serves what it serves from that layer's own
+    weights, bit for bit (the kernel in interpret mode, or ragged_dot)."""
+    cfg = TINY.with_(use_pallas=use_pallas)
+    layers = [_moe_params(cfg, seed) for seed in range(3)]
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 9, 64), jnp.float32)
+    serve = jax.jit(lambda p, x, layer: moe_lib.moe_serve(
+        cfg, p, x, layer=layer))
+    for layer in range(3):
+        stacked = dict(layers[layer])
+        for k in moe_lib.EXPERT_WEIGHTS:
+            stacked[k] = jnp.stack([p[k] for p in layers])
+        got = serve(stacked, x, jnp.asarray(layer, jnp.int32))
+        want = serve(layers[layer], x, None)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def _share(full, cfg_share):
@@ -192,6 +219,36 @@ def tiny_params():
 def _greedy(eng, prompt, n):
     (out,) = eng.generate([prompt], max_new=n)
     return out
+
+
+@pytest.mark.parametrize("attention", ["latent", "gqa"])
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_engine_reads_the_expert_stack_as_per_layer_weights(
+        tiny_params, monkeypatch, use_pallas, attention):
+    """Greedy tokens of the paged engine, ingesting two prompts (latent:
+    in 16-token chunks, the ragged ingest; GQA: whole, the monolithic
+    paged prefill) and decoding 8 tokens each, and their
+    log-probabilities, are exactly what it serves when each expert layer
+    is handed its own weights sliced out of the stack: the same matmuls on
+    the same tiles. (The tiny model's tokens alone barely see the experts:
+    its log-probabilities do.)"""
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (37, 20)]
+    kw = dict(max_batch=2, max_len=64, kv_backend="paged", page_size=8,
+              eos_id=-1)
+    cfg = TINY.with_(use_pallas=use_pallas)
+    params = tiny_params
+    if attention == "gqa":
+        cfg = cfg.with_(name="tiny-gqa-moe", kv_lora_rank=0, prefill_chunk=0)
+        params = transformer.init_params(cfg, jax.random.PRNGKey(5))
+    stacked = InferenceEngine(cfg, params, **kw).generate(
+        prompts, max_new=8)
+    # a config of its own name compiles its own programs
+    monkeypatch.setattr(transformer, "_expert_stack",
+                        lambda cfg, kind, seg, dtype: (seg, None))
+    per_layer = InferenceEngine(cfg.with_(name=cfg.name + "-per-layer"),
+                                params, **kw).generate(prompts, max_new=8)
+    assert stacked == per_layer             # tokens and log-probabilities
 
 
 def test_latent_pool_survives_fork_and_host_swap(tiny_params):

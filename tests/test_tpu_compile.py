@@ -10,6 +10,8 @@ and check that the compiled program holds the Mosaic kernel
 model is compiled too: it must fit a 16 GB chip, and at the benchmark's
 edge pool its decode and ragged ingest steps must read and write the
 stacked KV pool in place, with no pool-sized scratch, slice or relayout.
+The DeepSeek-V2-Lite share's steps must likewise read the held-expert
+weight stack in place, with no per-layer slice of it.
 
 The topology is described inside a module fixture (never at import: one
 process at a time may load the TPU library), which skips where it cannot be
@@ -297,3 +299,47 @@ def test_served_edge_steps_keep_the_kv_pool_in_place(spec, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 100
     dims = ",".join(map(str, pools[0].shape[1:]))
     assert _pool_sized(compiled.as_text(), dims) == []
+
+
+@pytest.mark.parametrize("step", ["decode", "ingest"])
+def test_served_expert_steps_read_the_expert_stack_in_place(spec,
+                                                            monkeypatch,
+                                                            step):
+    """The DeepSeek-V2-Lite share (rank 0 of 8-way expert parallelism: 8
+    held experts of width 1408 at d_model 2048), cut to 4 layers so three
+    expert layers share the layer loop, at the cell's 48 x 4096 latent
+    pool, page 32, 128-token chunks. Compiled for a v5e, its decode and
+    ragged ingest steps hand the grouped matmul the segment's whole stack
+    of held-expert weights and the layer: no instruction outside a fusion
+    body, other than the carry, holds one layer's experts (a per-layer
+    slice of the stack, 46 MB a projection)."""
+    from repro.configs.registry import get_config
+    from repro.kernels import runtime
+    from repro.models import transformer
+    from repro.serving import engine as engine_lib
+    from repro.serving.sampler import SamplerConfig
+    monkeypatch.setattr(runtime, "default_interpret", lambda: False)
+    cfg = get_config("deepseek-v2-lite").with_(
+        n_layers=4, ep_size=8, ep_rank=0, param_dtype="bfloat16",
+        use_pallas=True, prefill_chunk=CHUNK)
+    B, L = 48, 4096
+
+    def place(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = place(transformer.init_paged_cache(cfg, B, B * L // PAGE, PAGE,
+                                               L // PAGE, spec=True))
+    i32 = jnp.int32
+    if step == "decode":
+        fn = engine_lib._jitted(cfg, "decode_paged_run", SamplerConfig())
+        args = (spec((B, 1), i32), cache, spec((B,), jnp.bool_),
+                spec((2,), jnp.uint32))
+    else:
+        fn = engine_lib._jitted(cfg, "prefill_ragged")
+        args = (spec((ROWS, CHUNK), i32), cache, spec((ROWS,), i32),
+                spec((ROWS,), i32), spec((ROWS,), i32))
+    text = fn.lower(L // PAGE, params, *args).compile().as_text()
+    assert "moe_grouped_matmul" in text
+    for dims in ("8,2048,1408", "8,1408,2048"):
+        assert _pool_sized(text, dims) == []
